@@ -26,6 +26,7 @@ Deliberate fixes over the reference (SURVEY.md §7 "bugs to fix"):
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -313,11 +314,15 @@ class MultiheadAttention(nn.Module):
             # env default (None) so the A/B kill switch still works;
             # False (rematted attention) is a hard override
             save = None if self.flash_save_stats else False
-            if kernel_shard.flash_serviceable(self.mesh, self.h):
-                # r19: heads divide tp — the flash kernel runs PER SHARD
-                # on each device's local heads under shard_map (parallel/
+            if (kernel_shard.flash_serviceable(self.mesh, self.h)
+                    or kernel_shard.data_sharded(self.mesh)):
+                # a Mosaic kernel only partitions inside shard_map, on
+                # ANY mesh of more than one device.  Without a tp axis
+                # the batch rows shard over the data axes; with one
+                # (r19, heads divide tp) the flash kernel also runs PER
+                # SHARD on each device's local heads (parallel/
                 # kernel_shard.py) instead of falling back to the slower
-                # sequence-parallel strategies; dropout masks address
+                # sequence-parallel strategies.  Dropout masks address
                 # GLOBAL (b, h) stream indices, so they are placement-
                 # invariant vs the unsharded kernel
                 ctx = kernel_shard.flash_attention_sharded(
@@ -1064,6 +1069,14 @@ class Transformer(nn.Module):
         mlp_fn = {"pallas": fused_mlp_pallas,
                   "naive": lambda *a: mlp_reference(*a[:5])}.get(
             self.mlp_impl, fused_mlp)
+        if (self.mlp_impl == "pallas" and self.mesh is not None
+                and self.mesh.size > 1):
+            # the kernel only partitions inside shard_map: per-shard
+            # rows over the data axes (parallel/kernel_shard.py)
+            from faster_distributed_training_tpu.parallel import (
+                kernel_shard)
+            mlp_fn = functools.partial(kernel_shard.fused_mlp_sharded,
+                                       mesh=self.mesh)
 
         def classify(z):
             logits = mlp_fn(z.astype(self.dtype), w1.astype(self.dtype),

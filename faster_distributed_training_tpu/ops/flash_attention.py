@@ -35,9 +35,11 @@ TPU-first replacement for the reference's dense ScaledDotProduct
     the row lse, backward = two kernels (dq over the q-grid, dk/dv
     over the k-grid) driven by the saved (out, lse) — O(tile) VMEM,
     NO Lk cap, residuals stay O(L·D).
-  * non-TPU backends (tests, CPU sim) use the blockwise path; set
-    FDT_FORCE_PALLAS_INTERPRET=1 to exercise both kernels in
-    interpreter mode on CPU.
+  * non-TPU targets (tests, CPU sim) use the blockwise path; the
+    test-only FDT_FORCE_PALLAS_INTERPRET=1 seam exercises both kernels
+    in interpreter mode on CPU.  ops/pallas_target.py owns that
+    decision for every kernel in the package: a TPU target never
+    interprets.
 
 Head-dim support set (VERDICT r3 #7): the K-blocked kernels require
 ``D <= 128 or D % 128 == 0`` (`_kblocked_supported` — the running-stat
@@ -75,14 +77,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from faster_distributed_training_tpu.ops import pallas_target
 from faster_distributed_training_tpu.ops.attention import (
     NEG_INF, blockwise_attention, dense_attention_reference, mask_to_bias)
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("FDT_FORCE_PALLAS_INTERPRET") == "1":
-        return True
-    return jax.default_backend() == "tpu"
+    return pallas_target.flash_kernels()
 
 
 def _pack_seed(dropout_seed, bh0=None) -> jax.Array:
@@ -222,7 +223,7 @@ def _flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=(jax.default_backend() != "tpu"),
+        interpret=pallas_target.interpret(),
     )(q, k, v, bias, seed)
     if emit_lse:
         return res[0][:, :Lq, :], res[1][:, :Lq, 0]
@@ -371,7 +372,7 @@ def _flash_fwd_kblocked(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, _KB_LANES), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=(jax.default_backend() != "tpu"),
+        interpret=pallas_target.interpret(),
     )(q, k, v, bias, seed)
     return out[:, :Lq], lse[:, :Lq, 0]
 
@@ -481,7 +482,7 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
             dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
-    interp = jax.default_backend() != "tpu"
+    interp = pallas_target.interpret()
 
     def run(g):
         gn = pad_q_rows(n3(g))
@@ -834,7 +835,7 @@ def _flash_bwd_pallas_stats(q, k, v, key_bias, seed3, dropout_rate,
                 jax.ShapeDtypeStruct((N, Lk, D), jnp.float32),
                 jax.ShapeDtypeStruct((N, Lk, D), jnp.float32),
             ],
-            interpret=(jax.default_backend() != "tpu"),
+            interpret=pallas_target.interpret(),
         )(qp, kn, vn, bias, gp, lse128, delta128, seed)
         shape4 = lambda x, L: x.reshape(B, H, L, D)  # noqa: E731
         return (shape4(dq[:, :Lq], Lq).astype(q.dtype),
@@ -965,7 +966,7 @@ def _flash_bwd_pallas(q, k, v, key_bias, seed3, dropout_rate,
                 jax.ShapeDtypeStruct((N, Lk, D), jnp.float32),
                 jax.ShapeDtypeStruct((N, Lk, D), jnp.float32),
             ],
-            interpret=(jax.default_backend() != "tpu"),
+            interpret=pallas_target.interpret(),
         )(qp, kn, vn, bias, gp, seed)
         shape4 = lambda x, L: x.reshape(B, H, L, D)  # noqa: E731
         return (shape4(dq[:, :Lq], Lq).astype(q.dtype),

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from faster_distributed_training_tpu.ops import pallas_target
 from faster_distributed_training_tpu.ops.dropout import (guard_index_ceiling,
                                                          keep_factor_rows)
 from faster_distributed_training_tpu.ops.layernorm import (torch_layernorm,
@@ -254,7 +255,7 @@ def _ffn_fwd_pallas(h2d, ln_scale, ln_bias, w1, b1, w2, b2, seeds,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), h2d.dtype),
-        interpret=(jax.default_backend() != "tpu"),
+        interpret=pallas_target.interpret(),
     )(h2d, ln_scale.reshape(1, d), ln_bias.reshape(1, d), w1,
       b1.reshape(1, d_ff), w2, b2.reshape(1, d), seeds)
     return out[:B] if pad else out
@@ -433,8 +434,7 @@ def fused_ffn_sublayer_sharded(h, ln_scale, ln_bias, w1, b1, w2, b2,
             amax2 = jax.lax.pmax(amax2, ax)
         return out, amax2
 
-    from faster_distributed_training_tpu.compat import shard_map
-    out, amax2 = shard_map(
+    out, amax2 = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(data_spec, rep, rep, rep, rep, rep, rep, P(), P(), P()),
         out_specs=(data_spec, P()),
@@ -534,6 +534,9 @@ def _ffn_body_reference(h, ln_scale, ln_bias, w1, b1, w2, b2,
     return out
 
 
+_AMAX_TILE = (8, 128)   # fp32 min tile: the per-block amax output
+
+
 def _ffn_kernel2(h_ref, lns_ref, lnb_ref, w1_ref, b1_ref, w2_ref, b2_ref,
                  seeds_ref, scales_ref, o_ref, *amax_refs, block_rows: int,
                  rate_hidden: float, rate_conn: float, eps: float,
@@ -606,19 +609,13 @@ def _ffn_kernel2(h_ref, lns_ref, lnb_ref, w1_ref, b1_ref, w2_ref, b2_ref,
                                  rate_conn)
         o_ref[...] = (x32 + f2).astype(o_ref.dtype)
     if fmt is not None:
-        # (1, 1) running amaxes, max-accumulated across the sequential
-        # row-block grid (every block maps the same output block)
+        # one (8, 128) tile of this row block's amax per grid step,
+        # max-reduced outside the kernel: Mosaic cannot store a scalar
+        # into a VMEM ref, and max is order-free, so the reduced value
+        # is bit-equal to a running in-kernel max
         af_ref, aa_ref = amax_refs
-
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            af_ref[0, 0] = amax_blk_f
-            aa_ref[0, 0] = amax_blk_a
-
-        @pl.when(pl.program_id(0) > 0)
-        def _acc():
-            af_ref[0, 0] = jnp.maximum(af_ref[0, 0], amax_blk_f)
-            aa_ref[0, 0] = jnp.maximum(aa_ref[0, 0], amax_blk_a)
+        af_ref[...] = jnp.full(af_ref.shape, amax_blk_f, jnp.float32)
+        aa_ref[...] = jnp.full(aa_ref.shape, amax_blk_a, jnp.float32)
 
 
 def _ffn_fwd_pallas2(h2d, ln_scale, ln_bias, w1, b1, w2, b2, seeds,
@@ -674,8 +671,9 @@ def _ffn_fwd_pallas2(h2d, ln_scale, ln_bias, w1, b1, w2, b2, seeds,
     out_dtype = jnp.float32 if partial else h2d.dtype
     out_shape = [jax.ShapeDtypeStruct((nb * block_rows, d_out), out_dtype)]
     if fmt is not None:
-        out_specs += [pl.BlockSpec((1, 1), lambda i: (0, 0))] * 2
-        out_shape += [jax.ShapeDtypeStruct((1, 1), jnp.float32)] * 2
+        out_specs += [pl.BlockSpec(_AMAX_TILE, lambda i: (i, 0))] * 2
+        out_shape += [jax.ShapeDtypeStruct(
+            (nb * _AMAX_TILE[0], _AMAX_TILE[1]), jnp.float32)] * 2
     res = pl.pallas_call(
         kern,
         grid=(nb,),
@@ -692,13 +690,13 @@ def _ffn_fwd_pallas2(h2d, ln_scale, ln_bias, w1, b1, w2, b2, seeds,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=(jax.default_backend() != "tpu"),
+        interpret=pallas_target.interpret(),
     )(h2d, ln_scale.reshape(1, d), ln_bias.reshape(1, d), w1,
       b1.reshape(1, d_ff), w2, b2.reshape(1, d_out), seeds,
       scales.reshape(1, 4))
     if fmt is not None:
         out, af, aa = res
-        amax2 = jnp.stack([af[0, 0], aa[0, 0]])
+        amax2 = jnp.stack([jnp.max(af), jnp.max(aa)])
     else:
         out = res[0] if isinstance(res, (list, tuple)) else res
         amax2 = jnp.zeros((2,), jnp.float32)

@@ -31,6 +31,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from faster_distributed_training_tpu.ops import pallas_target
+
 
 def mlp_reference(x: jax.Array, w1: jax.Array, b1: Optional[jax.Array],
                   w2: jax.Array, b2: Optional[jax.Array]) -> jax.Array:
@@ -132,7 +134,7 @@ def _mlp_fwd_pallas(x2d: jax.Array, w1: jax.Array, b1: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_b, d_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_b, d_out), x2d.dtype),
-        interpret=(jax.default_backend() != "tpu"),
+        interpret=pallas_target.interpret(),
     )(x2d, w1.T, jnp.reshape(b1, (1, d_h)), w2.T, jnp.reshape(b2, (1, d_out)))
     return out[:B] if pad else out
 

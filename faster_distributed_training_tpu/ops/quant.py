@@ -64,6 +64,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from faster_distributed_training_tpu.ops import pallas_target
+
 try:
     from jax.experimental import pallas as pl
 except ImportError:  # pragma: no cover
@@ -279,7 +281,7 @@ def quant_dot_pallas(xq: jax.Array, wq: jax.Array, sx: jax.Array,
         ],
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * br, n), out_dtype),
-        interpret=(jax.default_backend() != "tpu"),
+        interpret=pallas_target.interpret(),
     )(xq, wq, inv)
     return out[:m] if pad else out
 
@@ -404,7 +406,7 @@ def quant_dot(x: jax.Array, w: jax.Array, sx: jax.Array, sw: jax.Array,
         raise ValueError(f"quant_dot grad_fmt must be one of "
                          f"{_GRAD_FMTS}, got {grad_fmt!r}")
     if use_pallas is None:
-        use_pallas = (jax.default_backend() == "tpu"
+        use_pallas = (pallas_target.on_tpu()
                       and quant_kernel_fits_vmem(x.shape[-1], w.shape[-1]))
     return _quant_dot_core(x, w, jnp.asarray(sx, jnp.float32),
                            jnp.asarray(sw, jnp.float32), fmt,

@@ -53,8 +53,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     sp placement.
     """
     B, H, L, D = q.shape
-    from faster_distributed_training_tpu.compat import axis_size
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     scale = 1.0 / math.sqrt(D)
     perm = [(j, (j + 1) % sp) for j in range(sp)]

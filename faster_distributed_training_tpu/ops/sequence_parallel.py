@@ -109,6 +109,5 @@ def sp_self_attention(body: Callable, q: jax.Array, k: jax.Array,
                       dropout_bh=global_bh())
         return fn(q_, k_, v_, **kw)
 
-    from faster_distributed_training_tpu.compat import shard_map
-    return shard_map(call, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=qkv_spec)(*args)
+    return jax.shard_map(call, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qkv_spec)(*args)

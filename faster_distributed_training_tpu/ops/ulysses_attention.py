@@ -58,8 +58,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     the pattern equal to the dense/flash one for the same seed.
     """
     B, H, L_loc, D = q.shape
-    from faster_distributed_training_tpu.compat import axis_size
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     if H % sp:
         raise ValueError(f"Ulysses needs heads ({H}) divisible by the "
                          f"sp axis size ({sp}); use ring attention otherwise")

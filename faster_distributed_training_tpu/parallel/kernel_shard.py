@@ -1,4 +1,4 @@
-"""ONE shard_map wrapper layer: every Pallas kernel partitions over tp.
+"""ONE shard_map wrapper layer: every Pallas kernel runs per shard.
 
 The repo's recurring measured caveat (recorded three times: flash
 attention r11, the monolithic fused-FFN kernel r11, the quant-matmul
@@ -41,7 +41,19 @@ Decompositions (one per recovered kernel):
   delayed per-tensor scales stay GLOBAL scalars (amax reductions
   happen outside the boundary on the logical arrays, unchanged).
 
-Enablement: the layer is ON by default; ``FDT_KERNEL_SHARD=0`` kills
+Data axes (PR 21): a Mosaic kernel cannot be partitioned by XLA at all
+— not over tp and not over a plain data axis — so on ANY mesh of more
+than one device every kernel must sit inside ``shard_map``.  The same
+three wrappers therefore also serve meshes WITHOUT a tp axis
+(``data_sharded``): flash and the quant GEMM run with batch rows over
+dp/fsdp and nothing else split, and ``fused_mlp_sharded`` does the same
+for the Pallas classifier head (batch-sharded rows, replicated
+weights) on every mesh.  (The fused-FFN kernel's data-axis twin is
+``ops.fused_ffn.fused_ffn_sublayer_sharded``.)  The data-axis routes
+have no XLA fallback to be killed into, so ``FDT_KERNEL_SHARD`` does
+not gate them.
+
+Enablement: the tp layer is ON by default; ``FDT_KERNEL_SHARD=0`` kills
 it, restoring the r11/r13 warned capability fallbacks — which also
 makes the kill switch the bench A/B arm (kernel-via-shard_map vs
 forced fallback, ``transformer_tp2_*`` arms).  Non-dividing shapes
@@ -62,7 +74,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from faster_distributed_training_tpu.compat import shard_map
 from faster_distributed_training_tpu.parallel.mesh import axis_size, tp_size
 
 ENV_KILL = "FDT_KERNEL_SHARD"
@@ -73,6 +84,20 @@ def enabled() -> bool:
     and tests can flip it): False restores the pre-r19 warned
     capability fallbacks on tp meshes."""
     return os.environ.get(ENV_KILL, "1") != "0"
+
+
+def _shard_map(f, mesh: Mesh, in_specs, out_specs):
+    # check_vma=False: a pallas_call's out_shape carries no
+    # varying-mesh-axes info, so VMA checking cannot see through it
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def data_sharded(mesh: Optional[Mesh]) -> bool:
+    """True on a mesh of more than one device with no tp axis to split:
+    kernels there run per shard with batch rows over the data axes
+    (and replicated over any other axis)."""
+    return mesh is not None and mesh.size > 1 and tp_size(mesh) == 1
 
 
 def _batch_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -98,7 +123,7 @@ def _batch_index(mesh: Mesh, batch: Tuple[str, ...]) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# flash attention: head-sharded over tp
+# flash attention: head-sharded over tp, batch over the data axes
 # ---------------------------------------------------------------------------
 
 def flash_serviceable(mesh: Optional[Mesh], n_heads: int) -> bool:
@@ -115,8 +140,9 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                             dropout_seed: Optional[jax.Array] = None,
                             save_stats: Optional[bool] = None
                             ) -> jax.Array:
-    """[B,H,L,D] flash attention with H sharded over tp and B over the
-    data axes — each device runs the flash Pallas kernel (or its
+    """[B,H,L,D] flash attention with H sharded over tp (when the mesh
+    has one) and B over the data axes — each device runs the flash
+    Pallas kernel (or its
     off-TPU blockwise twin, same routing as the unsharded call) on its
     local heads.  Dropout masks address GLOBAL (b, h) stream indices
     (ops/flash_attention._pack_seed), so the SAME seed draws the SAME
@@ -127,15 +153,14 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
 
     B, H, L, D = q.shape
     tp = tp_size(mesh)
-    if tp <= 1 or H % tp:
+    if H % tp:
         raise ValueError(
             f"flash_attention_sharded needs a tp axis whose size divides "
-            f"the head count (H={H}, mesh={dict(mesh.shape) if mesh else None}"
-            f") — build_model routes non-dividing shapes to the warned "
-            f"fallback instead")
+            f"the head count (H={H}, mesh={dict(mesh.shape)}) — build_model "
+            f"routes non-dividing shapes to the warned fallback instead")
     batch = _batch_axes(mesh)
     lead = _lead(batch)
-    qkv_spec = P(lead, "tp", None, None)
+    qkv_spec = P(lead, "tp" if tp > 1 else None, None, None)
     b_shards = 1
     for a in batch:
         b_shards *= mesh.shape[a]
@@ -165,18 +190,35 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
         mask_ = rest.pop(0) if has_mask else None
         seed_ = rest.pop(0) if has_drop else None
         b0 = _batch_index(mesh, batch) * jnp.uint32(b_loc)
-        h0 = lax.axis_index("tp").astype(jnp.uint32) * jnp.uint32(h_loc)
+        h0 = (lax.axis_index("tp").astype(jnp.uint32) * jnp.uint32(h_loc)
+              if tp > 1 else jnp.uint32(0))
         return flash_attention(q_, k_, v_, mask=mask_,
                                dropout_rate=dropout_rate,
                                dropout_seed=seed_,
                                save_stats=save_stats,
                                bh0=(b0, h0), h_glob=H)
 
-    return shard_map(call, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=qkv_spec,
-                     # the pallas_call's out_shape carries no
-                     # varying-mesh-axes info (the fused_ffn precedent)
-                     check_vma=False)(*args)
+    return _shard_map(call, mesh, tuple(specs), qkv_spec)(*args)
+
+
+# ---------------------------------------------------------------------------
+# classifier MLP head: batch rows over the data axes, weights replicated
+# ---------------------------------------------------------------------------
+
+def fused_mlp_sharded(x: jax.Array, w1: jax.Array, b1: jax.Array,
+                      w2: jax.Array, b2: jax.Array, mesh: Mesh) -> jax.Array:
+    """The Pallas classifier head (ops.fused_mlp.fused_mlp_pallas) on a
+    mesh of more than one device: x [B, d] arrives with its rows over
+    the data axes and each device runs the kernel on its own rows; the
+    small head weights are replicated at the boundary (whatever rule
+    laid them out).  The head has no dropout, so there is no global
+    index to address."""
+    from faster_distributed_training_tpu.ops.fused_mlp import (
+        fused_mlp_pallas)
+
+    rows = P(_lead(_batch_axes(mesh)), None)
+    return _shard_map(fused_mlp_pallas, mesh,
+                      (rows, P(), P(), P(), P()), rows)(x, w1, b1, w2, b2)
 
 
 # ---------------------------------------------------------------------------
@@ -298,12 +340,11 @@ def fused_ffn_sublayer_tp(h, ln_scale, ln_bias, w1, b1, w2, b2,
         amax2 = lax.pmax(amax2, "tp")
         return out, amax2
 
-    out, amax2 = shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(h_spec, rep, rep, P(None, "tp"), P("tp"),
-                  P("tp", None), rep, P(), P(), P()),
-        out_specs=(out_spec, P()),
-        check_vma=False,
+    out, amax2 = _shard_map(
+        per_shard, mesh,
+        (h_spec, rep, rep, P(None, "tp"), P("tp"),
+         P("tp", None), rep, P(), P(), P()),
+        (out_spec, P()),
     )(h, ln_scale, ln_bias, w1, b1, w2, b2,
       jnp.asarray(hid_seed, jnp.uint32), jnp.asarray(out_seed, jnp.uint32),
       pack_scales(quant_scales if quant_fmt is not None else None))
@@ -332,18 +373,22 @@ def quant_tp_serviceable(mesh: Optional[Mesh], tp_dim: Optional[int],
 def quant_tp_routed(mesh: Optional[Mesh], tp_dim: Optional[int],
                     kernel_shape, use_pallas) -> bool:
     """The QuantDense routing predicate: shard_map when the site is
-    serviceable AND the policy didn't force the registered fallback
+    serviceable over tp (or the mesh has only data axes to shard over —
+    ``data_sharded``) AND the policy didn't force the registered fallback
     (use_pallas=False — the FDT_KERNEL_SHARD=0 / non-dividing-shape
     path cli.build_model sets)."""
     return (use_pallas is not False
-            and quant_tp_serviceable(mesh, tp_dim, kernel_shape))
+            and (quant_tp_serviceable(mesh, tp_dim, kernel_shape)
+                 or data_sharded(mesh)))
 
 
 def quant_dense_sharded(x2d: jax.Array, kernel: jax.Array,
                         sx: jax.Array, sw: jax.Array, fmt: str,
                         mesh: Mesh, tp_dim: int,
                         grad_fmt: Optional[str] = None) -> jax.Array:
-    """One QuantDense GEMM per-shard over tp.  x2d: [M, K] (rows
+    """One QuantDense GEMM per-shard.  On a mesh without tp
+    (``data_sharded``) rows are batch-sharded, the kernel replicated and
+    nothing is reduced; the rest describes tp meshes.  x2d: [M, K] (rows
     batch-sharded over the data axes); kernel: (K, *feats) with feats
     dim ``tp_dim`` tp-sharded (column-parallel) or ``tp_dim == 0``
     (K tp-sharded, row-parallel — x2d's columns arrive tp-sharded the
@@ -357,17 +402,18 @@ def quant_dense_sharded(x2d: jax.Array, kernel: jax.Array,
     lead = _lead(batch)
     ndim = kernel.ndim
     feats = kernel.shape[1:]
-    row = tp_dim == 0
-    w_spec = P(*[("tp" if i == tp_dim else None) for i in range(ndim)])
+    row = tp > 1 and tp_dim == 0
+    col = tp_dim if tp > 1 else None     # the tp-sharded kernel dim
+    w_spec = P(*[("tp" if i == col else None) for i in range(ndim)])
     if row:
         x_spec = P(lead, "tp")
         out_spec = P(lead, *([None] * len(feats)))
         g_axes = batch
     else:
         x_spec = P(lead, None)
-        out_spec = P(lead, *[("tp" if i == tp_dim else None)
+        out_spec = P(lead, *[("tp" if i == col else None)
                              for i in range(1, ndim)])
-        g_axes = batch + ("tp",)
+        g_axes = batch + (("tp",) if tp > 1 else ())
 
     def per_shard(x_, w_, scales_):
         w2d = w_.reshape(w_.shape[0], -1)
@@ -388,8 +434,6 @@ def quant_dense_sharded(x2d: jax.Array, kernel: jax.Array,
     # this jax's shard_map transpose spec check on the cotangent side
     scales = jnp.stack([jnp.asarray(sx, jnp.float32).reshape(()),
                         jnp.asarray(sw, jnp.float32).reshape(())])
-    out = shard_map(per_shard, mesh=mesh,
-                    in_specs=(x_spec, w_spec, P(None)),
-                    out_specs=out_spec,
-                    check_vma=False)(x2d, kernel, scales)
+    out = _shard_map(per_shard, mesh, (x_spec, w_spec, P(None)),
+                     out_spec)(x2d, kernel, scales)
     return out.reshape(x2d.shape[0], int(np.prod(feats)))

@@ -145,7 +145,7 @@ def initialize_distributed(coordinator: Optional[str] = None,
 
 
 def _ici_device_mesh(shape: Tuple[int, ...],
-                     axes: Tuple[str, ...]) -> Optional[np.ndarray]:
+                     axes: Tuple[str, ...]) -> np.ndarray:
     """ICI-aware device assignment for a TPU mesh (SNIPPETS [1]).
 
     `mesh_utils.create_device_mesh` assigns later mesh dims to
@@ -153,13 +153,12 @@ def _ici_device_mesh(shape: Tuple[int, ...],
     `_AXIS_SPEED` (dp outermost, tp innermost = fastest links) before
     construction and transposed back to the caller's order after — the
     "tp on the fastest axis" auto policy.  Multi-process pods factor the
-    slowest data axis over DCN via `create_hybrid_device_mesh`.  Returns
-    None when the topology tools can't serve the request (caller falls
-    back to the plain reshape)."""
-    try:
-        from jax.experimental import mesh_utils
-    except ImportError:        # pragma: no cover - jax always ships it
-        return None
+    slowest data axis over DCN via `create_hybrid_device_mesh`.  The
+    caller asks only for shapes that use every device; a request the
+    topology tools cannot serve RAISES with the cause in it — a mesh
+    laid out by plain reshape instead would train, slower, without a
+    word."""
+    from jax.experimental import mesh_utils
     perm = sorted(range(len(axes)),
                   key=lambda i: (_AXIS_SPEED.get(axes[i], -1), i))
     pshape = tuple(shape[i] for i in perm)
@@ -176,7 +175,7 @@ def _ici_device_mesh(shape: Tuple[int, ...],
             # letting them span DCN would put the per-layer
             # model-parallel collectives on the slowest links, inverting
             # the _AXIS_SPEED policy — a mesh whose pp/data axes can't
-            # absorb the process count falls back to the plain reshape.
+            # absorb the process count is an error.
             paxes = [axes[i] for i in perm]
             dcn = [1] * len(pshape)
             for j, d in enumerate(pshape):
@@ -185,15 +184,21 @@ def _ici_device_mesh(shape: Tuple[int, ...],
                     dcn[j] = pc
                     break
             else:
-                return None
+                raise ValueError(
+                    f"no pp/dp/fsdp axis of {dict(zip(axes, shape))} is "
+                    f"divisible by the {pc} processes (tp/sp never span "
+                    f"DCN)")
             ici = list(pshape)
             ici[j] //= pc
             dev = mesh_utils.create_hybrid_device_mesh(
                 tuple(ici), tuple(dcn))
         else:
             dev = mesh_utils.create_device_mesh(pshape)
-    except Exception:
-        return None
+    except Exception as e:
+        raise RuntimeError(
+            f"cannot build an ICI-aware device mesh for "
+            f"{dict(zip(axes, shape))} over {jax.device_count()} "
+            f"{jax.devices()[0].device_kind} device(s): {e}") from e
     return np.transpose(dev, np.argsort(perm))
 
 
@@ -242,11 +247,10 @@ def make_mesh(axes: Sequence[str] = ("dp",),
         warnings.warn(f"mesh shape {shape} uses {want} of {n} visible "
                       f"devices; the remaining {n - want} idle",
                       stacklevel=2)
-    dev_array = None
     if (not explicit_devices and want == n
             and devices[0].platform == "tpu"):
         dev_array = _ici_device_mesh(shape, axes)
-    if dev_array is None:
+    else:
         dev_array = np.asarray(devices[:want]).reshape(shape)
     return Mesh(dev_array, axes)
 

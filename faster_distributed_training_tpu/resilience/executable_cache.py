@@ -43,10 +43,7 @@ Mechanics
   ``lowered.as_text()`` — shapes, shardings, donation policy context)
   PLUS the environment the executable is only valid in: jax + jaxlib
   versions, backend, device kind and count, mesh axes/shape, the
-  donation flag, and the host ISA fingerprint (the MULTICHIP_r03
-  lesson: a CPU AOT executable compiled with wider vector extensions
-  SIGILLs elsewhere — ``cli._host_isa_fingerprint`` keys the persistent
-  HLO cache for the same reason).  Any component moving (a jaxlib
+  donation flag.  Any component moving (a jaxlib
   upgrade, a different slice topology) changes the key and the old
   entries are simply never read again.
 * Where ``serialize_executable`` is unavailable or refuses a program
@@ -98,7 +95,7 @@ def environment_key(mesh=None, donate: Optional[bool] = None,
                     extra: str = "") -> str:
     """Fingerprint of everything OUTSIDE the HLO that an executable is
     only valid under: jax/jaxlib versions, backend + device kind/count,
-    mesh axes/shape, donation flag, host ISA.  A restarted slice on an
+    mesh axes/shape, donation flag.  A restarted slice on an
     upgraded runtime gets a clean miss, never a poisoned load."""
     import jax
 
@@ -125,12 +122,17 @@ def environment_key(mesh=None, donate: Optional[bool] = None,
         bits.append(f"donate={bool(donate)}")
     if extra:
         bits.append(str(extra))
-    try:
-        from faster_distributed_training_tpu.cli import _host_isa_fingerprint
-        bits.append(f"isa={_host_isa_fingerprint()}")
-    except Exception:
-        pass
     return hashlib.sha256("|".join(bits).encode()).hexdigest()[:16]
+
+
+def _lowered_devices(lowered):
+    """The device assignment ``lowered`` was lowered for (the devices of
+    its shardings / mesh).  jax.stages.Lowered has no public accessor
+    for it on jax 0.9.0; None (jax's all-local-devices default) if the
+    private one ever moves."""
+    devs = getattr(getattr(lowered, "_lowering", None), "_device_list",
+                   None)
+    return list(devs) if devs else None
 
 
 def serialize_available() -> bool:
@@ -223,8 +225,12 @@ class ExecutableCache:
                 raise ValueError(f"truncated entry ({len(raw) - 16}/{n} "
                                  f"payload bytes)")
             from jax.experimental import serialize_executable as se
+            # load over the devices the program was LOWERED for: left
+            # to its default, jax 0.9 loads over every local device and
+            # a program for fewer devices than the host has is refused
             compiled = se.deserialize_and_load(
-                raw[16:], lowered.in_tree, lowered.out_tree)
+                raw[16:], lowered.in_tree, lowered.out_tree,
+                execution_devices=_lowered_devices(lowered))
         except Exception as e:
             self.stats["corrupt"] += 1
             self._warn_once(

@@ -1,17 +1,24 @@
 """Build + bind the native runtime core (runtime/native/fdt_native.cc).
 
-The library is compiled on demand with g++ (cached by source mtime) and
-bound through ctypes — no pybind11 dependency in this environment.  Every
-entry point has a pure-Python fallback in data/, so the framework works
-even without a toolchain; when the library IS available the data path
-uses it (see data/agnews.py / data/loader.py call sites).
+The library is compiled on demand with g++ and bound through ctypes — no
+pybind11 dependency in this environment.  The build is keyed on a hash
+of the SOURCE BYTES (``_build/libfdt_native-<hash>.so``): mtimes do not
+survive a copy of the tree, and a library whose hash does not match the
+source in the checkout is never loaded.  Every entry point has a
+pure-Python fallback in data/, so the framework works even without a
+toolchain; when the library IS available the data path uses it (see
+data/agnews.py / data/loader.py call sites).  ``status()`` says which of
+the three happened: ``built``, ``reused <hash>`` or ``no compiler:
+Python fallback``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import List, Optional, Tuple
 
@@ -20,25 +27,50 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native", "fdt_native.cc")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_LIB = os.path.join(_BUILD_DIR, "libfdt_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_status = ""
 
 
-def _build() -> bool:
+def _source_digest() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()[:12]
+
+
+def _build(lib_path: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", _LIB]
+    # build beside the target and rename: concurrent builders (xdist
+    # workers) each publish a whole file, never a half-written one
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)
         return True
     except Exception:
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return False
 
 
+def status() -> str:
+    """How the library was obtained: ``built``, ``reused <hash>`` or
+    ``no compiler: Python fallback`` (loads it on first call)."""
+    load()
+    return _status
+
+
+def _set_status(msg: str) -> None:
+    global _status
+    _status = msg
+    print(f"[native] {msg}", file=sys.stderr)
+
+
 def load() -> Optional[ctypes.CDLL]:
-    """The bound library, building it if stale/absent; None on failure."""
+    """The bound library, built from the checkout's source unless a
+    build of exactly these source bytes exists; None on failure."""
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
@@ -46,12 +78,18 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_failed:
             return _lib
         try:
-            stale = (not os.path.exists(_LIB)
-                     or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-            if stale and not _build():
+            digest = _source_digest()
+            lib_path = os.path.join(_BUILD_DIR,
+                                    f"libfdt_native-{digest}.so")
+            if os.path.exists(lib_path):
+                how = f"reused {digest}"
+            elif _build(lib_path):
+                how = "built"
+            else:
                 _load_failed = True
+                _set_status("no compiler: Python fallback")
                 return None
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(lib_path)
             lib.fdt_crc32.restype = ctypes.c_uint32
             lib.fdt_crc32.argtypes = [ctypes.c_char_p, ctypes.c_int64]
             lib.fdt_clean_text.restype = ctypes.c_int64
@@ -78,8 +116,10 @@ def load() -> Optional[ctypes.CDLL]:
                 ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
                 ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32)]
             _lib = lib
-        except Exception:
+            _set_status(how)
+        except Exception as e:
             _load_failed = True
+            _set_status(f"no compiler: Python fallback ({e!r})")
     return _lib
 
 

@@ -53,9 +53,8 @@ class DecodeEngine:
     ``device`` pins the replica to one chip (the SNIPPETS [3] 1D
     replicated layout decode defaults to); ``mesh`` is the model-
     sharded exception for checkpoints that don't fit a chip.  ``donate``
-    None = auto: the cache buffers round-trip through every step/insert
-    program unless the backend is a jaxlib-0.4.x CPU client (the r7
-    allocator caveat, same gate as the classifier engine)."""
+    None = donate: the cache buffers round-trip through every
+    step/insert program."""
 
     def __init__(self, model, state: ServingState, buckets: Sequence[int],
                  batch_size: int = 4, page: int = 16, max_pages: int = 0,
@@ -86,12 +85,7 @@ class DecodeEngine:
         self.device = device
         self.mesh = mesh
         self._log = log
-        if donate is None:
-            from faster_distributed_training_tpu.cli import (
-                donation_workaround_needed)
-            donate = not (jax.default_backend() == "cpu"
-                          and donation_workaround_needed())
-        self.donate = bool(donate)
+        self.donate = True if donate is None else bool(donate)
         params = state.params["model"]
         if device is not None:
             params = jax.device_put(params, device)

@@ -125,9 +125,8 @@ class InferenceEngine:
     model-sharded fallback — compiles/executes under the mesh context
     with the variables wherever the caller placed them.
 
-    ``donate``: None = auto (donate the batch argument unless the
-    backend is a jaxlib-0.4.x CPU client, the r7 allocator caveat —
-    ``cli.donation_workaround_needed``); True/False force.  Donated or
+    ``donate``: None = donate the batch argument; True/False force.
+    Donated or
     not, callers passing device arrays must treat them as CONSUMED.
     """
 
@@ -144,12 +143,7 @@ class InferenceEngine:
         self.device = device
         self.mesh = mesh
         self._log = log
-        if donate is None:
-            from faster_distributed_training_tpu.cli import (
-                donation_workaround_needed)
-            donate = not (jax.default_backend() == "cpu"
-                          and donation_workaround_needed())
-        self.donate = bool(donate)
+        self.donate = True if donate is None else bool(donate)
         variables = state.variables()
         if device is not None:
             variables = jax.device_put(variables, device)

@@ -68,8 +68,10 @@ exactly the restart-MTTR compile component.
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import os
+import re
 import threading
 import time
 import warnings
@@ -167,6 +169,47 @@ def memory_analysis_dict(compiled) -> Optional[Dict[str, int]]:
     return out
 
 
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+                "all-to-all", "collective-permute")
+_COLLECTIVE_RE = re.compile(
+    r" (" + "|".join(_COLLECTIVES) + r")(?:-start)?\(")
+
+
+def hlo_op_counts(lowered_text: str, compiled) -> Optional[Dict[str, int]]:
+    """Static counts of what the program holds: ``tpu_custom_call``
+    (Pallas/Mosaic kernels, from the LOWERED module — present whether
+    the executable was compiled or served from a cache) and each
+    collective (from the COMPILED module: the partitioner inserts most
+    of them; absent keys mean the backend returned no text)."""
+    if not lowered_text:
+        return None
+    out = {"tpu_custom_call": lowered_text.count("tpu_custom_call")}
+    try:
+        text = compiled.as_text()
+    except Exception:
+        return out
+    found = collections.Counter(_COLLECTIVE_RE.findall(text))
+    out.update({op: found[op] for op in _COLLECTIVES})
+    return out
+
+
+_cache_hits = [0, False]    # [count, listener registered]
+
+
+def _persistent_cache_hits() -> int:
+    """Running count of jax's own persistent-compilation-cache hit
+    events in this process (listener registered on first use)."""
+    if not _cache_hits[1]:
+        import jax.monitoring
+
+        def on_event(name, **_kw):
+            if name == "/jax/compilation_cache/cache_hits":
+                _cache_hits[0] += 1
+        jax.monitoring.register_event_listener(on_event)
+        _cache_hits[1] = True
+    return _cache_hits[0]
+
+
 class ProgramObservatory:
     """Owns the run's compile record.  Thread-safe (the checkpoint
     background writer never compiles, but nothing here assumes that).
@@ -218,7 +261,9 @@ class ProgramObservatory:
             t0 = time.monotonic()
             lowered = jitted.lower(*args)
             lower_ms = (time.monotonic() - t0) * 1e3
-            fingerprint = self._fingerprint(lowered)
+            lowered_text = self._lowered_text(lowered)
+            fingerprint = (hashlib.sha256(lowered_text.encode())
+                           .hexdigest()[:16] if lowered_text else "")
             # r17 executable cache: lookup-before-compile.  A hit
             # deserializes the stored executable (compile_ms below IS
             # the deserialize time — the restart-MTTR number the A/B
@@ -239,10 +284,16 @@ class ProgramObservatory:
                 source = "deserialized"
             else:
                 before = self._cache_listing()
+                hits0 = _persistent_cache_hits()
                 t0 = time.monotonic()
                 compiled = lowered.compile()
                 compile_ms = (time.monotonic() - t0) * 1e3
-                cache, method = self._cache_verdict(before, compile_ms)
+                if _persistent_cache_hits() > hits0:
+                    # jax itself said so: the only verdict that tells a
+                    # FAST hit from a compile too quick to be stored
+                    cache, method = "hit", "jax_event"
+                else:
+                    cache, method = self._cache_verdict(before, compile_ms)
                 # "persistent_dir": XLA's own persistent cache served
                 # the compile (the executable tier's designed fallback)
                 source = "persistent_dir" if cache == "hit" else "compiled"
@@ -258,6 +309,7 @@ class ProgramObservatory:
                         # serving this program at restart regardless
                         ec.note_skipped_served()
             mem = memory_analysis_dict(compiled)
+            ops = hlo_op_counts(lowered_text, compiled)
         except Exception as e:
             self._log(f"[programs] could not observe-compile {name!r} "
                       f"({e!r}); plain jit dispatch serves it (no program "
@@ -271,11 +323,12 @@ class ProgramObservatory:
             except Exception:
                 pass  # accounting must never kill the compile path
         self._record(name, sig, lower_ms, compile_ms, fingerprint, cache,
-                     method, mem, source)
+                     method, mem, source, ops)
         return compiled
 
     def _record(self, name, sig, lower_ms, compile_ms, fingerprint,
-                cache, method, mem, source: str = "compiled") -> None:
+                cache, method, mem, source: str = "compiled",
+                ops: Optional[dict] = None) -> None:
         with self._lock:
             entries = self.programs.setdefault(name, [])
             self._detect_retrace(name, entries, sig)
@@ -289,6 +342,11 @@ class ProgramObservatory:
                      "_sig": sig}
             if mem:
                 entry.update(mem)
+            if ops:
+                # manifest-only (not in the JSONL program event): what
+                # chip_smoke.py reads to prove kernels and collectives
+                # are in the program that ran
+                entry["hlo_ops"] = ops
             entries.append(entry)
         if self.recorder is not None:
             ev = {"name": name, "lowerings": len(entries),
@@ -343,12 +401,14 @@ class ProgramObservatory:
 
     # -- cache + fingerprint ----------------------------------------------
 
-    def _fingerprint(self, lowered) -> str:
+    def _lowered_text(self, lowered) -> str:
+        """The lowered module's text — hashed into the HLO fingerprint
+        and scanned for kernel custom calls; "" under the
+        FDT_HLO_FINGERPRINT=0 escape or on failure."""
         if os.environ.get(ENV_FINGERPRINT, "1") == "0":
             return ""
         try:
-            return hashlib.sha256(
-                lowered.as_text().encode()).hexdigest()[:16]
+            return lowered.as_text()
         except Exception:
             return ""
 

@@ -492,12 +492,10 @@ class Trainer:
                 closer()
             raise
         if metrics is not None:
-            # fence with a device->host readback: on some PJRT backends
-            # block_until_ready returns at dispatch, not completion
-            # (.claude/skills/verify/SKILL.md), which would make the
+            # fence: dispatch is asynchronous, and without it the
             # reference-parity epoch timing (resnet50_test.py:519,614)
-            # meaninglessly small.
-            float(metrics["loss"])
+            # would measure the enqueue
+            jax.block_until_ready(metrics["loss"])
         self._last_epoch_steps = n
         elapsed = time.monotonic() - t0
         return state, acc.summary(), elapsed
@@ -618,7 +616,7 @@ class Trainer:
                 closer()
             raise
         if metrics is not None:
-            float(metrics["loss"])     # fence (see run_epoch)
+            jax.block_until_ready(metrics["loss"])   # fence (run_epoch)
         self._last_epoch_steps = n
         return state, acc.summary(), time.monotonic() - t0
 
@@ -704,7 +702,7 @@ class Trainer:
                 key)
             last = self._log_dispatch(epoch, n, run, metrics, last)
         if metrics is not None:
-            float(metrics["loss"])     # fence (see run_epoch)
+            jax.block_until_ready(metrics["loss"])   # fence (run_epoch)
         self._last_epoch_steps = n
         return state, acc.summary(), time.monotonic() - t0
 
@@ -819,7 +817,7 @@ class Trainer:
             # prefetch-closer contract the host paths honor in except:)
             window.close()
         if metrics is not None:
-            float(metrics["loss"])     # fence (see run_epoch)
+            jax.block_until_ready(metrics["loss"])   # fence (run_epoch)
         self._last_epoch_steps = n
         return state, acc.summary(), time.monotonic() - t0
 

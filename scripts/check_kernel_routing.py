@@ -51,8 +51,9 @@ KERNEL_MODULES: Dict[str, str] = {
         "per-site column/row tiles via kernel_shard.quant_dense_sharded;"
         " QuantDense forces the XLA reference on unrouted tp sites",
     "ops/fused_mlp.py":
-        "classifier MLP on the pooled (B, d) activations — batch-sharded"
-        " operands only, no tensor-parallel dimension to split",
+        "classifier MLP on the pooled (B, d) activations — no tensor-"
+        "parallel dimension; on any mesh > 1 device it runs per shard "
+        "over the data axes via kernel_shard.fused_mlp_sharded",
 }
 
 # public kernel entry points -> defining module.  Private helpers
@@ -78,9 +79,13 @@ ALLOWED_CALLERS: Dict[Tuple[str, str], str] = {
         "THE shard_map layer: per-shard Megatron column/row FFN tiles",
     ("parallel/kernel_shard.py", "quant_dot"):
         "THE shard_map layer: per-shard quant GEMM on the site's tile",
+    ("parallel/kernel_shard.py", "fused_mlp_pallas"):
+        "THE shard_map layer: classifier head per shard over the data "
+        "axes (a Mosaic kernel only partitions inside shard_map)",
     ("models/transformer.py", "flash_attention"):
-        "guarded by kernel_shard.flash_serviceable at the call site; "
-        "build_model's registered warned fallback reroutes tp otherwise",
+        "one-device / mesh-less branch only: guarded by kernel_shard"
+        ".flash_serviceable + data_sharded at the call site; build_model's"
+        " registered warned fallback reroutes non-dividing tp",
     ("models/transformer.py", "fused_ffn_sublayer"):
         "unsharded-mesh branch only (tp routes through "
         "kernel_shard.fused_ffn_sublayer_tp in the same dispatch chain)",
@@ -90,8 +95,8 @@ ALLOWED_CALLERS: Dict[Tuple[str, str], str] = {
     ("models/transformer.py", "ffn_core_generalized"):
         "unsharded quantized composition (mesh is None on that branch)",
     ("models/transformer.py", "fused_mlp_pallas"):
-        "classifier MLP on pooled (B, d) activations — batch-only "
-        "operands, nothing tensor-parallel to split",
+        "one-device / mesh-less branch only; any mesh > 1 device routes "
+        "through kernel_shard.fused_mlp_sharded in the same expression",
     ("ops/fused_ffn.py", "quant_dot"):
         "the pure-XLA oracle/backward (use_pallas=False reference path "
         "— partitions like any dot)",

@@ -152,7 +152,7 @@ def _spawn(workdir: str, pod: bool, pi: int = 0, die_at: int = 0,
     for k in ("FDT_POD_INDEX", "FDT_POD_COUNT", "FDT_SLICE_COUNT",
               "FDT_FAULT_HOST", "FDT_FAULT_SLICE",
               "FDT_FAULT_DIE_AT_STEP", "FDT_SMOKE_CKPT_EVERY",
-              "FDT_SMOKE_EXEC_CACHE", "FDT_COMPILATION_CACHE"):
+              "FDT_SMOKE_EXEC_CACHE", "JAX_COMPILATION_CACHE_DIR"):
         env.pop(k, None)
     if extra_env:
         env.update(extra_env)
@@ -349,13 +349,13 @@ def _run_cache_scenario(check, ref_digest: str,
         # which is exactly the tier the twins A/B.
         _join(_spawn(workdir, pod=False,
                      extra_env={**env,
-                                "FDT_COMPILATION_CACHE":
+                                "JAX_COMPILATION_CACHE_DIR":
                                     tempfile.mkdtemp(prefix="fdt_xla_"),
                                 "FDT_FAULT_DIE_AT_STEP": str(die_at)}),
               f"{mode} crash", expect_fail=True)
         runs[mode] = _join(
             _spawn(workdir, pod=False,
-                   extra_env={**env, "FDT_COMPILATION_CACHE":
+                   extra_env={**env, "JAX_COMPILATION_CACHE_DIR":
                               tempfile.mkdtemp(prefix="fdt_xla_")}),
             f"{mode} relaunch")
         try:

@@ -612,3 +612,96 @@ class TestKernelRoutingLint:
         # every ALLOWED_CALLERS pair is absent from the scratch package:
         # rule 3 reports the rot instead of silently passing
         assert any(p.startswith("rule 3") for p in problems)
+
+
+# -------------------------------------------------------------------------
+# PR 21: the same layer over the DATA axes — on a real chip a Mosaic
+# kernel is refused on ANY mesh of more than one device unless it sits
+# inside shard_map, so flash, the MLP head and the quant GEMM run per
+# shard with batch rows over dp (and replicated over whatever else)
+# -------------------------------------------------------------------------
+
+class TestDataAxes:
+    def test_data_sharded_predicate(self, requires_devices, devices8):
+        requires_devices(8)
+        assert kernel_shard.data_sharded(make_mesh(("dp",), (8,), devices8))
+        assert kernel_shard.data_sharded(
+            make_mesh(("dp", "fsdp"), (2, 4), devices8))
+        assert not kernel_shard.data_sharded(
+            make_mesh(("dp", "tp"), (4, 2), devices8))   # tp routes
+        assert not kernel_shard.data_sharded(
+            make_mesh(("dp",), (1,), devices8[:1]))      # one device
+        assert not kernel_shard.data_sharded(None)
+
+    def test_flash_on_dp_mesh_matches_unsharded(self, requires_devices,
+                                                devices8, monkeypatch):
+        """flash fwd + grads with in-kernel dropout over dp=8: the same
+        masks (global (b, h) addressing) and values as one device."""
+        requires_devices(8)
+        from faster_distributed_training_tpu.ops.flash_attention import (
+            flash_attention)
+        monkeypatch.setenv("FDT_FORCE_PALLAS_INTERPRET", "1")
+        mesh = make_mesh(("dp",), (8,), devices8)
+        q, k, v, mask = TestFlashHeadSharded()._qkvm()
+        kw = dict(dropout_rate=0.2, dropout_seed=jnp.uint32(5))
+
+        def loss(fn):
+            return lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_) ** 2)
+
+        ref = jax.value_and_grad(loss(lambda *a: flash_attention(
+            *a, mask=mask, **kw)), argnums=(0, 1, 2))
+        got = jax.value_and_grad(loss(
+            lambda *a: kernel_shard.flash_attention_sharded(
+                *a, mask, mesh, **kw)), argnums=(0, 1, 2))
+        _tree_allclose(jax.jit(got)(q, k, v), jax.jit(ref)(q, k, v),
+                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("axes,shape", [(("dp",), (8,)),
+                                            (("dp", "tp"), (4, 2))])
+    def test_mlp_head_matches_unsharded(self, requires_devices, devices8,
+                                        axes, shape):
+        """The Pallas classifier head per shard — value AND gradients
+        (weights replicated at the boundary; on a tp mesh the head runs
+        replicated over tp and the cotangents must not double)."""
+        requires_devices(8)
+        from faster_distributed_training_tpu.ops.fused_mlp import (
+            fused_mlp_pallas)
+        mesh = make_mesh(axes, shape, devices8)
+        rr = np.random.default_rng(3)
+        x, w1, b1, w2, b2 = (jnp.asarray(rr.normal(size=s), jnp.float32)
+                             for s in ((8, 16), (32, 16), (1, 32),
+                                       (4, 32), (1, 4)))
+
+        def loss(fn):
+            return lambda *a: jnp.sum(fn(*a) ** 2)
+
+        ref = jax.value_and_grad(loss(fused_mlp_pallas),
+                                 argnums=(0, 1, 2, 3, 4))
+        got = jax.value_and_grad(
+            loss(lambda *a: kernel_shard.fused_mlp_sharded(*a, mesh)),
+            argnums=(0, 1, 2, 3, 4))
+        _tree_allclose(jax.jit(got)(x, w1, b1, w2, b2),
+                       jax.jit(ref)(x, w1, b1, w2, b2),
+                       rtol=2e-5, atol=2e-5)
+
+    def test_quant_dense_on_dp_mesh_matches_unsharded(
+            self, requires_devices, devices8):
+        requires_devices(8)
+        mesh = make_mesh(("dp",), (8,), devices8)
+        assert kernel_shard.quant_tp_routed(mesh, 1, (16, 32), None)
+        assert not kernel_shard.quant_tp_routed(mesh, 1, (16, 32), False)
+        rr = np.random.default_rng(4)
+        x = jnp.asarray(rr.normal(size=(16, 16)), jnp.float32)
+        w = jnp.asarray(rr.normal(size=(16, 32)), jnp.float32)
+        sx, sw = jnp.float32(20.0), jnp.float32(30.0)
+
+        def loss(fn):
+            return lambda x_, w_: jnp.sum(fn(x_, w_) ** 2)
+
+        ref = jax.value_and_grad(loss(lambda x_, w_: Q.quant_dot(
+            x_, w_, sx, sw, "int8", use_pallas=False)), argnums=(0, 1))
+        got = jax.value_and_grad(loss(
+            lambda x_, w_: kernel_shard.quant_dense_sharded(
+                x_, w_, sx, sw, "int8", mesh, tp_dim=1)), argnums=(0, 1))
+        _tree_allclose(jax.jit(got)(x, w), jax.jit(ref)(x, w),
+                       rtol=2e-5, atol=2e-5)

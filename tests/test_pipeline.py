@@ -275,21 +275,25 @@ class TestIciDeviceMeshDcn:
         assert got.shape == (4, 2)
 
     def test_tp_never_spans_dcn(self, monkeypatch):
-        # a tp-only mesh cannot absorb the process count -> None (the
-        # caller's plain-reshape fallback), never a tp DCN factoring
+        # a tp-only mesh cannot absorb the process count -> an error
+        # with the cause in it (no silent plain-reshape fallback),
+        # never a tp DCN factoring
         calls = self._capture(monkeypatch)
-        assert _ici_device_mesh((4,), ("tp",)) is None
+        with pytest.raises(RuntimeError, match="never span DCN"):
+            _ici_device_mesh((4,), ("tp",))
         assert "args" not in calls
-        # pp present but indivisible, dp too small: same fallback
-        assert _ici_device_mesh((3, 2), ("pp", "tp")) is None
+        # pp present but indivisible, dp too small: same error
+        with pytest.raises(RuntimeError, match="divisible"):
+            _ici_device_mesh((3, 2), ("pp", "tp"))
 
-    def test_topology_failure_falls_back_none(self, monkeypatch):
+    def test_topology_failure_raises_with_cause(self, monkeypatch):
         import jax.experimental.mesh_utils as mu
         monkeypatch.setattr(jax, "process_count", lambda: 2)
         monkeypatch.setattr(mu, "create_hybrid_device_mesh",
                             lambda *a, **k: (_ for _ in ()).throw(
                                 RuntimeError("no topology")))
-        assert _ici_device_mesh((2, 2, 2), ("dp", "tp", "pp")) is None
+        with pytest.raises(RuntimeError, match="no topology"):
+            _ici_device_mesh((2, 2, 2), ("dp", "tp", "pp"))
 
     def test_single_process_three_axes(self, requires_devices):
         requires_devices(8)

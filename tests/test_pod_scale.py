@@ -161,7 +161,7 @@ class TestShardedResidency:
         from faster_distributed_training_tpu.train import (
             create_train_state, make_fused_train_step)
 
-        # the two fused programs dominate this test's cost; the ISA-keyed
+        # the two fused programs dominate this test's cost; the
         # persistent cache (the same one every run_training e2e test
         # uses) makes re-runs compile-free
         enable_compilation_cache()
@@ -564,24 +564,6 @@ class TestRetentionDeleteHook:
         assert m._name(3) in deleted          # torn dir swept
         assert not os.path.exists(torn)
         assert os.path.isdir(os.path.join(str(tmp_path), m._name(4)))
-
-
-class TestDonationVersionGate:
-    """Satellite: the r7 CPU donation workaround is version-gated — the
-    ROADMAP 'retest when jax moves past 0.4.x' is automatic."""
-
-    @pytest.mark.parametrize("version,needed", [
-        ("0.4.36", True), ("0.4.9", True), ("0.3.25", True),
-        ("0.5.0", False), ("0.6.2", False), ("1.0.0", False),
-        ("", True), ("garbage", True), (None, None)])
-    def test_predicate(self, version, needed):
-        from faster_distributed_training_tpu.cli import (
-            donation_workaround_needed)
-        if version is None:
-            # container default must resolve without raising
-            assert donation_workaround_needed() in (True, False)
-        else:
-            assert donation_workaround_needed(version) is needed
 
 
 class TestPackedMetricCollective:

@@ -63,7 +63,7 @@ class TestObservedJit:
         assert v["cache"] in ("hit", "miss", "below_threshold", "off",
                               "unknown")
         assert v["cache_method"] in ("dir_stat", "timing_threshold",
-                                     "none")
+                                     "jax_event", "none")
         # sha256 prefix of lowered.as_text() (16 hex chars) unless the
         # env kill switch stripped it
         assert len(v["fingerprint"]) in (0, 16)
