@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
                     Sequence, Tuple)
 
@@ -421,32 +422,75 @@ class ParallelBatchIterator:
                 yield fut.result()   # in-order; re-raises worker errors
 
 
+class DevicePrefetch:
+    """The iterator :func:`device_prefetch` returns.  After each
+    ``__next__``: ``wait_s`` = host seconds that call spent in the
+    loader's ``next()``, ``h2d_s`` = host seconds it spent inside
+    ``put_fn`` (staging a LATER batch; the first call primes ``depth``
+    batches, so it carries them all).  Each is wrapped in its trace
+    phase (``fdt/data_wait``, ``fdt/h2d`` — telemetry/spans.py), tagged
+    with ``step``, which the consumer sets before calling ``next``."""
+
+    def __init__(self, iterator: Iterable, put_fn: Callable[[Any], Any],
+                 depth: int = 2):
+        # imported here: telemetry's package import reaches train/, which
+        # imports this module
+        from faster_distributed_training_tpu.telemetry.spans import phase
+        self._phase = phase
+        self._it = iter(iterator)
+        self._put = put_fn
+        self._depth = depth
+        self._staged: list = []
+        self._primed = depth <= 0
+        self._exhausted = False
+        self.step: Optional[int] = None
+        self.wait_s = 0.0
+        self.h2d_s = 0.0
+
+    def __iter__(self) -> "DevicePrefetch":
+        return self
+
+    def _stage(self) -> bool:
+        """Read one batch and put it on the device; False at the end of
+        the source.  Once exhausted, never calls next() again — not every
+        iterator keeps raising StopIteration (PrefetchIterator's queue
+        would block)."""
+        if self._exhausted:
+            return False
+        t0 = time.monotonic()
+        try:
+            with self._phase("data_wait", step=self.step):
+                item = next(self._it)
+        except StopIteration:
+            self._exhausted = True
+            self.wait_s += time.monotonic() - t0
+            return False
+        t1 = time.monotonic()
+        with self._phase("h2d", step=self.step):
+            self._staged.append(self._put(item))
+        self.wait_s += t1 - t0
+        self.h2d_s += time.monotonic() - t1
+        return True
+
+    def __next__(self):
+        self.wait_s = self.h2d_s = 0.0
+        if not self._primed:
+            self._primed = True
+            while len(self._staged) < self._depth and self._stage():
+                pass
+        # stage the NEXT batch before yielding the current one so its
+        # transfer overlaps the consumer's compute (depth <= 0: this IS
+        # the current one — fully synchronous, no double buffering)
+        self._stage()
+        if not self._staged:
+            raise StopIteration
+        return self._staged.pop(0)
+
+
 def device_prefetch(iterator: Iterable, put_fn: Callable[[Any], Any],
-                    depth: int = 2) -> Iterator:
+                    depth: int = 2) -> DevicePrefetch:
     """Keep `depth` batches already transferred to device ahead of the
     consumer — overlaps H2D with compute like pin_memory+non_blocking
     (resnet50_test.py:522).  depth <= 0 = fully synchronous transfer
     per batch (the bag-of-tricks OFF arm: no double buffering)."""
-    if depth <= 0:
-        for item in iterator:
-            yield put_fn(item)
-        return
-    staged = []
-    it = iter(iterator)
-    exhausted = False
-    try:
-        for _ in range(depth):
-            staged.append(put_fn(next(it)))
-    except StopIteration:
-        exhausted = True
-    while staged:
-        if not exhausted:
-            # stage the NEXT batch before yielding the current one so its
-            # transfer overlaps the consumer's compute; once exhausted,
-            # never call next() again — not every iterator keeps raising
-            # StopIteration (PrefetchIterator's queue would block)
-            try:
-                staged.append(put_fn(next(it)))
-            except StopIteration:
-                exhausted = True
-        yield staged.pop(0)
+    return DevicePrefetch(iterator, put_fn, depth)

@@ -224,6 +224,7 @@ def _flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=pallas_target.interpret(),
+        name="fdt_flash_fwd_lse" if emit_lse else "fdt_flash_fwd",
     )(q, k, v, bias, seed)
     if emit_lse:
         return res[0][:, :Lq, :], res[1][:, :Lq, 0]
@@ -373,6 +374,7 @@ def _flash_fwd_kblocked(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=pallas_target.interpret(),
+        name="fdt_flash_fwd_kblocked",
     )(q, k, v, bias, seed)
     return out[:, :Lq], lse[:, :Lq, 0]
 
@@ -507,6 +509,7 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
             out_shape=jax.ShapeDtypeStruct((N, Lqp, D), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interp,
+            name="fdt_flash_bwd_dq",
         )(qp, kp, vp, bias, gn, lse128, delta128, seed)
         dk, dv = pl.pallas_call(
             dkv_kernel,
@@ -532,6 +535,7 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)],
             interpret=interp,
+            name="fdt_flash_bwd_dkv",
         )(qp, kp, vp, bias, gn, lse128, delta128, seed)
         shape4 = lambda x, L: x[:, :L].reshape(B, H, L, D)  # noqa: E731
         return (shape4(dq, Lq).astype(q.dtype),
@@ -836,6 +840,7 @@ def _flash_bwd_pallas_stats(q, k, v, key_bias, seed3, dropout_rate,
                 jax.ShapeDtypeStruct((N, Lk, D), jnp.float32),
             ],
             interpret=pallas_target.interpret(),
+            name="fdt_flash_bwd_fused",
         )(qp, kn, vn, bias, gp, lse128, delta128, seed)
         shape4 = lambda x, L: x.reshape(B, H, L, D)  # noqa: E731
         return (shape4(dq[:, :Lq], Lq).astype(q.dtype),
@@ -967,6 +972,7 @@ def _flash_bwd_pallas(q, k, v, key_bias, seed3, dropout_rate,
                 jax.ShapeDtypeStruct((N, Lk, D), jnp.float32),
             ],
             interpret=pallas_target.interpret(),
+            name="fdt_flash_bwd_recompute",
         )(qp, kn, vn, bias, gp, seed)
         shape4 = lambda x, L: x.reshape(B, H, L, D)  # noqa: E731
         return (shape4(dq[:, :Lq], Lq).astype(q.dtype),

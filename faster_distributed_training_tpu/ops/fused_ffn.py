@@ -256,6 +256,7 @@ def _ffn_fwd_pallas(h2d, ln_scale, ln_bias, w1, b1, w2, b2, seeds,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), h2d.dtype),
         interpret=pallas_target.interpret(),
+        name="fdt_fused_ffn_fwd",
     )(h2d, ln_scale.reshape(1, d), ln_bias.reshape(1, d), w1,
       b1.reshape(1, d_ff), w2, b2.reshape(1, d), seeds)
     return out[:B] if pad else out
@@ -691,6 +692,7 @@ def _ffn_fwd_pallas2(h2d, ln_scale, ln_bias, w1, b1, w2, b2, seeds,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=pallas_target.interpret(),
+        name="fdt_fused_ffn_fwd_general",
     )(h2d, ln_scale.reshape(1, d), ln_bias.reshape(1, d), w1,
       b1.reshape(1, d_ff), w2, b2.reshape(1, d_out), seeds,
       scales.reshape(1, 4))

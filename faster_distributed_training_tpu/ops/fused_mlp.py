@@ -135,6 +135,7 @@ def _mlp_fwd_pallas(x2d: jax.Array, w1: jax.Array, b1: jax.Array,
         out_specs=pl.BlockSpec((block_b, d_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_b, d_out), x2d.dtype),
         interpret=pallas_target.interpret(),
+        name="fdt_fused_mlp",
     )(x2d, w1.T, jnp.reshape(b1, (1, d_h)), w2.T, jnp.reshape(b2, (1, d_out)))
     return out[:B] if pad else out
 

@@ -282,6 +282,7 @@ def quant_dot_pallas(xq: jax.Array, wq: jax.Array, sx: jax.Array,
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * br, n), out_dtype),
         interpret=pallas_target.interpret(),
+        name="fdt_quant_matmul",
     )(xq, wq, inv)
     return out[:m] if pad else out
 

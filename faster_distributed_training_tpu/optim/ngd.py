@@ -142,58 +142,61 @@ def _core_step(w, d, rho, x, tr_xxt, updating, hp: NGDHyperParams):
         return w, d, rho
 
     def do_update(_):
-        j = h.T @ x                   # J_t = H_t^T X_t          (rank, dim)
-        if n_rows > dim:              # static shape choice (ngd:214-217)
-            l_mat = j @ w.T
-        else:
-            l_mat = h.T @ h
-        k_mat = j @ j.T
+        # scopes enter by `with`, never by a wrapper: a frame more on the
+        # tracing stack costs set-up seconds on the chip's host (PERF.md)
+        with jax.named_scope("fisher_update"):
+            j = h.T @ x               # J_t = H_t^T X_t          (rank, dim)
+            if n_rows > dim:              # static shape choice (ngd:214-217)
+                l_mat = j @ w.T
+            else:
+                l_mat = h.T @ h
+            k_mat = j @ j.T
 
-        d_sum = jnp.sum(d)
-        beta = rho * (1.0 + alpha) + alpha * d_sum / dim
-        e = 1.0 / (beta / d + 1.0)
-        inv_sqrt_e = 1.0 / jnp.sqrt(e)
-        # z_t_scale keeps Z_t (4th-power-of-gradients) in range (ngd:240)
-        z_scale = jnp.maximum(1.0, jnp.trace(k_mat))
-        d_plus_rho = d + rho
-        inv_sqrt_e_outer = ((eta_n ** 2) / z_scale) * jnp.outer(inv_sqrt_e,
-                                                                inv_sqrt_e)
-        op1 = (eta_n * (1.0 - eta) / z_scale) * jnp.outer(
-            inv_sqrt_e, inv_sqrt_e * d_plus_rho)
-        z = (k_mat * inv_sqrt_e_outer + l_mat * (op1 + op1.T)
-             + jnp.diag(((1.0 - eta) ** 2 / z_scale)
-                        * d_plus_rho * d_plus_rho))
+            d_sum = jnp.sum(d)
+            beta = rho * (1.0 + alpha) + alpha * d_sum / dim
+            e = 1.0 / (beta / d + 1.0)
+            inv_sqrt_e = 1.0 / jnp.sqrt(e)
+            # z_t_scale keeps Z_t (4th-power-of-gradients) in range (ngd:240)
+            z_scale = jnp.maximum(1.0, jnp.trace(k_mat))
+            d_plus_rho = d + rho
+            inv_sqrt_e_outer = ((eta_n ** 2) / z_scale) * jnp.outer(inv_sqrt_e,
+                                                                    inv_sqrt_e)
+            op1 = (eta_n * (1.0 - eta) / z_scale) * jnp.outer(
+                inv_sqrt_e, inv_sqrt_e * d_plus_rho)
+            z = (k_mat * inv_sqrt_e_outer + l_mat * (op1 + op1.T)
+                 + jnp.diag(((1.0 - eta) ** 2 / z_scale)
+                            * d_plus_rho * d_plus_rho))
 
-        # (rank, rank) symmetric eigendecomposition ON DEVICE — the
-        # reference ships Z_t to the CPU here (ngd_optimizer.py:265).
-        # Symmetrize first: K/L are symmetric only up to rounding, and eigh
-        # reads a single triangle.
-        z = 0.5 * (z + z.T)
-        c, u = jnp.linalg.eigh(z)
-        c = c[::-1]                    # descending
-        u = u[:, ::-1]
-        c_floor = ((rho * (1.0 - eta)) ** 2) / z_scale
-        c = jnp.maximum(c, c_floor)
-        sqrt_c = jnp.sqrt(c) * jnp.sqrt(z_scale)
-        inv_sqrt_c = 1.0 / sqrt_c
+            # (rank, rank) symmetric eigendecomposition ON DEVICE — the
+            # reference ships Z_t to the CPU here (ngd_optimizer.py:265).
+            # Symmetrize first: K/L are symmetric only up to rounding, and eigh
+            # reads a single triangle.
+            z = 0.5 * (z + z.T)
+            c, u = jnp.linalg.eigh(z)
+            c = c[::-1]                    # descending
+            u = u[:, ::-1]
+            c_floor = ((rho * (1.0 - eta)) ** 2) / z_scale
+            c = jnp.maximum(c, c_floor)
+            sqrt_c = jnp.sqrt(c) * jnp.sqrt(z_scale)
+            inv_sqrt_c = 1.0 / sqrt_c
 
-        rho_new = (1.0 / (dim - rank)) * (
-            eta_n * tr_xxt + (1.0 - eta) * (dim * rho + d_sum)
-            - jnp.sum(sqrt_c))
-        floor_val = jnp.maximum(EPSILON, DELTA * jnp.max(sqrt_c))
-        d_new = jnp.maximum(sqrt_c - rho_new, floor_val)
-        rho_new = jnp.maximum(rho_new, floor_val)
+            rho_new = (1.0 / (dim - rank)) * (
+                eta_n * tr_xxt + (1.0 - eta) * (dim * rho + d_sum)
+                - jnp.sum(sqrt_c))
+            floor_val = jnp.maximum(EPSILON, DELTA * jnp.max(sqrt_c))
+            d_new = jnp.maximum(sqrt_c - rho_new, floor_val)
+            rho_new = jnp.maximum(rho_new, floor_val)
 
-        beta_new = rho_new * (1.0 + alpha) + alpha * jnp.sum(d_new) / dim
-        e_new = 1.0 / (beta_new / d_new + 1.0)
-        sqrt_e_new = jnp.sqrt(e_new)
+            beta_new = rho_new * (1.0 + alpha) + alpha * jnp.sum(d_new) / dim
+            e_new = 1.0 / (beta_new / d_new + 1.0)
+            sqrt_e_new = jnp.sqrt(e_new)
 
-        # B_t = J_t + (1-eta)/(eta/N) (D_t + rho_t I) W_t   (ngd:308-311)
-        w_coeff = ((1.0 - eta) / eta_n) * d_plus_rho
-        b = j + w_coeff[:, None] * w
-        # A_t = (eta/N) E_{t+1}^{1/2} C_t^{-1/2} U_t^T E_t^{-1/2}
-        a = u.T * jnp.outer(eta_n * sqrt_e_new * inv_sqrt_c, inv_sqrt_e)
-        return a @ b, d_new, rho_new
+            # B_t = J_t + (1-eta)/(eta/N) (D_t + rho_t I) W_t   (ngd:308-311)
+            w_coeff = ((1.0 - eta) / eta_n) * d_plus_rho
+            b = j + w_coeff[:, None] * w
+            # A_t = (eta/N) E_{t+1}^{1/2} C_t^{-1/2} U_t^T E_t^{-1/2}
+            a = u.T * jnp.outer(eta_n * sqrt_e_new * inv_sqrt_c, inv_sqrt_e)
+            return a @ b, d_new, rho_new
 
     w1, d1, rho1 = lax.cond(updating, do_update, no_update, operand=None)
     return (w1, d1, rho1), x_hat
@@ -481,27 +484,28 @@ def scale_by_ngd(alpha: float = 4.0, rank: int = -1, update_period: int = 4,
 
     def grouped_update(updates, state, params=None):
         del params
-        flat, treedef = jax.tree.flatten(updates)
-        orig_dtypes = [g.dtype for g in flat]
-        work = [g.astype(precond_dtype) for g in flat]
-        shapes = [tuple(np.shape(g)) for g in flat]
-        plan = _build_plan(shapes, hp)
-        new_groups = dict(state.groups)
-        for r, round_groups in enumerate(plan):
-            for (n, dim, rank_), members in round_groups.items():
-                key = _group_key(r, n, dim, rank_)
-                moved = [jnp.moveaxis(work[i], r, -1) for i in members]
-                xs = jnp.stack([m.reshape(n, dim) for m in moved])
-                gs = new_groups[key]
-                gw, gd, grho, outs = _group_precondition(
-                    gs.w, gs.d, gs.rho, state.t, xs, hp)
-                new_groups[key] = GroupState(gw, gd, grho)
-                for slot, i in enumerate(members):
-                    out = outs[slot].reshape(moved[slot].shape)
-                    work[i] = jnp.moveaxis(out, -1, r)
-        out_flat = [g.astype(dt) for g, dt in zip(work, orig_dtypes)]
-        return (treedef.unflatten(out_flat),
-                ScaleByNGDState(t=state.t + 1, axes=(), groups=new_groups))
+        with jax.named_scope("ngd"):
+            flat, treedef = jax.tree.flatten(updates)
+            orig_dtypes = [g.dtype for g in flat]
+            work = [g.astype(precond_dtype) for g in flat]
+            shapes = [tuple(np.shape(g)) for g in flat]
+            plan = _build_plan(shapes, hp)
+            new_groups = dict(state.groups)
+            for r, round_groups in enumerate(plan):
+                for (n, dim, rank_), members in round_groups.items():
+                    key = _group_key(r, n, dim, rank_)
+                    moved = [jnp.moveaxis(work[i], r, -1) for i in members]
+                    xs = jnp.stack([m.reshape(n, dim) for m in moved])
+                    gs = new_groups[key]
+                    gw, gd, grho, outs = _group_precondition(
+                        gs.w, gs.d, gs.rho, state.t, xs, hp)
+                    new_groups[key] = GroupState(gw, gd, grho)
+                    for slot, i in enumerate(members):
+                        out = outs[slot].reshape(moved[slot].shape)
+                        work[i] = jnp.moveaxis(out, -1, r)
+            out_flat = [g.astype(dt) for g, dt in zip(work, orig_dtypes)]
+            return (treedef.unflatten(out_flat),
+                    ScaleByNGDState(t=state.t + 1, axes=(), groups=new_groups))
 
     # -------------------- ungrouped (reference-shaped) --------------------
     def ungrouped_init(params):
@@ -512,26 +516,27 @@ def scale_by_ngd(alpha: float = 4.0, rank: int = -1, update_period: int = 4,
 
     def ungrouped_update(updates, state, params=None):
         del params
+        with jax.named_scope("ngd"):
 
-        def per_leaf(g, ax_states):
-            orig_dtype = g.dtype
-            g = g.astype(precond_dtype)
-            new_states = []
-            for axis, st in enumerate(ax_states):
-                if st is None:
-                    new_states.append(None)
-                    continue
-                st, g = precondition(st, g, axis, hp)
-                new_states.append(st)
-            return g.astype(orig_dtype), tuple(new_states)
+            def per_leaf(g, ax_states):
+                orig_dtype = g.dtype
+                g = g.astype(precond_dtype)
+                new_states = []
+                for axis, st in enumerate(ax_states):
+                    if st is None:
+                        new_states.append(None)
+                        continue
+                    st, g = precondition(st, g, axis, hp)
+                    new_states.append(st)
+                return g.astype(orig_dtype), tuple(new_states)
 
-        flat_updates, treedef = jax.tree.flatten(updates)
-        flat_axes = treedef.flatten_up_to(state.axes)
-        out = [per_leaf(g, ax) for g, ax in zip(flat_updates, flat_axes)]
-        new_updates = treedef.unflatten([o[0] for o in out])
-        new_axes = treedef.unflatten([o[1] for o in out])
-        return new_updates, ScaleByNGDState(t=state.t + 1, axes=new_axes,
-                                            groups={})
+            flat_updates, treedef = jax.tree.flatten(updates)
+            flat_axes = treedef.flatten_up_to(state.axes)
+            out = [per_leaf(g, ax) for g, ax in zip(flat_updates, flat_axes)]
+            new_updates = treedef.unflatten([o[0] for o in out])
+            new_axes = treedef.unflatten([o[1] for o in out])
+            return new_updates, ScaleByNGDState(t=state.t + 1, axes=new_axes,
+                                                groups={})
 
     if grouped:
         return optax.GradientTransformation(grouped_init, grouped_update)

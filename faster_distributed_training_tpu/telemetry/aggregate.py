@@ -23,12 +23,30 @@ median (``statistics.median_low``) so a 2-host pod can still flag its
 slow half — an interpolated median of [fast, slow] sits between them
 and a 3x-slow host would never cross 2x it.
 
-Step-time definition (:func:`step_time_ms` — the ONE place it lives;
-per-host stats, the pooled pod percentiles, and the incremental fold
-all call it): ``dispatch_ms / k`` of non-``compile`` step records — the
-jitted call alone, per train step; data wait and checkpoint blocking
-are broken out per record and excluded, and first-execution (compile)
-records never pollute the percentiles.
+Step-time definition (this module is the ONE place it lives; per-host
+stats, the pooled pod percentiles, and the incremental fold all go
+through :func:`_accumulate` / :func:`_step_samples`):
+
+  * where a host's records carry ``fence_ms`` (a ``--log_every``
+    read-back or the epoch's closing fence drained the device —
+    recorder.py), one sample per fenced window, ``fence_ms /
+    fence_steps`` (:func:`fenced_step_ms`): host wall over a window that
+    begins and ends with the device drained — a real step time, stalls
+    (saves, hooks) inside it;
+  * a host WITHOUT any such record (files older than PR 24; a first
+    epoch shorter than ``--log_every``, whose one window holds the
+    compile) keeps :func:`step_time_ms`, ``dispatch_ms / k`` of
+    non-``compile`` records.
+    That is the ENQUEUE, not the step: under async dispatch a call
+    returns at once or blocks for a whole device step (recorder.py's
+    wall-time caveat), so its percentiles bound nothing — it stays only
+    so that such files still fold, and ``step_time_source`` in every
+    host's stats says which definition it got.
+
+Data wait and checkpoint blocking are broken out per record
+(``data_ms_total`` / ``block_ms_total``), and first-execution (compile)
+records never pollute either definition (a window that holds one
+carries no fence).
 
 Run scoping: markers are TIME-SCOPED like the r10 EXIT markers —
 process 0 honors a marker only when it is newer than this run's
@@ -80,16 +98,28 @@ def publish_epoch_marker(directory: str, epoch: int, pi: int) -> None:
 
 def step_time_ms(rec: dict, upto_epoch: Optional[int] = None
                  ) -> Optional[float]:
-    """Per-train-step time of one JSONL record, or None when the record
-    doesn't contribute (non-step kinds, compile records, epochs past
-    ``upto_epoch``).  THE step-time definition — every consumer
-    (per-host stats, pooled percentiles, incremental fold, report
-    script) goes through here so they can never disagree."""
+    """Per-train-step ENQUEUE time of one JSONL record, or None when the
+    record doesn't contribute (non-step kinds, compile records, epochs
+    past ``upto_epoch``).  The fallback definition (module docstring):
+    used for a host none of whose records carries a fence."""
     if rec.get("kind") != "step" or rec.get("compile"):
         return None
     if upto_epoch is not None and rec.get("epoch", 0) > upto_epoch:
         return None
     return rec["dispatch_ms"] / max(rec.get("k", 1), 1)
+
+
+def fenced_step_ms(rec: dict, upto_epoch: Optional[int] = None
+                   ) -> Optional[float]:
+    """``fence_ms / fence_steps`` of a record that closed a fenced window
+    (a step record at a read-back, or the epoch's ``epoch_fence``), else
+    None — the preferred definition (module docstring)."""
+    if (rec.get("kind") not in ("step", "epoch_fence")
+            or not rec.get("fence_steps")):
+        return None
+    if upto_epoch is not None and rec.get("epoch", 0) > upto_epoch:
+        return None
+    return rec["fence_ms"] / rec["fence_steps"]
 
 
 def read_host_records(directory: str) -> Dict[int, List[dict]]:
@@ -121,13 +151,16 @@ def read_host_records(directory: str) -> Dict[int, List[dict]]:
 # -- per-host reductions (shared by the stateless and incremental paths) --
 
 def _new_fold() -> dict:
-    return {"steps": 0, "records": 0, "per_step_ms": [],
+    return {"steps": 0, "records": 0, "per_step_ms": [], "fenced_ms": [],
             "ex_s_sum": 0.0, "ex_s_n": 0,
             "data_ms_total": 0.0, "block_ms_total": 0.0}
 
 
 def _accumulate(fold: dict, rec: dict,
                 upto_epoch: Optional[int] = None) -> None:
+    fenced = fenced_step_ms(rec, upto_epoch=upto_epoch)
+    if fenced is not None:
+        fold["fenced_ms"].append(fenced)
     t = step_time_ms(rec, upto_epoch=upto_epoch)
     if t is None:
         return
@@ -141,10 +174,18 @@ def _accumulate(fold: dict, rec: dict,
     fold["block_ms_total"] += float(rec.get("block_ms", 0.0))
 
 
+def _step_samples(fold: dict) -> List[float]:
+    """A host's step-time samples: its fenced windows where it has any,
+    else its per-record enqueue times (module docstring)."""
+    return fold["fenced_ms"] or fold["per_step_ms"]
+
+
 def _host_stats(fold: dict) -> dict:
-    stats = {"steps": fold["steps"], "records": fold["records"]}
+    stats = {"steps": fold["steps"], "records": fold["records"],
+             "step_time_source": ("fenced" if fold["fenced_ms"]
+                                  else "dispatch")}
     stats.update({f"step_ms_p{q}": v
-                  for q, v in percentiles(fold["per_step_ms"]).items()})
+                  for q, v in percentiles(_step_samples(fold)).items()})
     if fold["ex_s_n"]:
         stats["ex_s_mean"] = round(fold["ex_s_sum"] / fold["ex_s_n"], 1)
     stats["data_ms_total"] = round(fold["data_ms_total"], 1)
@@ -171,7 +212,7 @@ def span_breakdown(records: List[dict]) -> Dict[str, dict]:
 def aggregate_folds(folds: Dict[int, dict],
                     straggler_ratio: float = 2.0) -> dict:
     """One summary from per-host folds: per-host and pooled p50/p95/p99
-    per-step dispatch times + the straggler table (module docstring)."""
+    step times + the straggler table (module docstring)."""
     folds = {pi: f for pi, f in folds.items() if f["records"]}
     per_host = {pi: _host_stats(f) for pi, f in sorted(folds.items())}
     out: dict = {"hosts": {str(pi): st for pi, st in per_host.items()},
@@ -180,7 +221,7 @@ def aggregate_folds(folds: Dict[int, dict],
                  "stragglers": []}
     pooled: List[float] = []
     for f in folds.values():
-        pooled.extend(f["per_step_ms"])
+        pooled.extend(_step_samples(f))
     if pooled:
         out["pod"] = {"steps": sum(st["steps"]
                                    for st in per_host.values()),

@@ -29,14 +29,32 @@ by ``"kind"``:
 
   ``run_start``  {t, process_index, process_count, schema}
   ``step``       {step, epoch, n, k, wall_ms, dispatch_ms, data_ms,
-                  block_ms, examples, ex_s, compile?}
+                  block_ms, examples, ex_s, h2d_ms, compile?, sync_ms?,
+                  fence_steps?, fence_ms?}
                  step = global step AFTER the dispatch; n = step in
-                 epoch; wall_ms = full host wall since the previous
-                 record (data wait + dispatch + resilience hooks);
-                 dispatch_ms = the jitted call alone; ex_s =
+                 epoch; wall_ms = the loop iteration's whole host wall
+                 (data wait + dispatch + resilience hooks + the
+                 --log_every read-back when it fell here);
+                 dispatch_ms = the jitted call alone (the ENQUEUE: it
+                 returns at once or blocks for a whole device step,
+                 see the caveat below); data_ms = loader wait + H2D
+                 staging, of which h2d_ms inside put_fn; ex_s =
                  examples / wall; compile=true marks a first execution
                  (compile time — aggregation excludes these from
-                 step-time percentiles)
+                 step-time percentiles); sync_ms = host ms blocked in a
+                 device->host read (written only when non-zero);
+                 fence_steps / fence_ms = only on a dispatch that ended
+                 in the read-back, which drains the device: train steps
+                 and host wall ms since the previous such fence of this
+                 run_epoch call (or since its first dispatch began);
+                 the block_ms in between are NOT taken out (the device
+                 works through its queue while the host blocks) —
+                 fence_ms / fence_steps is the program's own FENCED
+                 step time
+  ``epoch_fence`` {step, epoch, fence_steps, fence_ms, sync_ms}
+                 run_epoch's closing fence closed the epoch's last
+                 fenced window (same meaning as the step record's
+                 fence fields; sync_ms = the wait of the fence itself)
   ``span``       {name, dur_ms, step?}           (telemetry/spans.py)
   ``epoch``      {epoch, steps, trained_steps, loss?, accuracy?,
                   wall_s, ex_s, peak_mem_bytes?, eval_loss?,
@@ -104,11 +122,17 @@ directory, exactly like the checkpoint dir (cli.attempt's docstring);
 the aggregation barrier is additionally time-scoped
 (telemetry/aggregate.py) so a reused directory's markers can't lie.
 
-Wall-time caveat, documented rather than hidden: per-dispatch wall time
-is HOST time between dispatch returns.  Under async dispatch the host
-can briefly run ahead of the device, but donated-buffer backpressure
-re-couples them within one step, so percentiles over an epoch track
-device step time; the bench arms remain the fenced ground truth.
+Wall-time caveat, documented rather than hidden: ``wall_ms`` and
+``dispatch_ms`` are HOST times, and under async dispatch they are not
+step times.  The host runs ahead of the device until the runtime's
+queue of in-flight executions is full; from then on a dispatch either
+returns at once or blocks for a whole device step (on the v5e, ResNet-50
+bs1024 at 160 ms a step: median ``dispatch_ms`` 3.8, mean 46.9, about a
+third of the calls blocked inside ``ExecutePrepare`` — PERF.md section
+5), so no percentile of them tracks the device.  The step time the
+program itself can vouch for is the FENCED one: ``fence_ms /
+fence_steps`` of the records that carry them (one per ``--log_every``
+window, closed by the read-back that drains the device).
 """
 
 from __future__ import annotations
@@ -119,7 +143,7 @@ import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 SCHEMA_VERSION = 1
 ENV_KILL = "FDT_TELEMETRY"
@@ -135,9 +159,16 @@ MANIFEST = "manifest.json"
 TELEMETRY_SCHEMA: Dict[str, Optional[frozenset]] = {
     "run_start": frozenset({"t", "process_index", "process_count",
                             "schema"}),
+    # h2d_ms / sync_ms / fence_steps / fence_ms (PR 24, append-only):
+    # the loop's host phases and its fenced step time (module docstring)
     "step": frozenset({"step", "epoch", "n", "k", "wall_ms",
                        "dispatch_ms", "data_ms", "block_ms", "examples",
-                       "ex_s", "compile"}),
+                       "ex_s", "compile", "h2d_ms", "sync_ms",
+                       "fence_steps", "fence_ms"}),
+    # PR 24, append-only: the epoch's last fenced window, closed by
+    # run_epoch's own fence (train/loop._DispatchClock.fence)
+    "epoch_fence": frozenset({"step", "epoch", "fence_steps", "fence_ms",
+                              "sync_ms"}),
     "span": frozenset({"name", "dur_ms", "step"}),
     # perplexity/eval_perplexity (r18 LM workload, append-only): only
     # emitted on --task lm runs (exp of the token-weighted epoch loss)
@@ -366,9 +397,15 @@ class TelemetryRecorder:
     def record_step(self, step: int, epoch: int, n: int, k: int,
                     wall_ms: float, dispatch_ms: float, examples: int,
                     data_ms: float = 0.0, block_ms: float = 0.0,
-                    compile_: bool = False) -> None:
+                    compile_: bool = False, h2d_ms: float = 0.0,
+                    sync_ms: float = 0.0,
+                    fence: Optional[Tuple[int, float]] = None) -> None:
+        """``fence`` = (train steps, host ms) of the fenced window this
+        dispatch closed; such a record is kept whatever the sampling
+        cadence (there is one per --log_every window and the fenced
+        step time is folded from them)."""
         self._steps_seen += 1
-        if (self.step_every > 1 and not compile_
+        if (self.step_every > 1 and not compile_ and fence is None
                 and self._steps_seen % self.step_every):
             return
         rec = {"kind": "step", "step": int(step), "epoch": int(epoch),
@@ -376,9 +413,15 @@ class TelemetryRecorder:
                "dispatch_ms": round(dispatch_ms, 3),
                "data_ms": round(data_ms, 3), "block_ms": round(block_ms, 3),
                "examples": int(examples),
-               "ex_s": round(examples / max(wall_ms / 1e3, 1e-9), 1)}
+               "ex_s": round(examples / max(wall_ms / 1e3, 1e-9), 1),
+               "h2d_ms": round(h2d_ms, 3)}
         if compile_:
             rec["compile"] = True
+        if sync_ms:
+            rec["sync_ms"] = round(sync_ms, 3)
+        if fence is not None:
+            rec["fence_steps"] = int(fence[0])
+            rec["fence_ms"] = round(fence[1], 3)
         self._append(rec)
 
     def next_step_kept(self) -> bool:

@@ -1,6 +1,9 @@
-"""Span API: one name, two observability surfaces.
+"""Span API: one vocabulary, ``fdt/<name>``, on the profiler's clock.
 
-``with spans.span("restore"):`` does two things at once:
+Two entry points write it:
+
+``with spans.span("restore"):`` — for BOUNDARY events (checkpoint,
+restore, eval, upload).  It does two things at once:
 
   * records the HOST wall time of the block into the active
     :class:`~faster_distributed_training_tpu.telemetry.recorder.
@@ -11,6 +14,14 @@
     or the windowed ``--profile_steps A:B``) the identical names appear
     on the XLA timeline — the JSONL numbers and the trace annotate each
     other instead of living in two vocabularies.
+
+``with spans.phase("dispatch", step=n):`` — for the HOT LOOP.  The bare
+trace annotation and nothing else: no lock, no ``_OPEN`` entry, no JSONL
+record (the step record carries the loop's host times; a TraceMe costs a
+level check when no profiler session is open).  The dispatching thread's
+iteration is TILED by sibling phases, none nested in another ``fdt/*``
+span, so a reduction that gives a device-idle gap to the host span
+covering most of it names exactly one phase.
 
 The recorder is installed process-globally (:func:`set_recorder`) rather
 than threaded through every constructor: the instrumented seams live in
@@ -33,6 +44,58 @@ are never renamed; README "Observability" documents them):
   ``rendezvous``            pod restore-agreement barrier (coordinator)
   ``eval``                  the per-epoch eval pass
   ``first_dispatch_compile`` first execution of a train program (compile)
+
+Hot-loop phases (:data:`PHASES`; trace only; ``step`` = the first train
+step of the dispatch the phase belongs to, which is the step record's
+``step`` at K=1 and ``step - k + 1`` under K-step dispatch):
+
+  ``data_wait``    the loader's ``next()`` (data/loader.device_prefetch;
+                   the fused-host group read; the stream window swap)
+  ``h2d``          ``put_fn``: host->device staging of a LATER batch
+  ``dispatch``     the jitted train-step call (the enqueue; it blocks
+                   when the runtime's queue of in-flight steps is full)
+  ``hooks``        ``Trainer._resilience_hooks`` (only with resilience)
+  ``readback``     ``float(metrics["loss"])`` at a ``--log_every``
+                   boundary: the loop's only device->host sync
+  ``epoch_fence``  ``run_epoch``'s closing ``block_until_ready``
+
+Device scopes (``jax.named_scope`` in train/steps.py and optim/ngd.py;
+metadata only: they live in the HLO's ``op_name`` debug locations, never
+in ``lowered.as_text()``, so program fingerprints and compile-cache keys
+do not move).  A transform wraps ONE path element (``jvp(fdt/model)``,
+``transpose(jvp(fdt/model))``), so readers match a scope as a substring
+of the op's ``op_name`` (telemetry/trace_report.py).  Enter them by
+``with``, never as a decorator or through a wrapper that makes the call:
+a Python frame more between the epoch loop and traced code cost 20 s of
+set-up on the chip's host (PERF.md section 6, PR 24):
+
+  ``fdt/augment``      in-graph crop/flip/normalize of uint8 images
+  ``fdt/mixup``        the image-space mixup variants
+  ``fdt/model``        ``state.apply_fn`` inside ``loss_fn``: forward =
+                       ``jvp(fdt/model)``, backward = its ``transpose``
+  ``fdt/loss``         the (mixup) criterion
+  ``fdt/grad_reduce``  ``reduce_grads`` + ``unscale_and_check``
+  ``fdt/optimizer``    ``state.apply_gradients``; inside it the bare
+                       children ``ngd`` (scale_by_ngd's update) and,
+                       inside that, ``fisher_update`` (the
+                       every-``update_period`` Fisher refresh)
+  ``fdt/quant_scale_refresh``  ops/quant.py's delayed-scaling roll
+
+Pallas kernel names (``name=`` on every ``pl.pallas_call``; a trace shows
+them in the custom call's name):
+
+  ``fdt_flash_fwd`` / ``fdt_flash_fwd_lse``  monolithic flash forward
+                       (without / with the saved row statistics)
+  ``fdt_flash_fwd_kblocked``   k-blocked flash forward (beyond the
+                       monolithic envelope)
+  ``fdt_flash_bwd_dq`` / ``fdt_flash_bwd_dkv``  k-blocked backward pair
+  ``fdt_flash_bwd_fused``      one-kernel backward from saved statistics
+  ``fdt_flash_bwd_recompute``  one-kernel backward that recomputes them
+  ``fdt_fused_ffn_fwd`` / ``fdt_fused_ffn_fwd_general``  the encoder's
+                       fused FFN forward: plain / quantized-or-tp-partial
+                       (its backward is XLA's)
+  ``fdt_fused_mlp``    the classifier head's fused MLP
+  ``fdt_quant_matmul`` the int8/fp8 quantized matmul
 """
 
 from __future__ import annotations
@@ -43,6 +106,12 @@ import time
 from typing import Iterator, List, Optional
 
 _ACTIVE = None   # the installed TelemetryRecorder (or None)
+
+# the hot loop's phases, labels built once (phase() is called several
+# times per dispatch)
+PHASES = ("data_wait", "h2d", "dispatch", "hooks", "readback",
+          "epoch_fence")
+_PHASE_LABEL = {name: f"fdt/{name}" for name in PHASES}
 
 # spans currently OPEN, any thread ({token: {name, t0, step, thread}}):
 # the crash flight recorder (telemetry/flight.py) reads this so a host
@@ -103,3 +172,14 @@ def span(name: str, step: Optional[int] = None) -> Iterator[None]:
         rec = _ACTIVE
         if rec is not None:
             rec.record_span(name, (time.monotonic() - t0) * 1e3, step=step)
+
+
+def phase(name: str, step: Optional[int] = None):
+    """``fdt/<name>`` on the profiler's timeline and nowhere else: the
+    hot loop's annotation (module docstring).  ``name`` is one of
+    :data:`PHASES`; ``step`` lands as the event's ``step`` stat."""
+    import jax
+
+    if step is None:
+        return jax.profiler.TraceAnnotation(_PHASE_LABEL[name])
+    return jax.profiler.TraceAnnotation(_PHASE_LABEL[name], step=step)

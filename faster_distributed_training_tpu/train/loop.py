@@ -59,6 +59,153 @@ def _stack_host_batches(group: List[Dict[str, Any]]) -> Dict[str, Any]:
     return out
 
 
+class _DispatchClock:
+    """The host side of ONE ``run_epoch`` call, shared by the four epoch
+    loops: the iteration's clock reads, its ``fdt/*`` trace phases
+    (telemetry/spans.py — siblings that tile the iteration, none nested
+    in another, all tagged with the iteration's ``step``), the
+    ``--log_every`` window and the step record.  An iteration is
+
+        clock.start(...)             # top of the iteration
+        ...the data phase...         # device_prefetch annotates itself;
+                                     # the other loops use clock.phase()
+        with clock.dispatch(kk):     # one block a segment
+            state, metrics = step(...)
+        ...acc / n / global_step bookkeeping...
+        state = clock.finish(state, n, run, metrics, key, group)
+
+    ``finish`` runs the resilience hooks, the ``--log_every`` read-back
+    and THEN writes the record, so ``wall_ms`` is the whole iteration.
+    The read-back drains the device, so each one closes a FENCED window:
+    train steps and host seconds since the previous one (or since this
+    call's first dispatch began, the device being drained between
+    ``run_epoch`` calls).  The seconds the loop spent blocked in hooks
+    are NOT taken out, unlike in ``_log_dispatch``'s ex/s line: the
+    host runs tens of steps ahead, so the device works through its
+    queue while the host blocks, and a window less its blocked time
+    read under the device's own busy time on the chip (PERF.md, PR 24);
+    the record's ``block_ms`` says how much there was.  A window that
+    held a program's first (compiling) dispatch carries no fence."""
+
+    def __init__(self, trainer: "Trainer", epoch: int, t0: float,
+                 start_step: int):
+        self.tr = trainer
+        self.epoch = epoch
+        self.last = (t0, start_step)      # _log_dispatch's (t, n)
+        self.fence_t: Optional[float] = None
+        self.fence_step = trainer.global_step
+        self.fence_clean = True
+        self.step = trainer.global_step + 1
+        self.want = True
+        self.t_rec = self.t_disp = self.t_done = self.t_hooks = t0
+        trainer._blocked_since_log = 0.0
+        trainer._sync_s = 0.0
+
+    def start(self, key: Optional[tuple] = None, due: bool = False) -> None:
+        """Top of an iteration.  ``key`` (where the loop knows its
+        program before the data phase) lets a dispatch whose record the
+        --telemetry_every cadence drops skip its telemetry-only clock
+        reads; ``due`` = it will cross a --log_every boundary, whose
+        record is always kept."""
+        tr = self.tr
+        self.step = tr.global_step + 1
+        self.want = key is None or due or tr._keep_dispatch_times(key)
+        self.t_rec = time.monotonic() if self.want else 0.0
+        self.t_disp = None
+
+    def phase(self, name: str):
+        return spans.phase(name, step=self.step)
+
+    def _close_window(self, now: float) -> Optional[tuple]:
+        """(train steps, host ms) of the fenced window that a drain at
+        ``now`` closes — None where it held a compiling dispatch — and
+        open the next one there."""
+        tr = self.tr
+        fence = None
+        if self.fence_clean and self.fence_t is not None:
+            fence = (tr.global_step - self.fence_step,
+                     (now - self.fence_t) * 1e3)
+        self.fence_t, self.fence_step = now, tr.global_step
+        self.fence_clean = True
+        return fence
+
+    @contextlib.contextmanager
+    def dispatch(self, kk: int):
+        """Round one jitted step call.  A context manager and not a
+        wrapper that makes the call: the call's Python stack stays as
+        deep as the loop's own, and a program's first call traces under
+        it (set-up time on the chip's host moved by 20 s with one frame
+        more — PERF.md, PR 24)."""
+        if self.t_disp is None:
+            first = self.fence_t is None
+            self.t_disp = (time.monotonic() if self.want or first
+                           else 0.0)
+            if first:
+                self.fence_t = self.t_disp
+        self.tr._prof_before(kk)
+        with spans.phase("dispatch", step=self.step):
+            yield
+        self.t_done = time.monotonic()
+
+    def finish(self, state: TrainState, n: int, run: int, metrics,
+               key: tuple, group: tuple, h2d_s: float = 0.0) -> TrainState:
+        """After the dispatch's bookkeeping (``n`` and ``global_step``
+        already advanced; ``run`` = train steps this iteration
+        dispatched): placement probe, profiler window, hooks, read-back,
+        record."""
+        tr = self.tr
+        if tr._sharding_expect is None:
+            tr._observe_state_placement(state)
+        tr._prof_after(metrics)
+        self.t_hooks = t_end = time.monotonic()
+        if tr.resilience is not None:
+            with spans.phase("hooks", step=self.step):
+                state = tr._resilience_hooks(state, self.epoch, n,
+                                             n_steps=run, metrics=metrics,
+                                             group=group)
+            t_end = time.monotonic()
+        block_s = t_end - self.t_done
+        tr._blocked_since_log += block_s
+        if key not in tr._dispatched:
+            self.fence_clean = False
+        fence = None
+        if tr._log_due(n, run):
+            self.last = tr._log_dispatch(self.epoch, n, run, metrics,
+                                         self.last)
+            fence = self._close_window(self.last[0])
+            t_end = time.monotonic()
+        sync_s, tr._sync_s = tr._sync_s, 0.0
+        want = self.want
+        tr._record_dispatch(
+            self.epoch, n, run, t_end - self.t_rec if want else 0.0,
+            self.t_done - self.t_disp if want else 0.0,
+            self.t_disp - self.t_rec if want else 0.0, block_s, key,
+            h2d_s=h2d_s, sync_s=sync_s, fence=fence)
+        return state
+
+    def fence(self, metrics) -> None:
+        """run_epoch's closing fence: dispatch is asynchronous, and
+        without it the reference-parity epoch timing
+        (resnet50_test.py:519,614) would measure the enqueue.  It drains
+        the device like a read-back, so it closes the last fenced window
+        too — as an ``epoch_fence`` record (the last step's record is
+        already written): an epoch shorter than --log_every, the paper's
+        ResNet at 48 steps, has no other."""
+        if metrics is None:
+            return
+        tr = self.tr
+        t0 = time.monotonic()
+        with spans.phase("epoch_fence", step=tr.global_step):
+            jax.block_until_ready(metrics["loss"])
+        now = time.monotonic()
+        fence = self._close_window(now)
+        if tr.telemetry is not None and fence is not None and fence[0]:
+            tr.telemetry.recorder.record_event(
+                "epoch_fence", step=tr.global_step, epoch=self.epoch,
+                fence_steps=fence[0], fence_ms=round(fence[1], 3),
+                sync_ms=round((now - t0) * 1e3, 3))
+
+
 class Trainer:
     """Owns the compiled steps and the epoch loop."""
 
@@ -167,6 +314,10 @@ class Trainer:
         # printed ex/s is actual step throughput, not wall throughput
         # diluted by a save that happened to land in the window
         self._blocked_since_log = 0.0
+        # host seconds blocked in device->host reads since the last step
+        # record (the --log_every read-back; --sentinel full's per-dispatch
+        # loss fetch) — the record's sync_ms
+        self._sync_s = 0.0
         # programs that have already executed once: the FIRST dispatch of
         # each (path, kk) program carries its compile and is recorded as
         # compile=True + a first_dispatch_compile span, so step-time
@@ -271,7 +422,9 @@ class Trainer:
 
     def _record_dispatch(self, epoch: int, n: int, kk: int, wall_s: float,
                          dispatch_s: float, data_s: float, block_s: float,
-                         program_key: tuple) -> None:
+                         program_key: tuple, h2d_s: float = 0.0,
+                         sync_s: float = 0.0,
+                         fence: Optional[tuple] = None) -> None:
         """Per-dispatch telemetry: one small host-side record into the
         recorder's ring buffer (nothing on the device, no sync).  The
         first execution of each compiled program is marked compile=True
@@ -287,7 +440,8 @@ class Trainer:
         rec.record_step(self.global_step, epoch, n, kk, wall_s * 1e3,
                         dispatch_s * 1e3, kk * self.cfg.batch_size,
                         data_ms=data_s * 1e3, block_ms=block_s * 1e3,
-                        compile_=first)
+                        compile_=first, h2d_ms=h2d_s * 1e3,
+                        sync_ms=sync_s * 1e3, fence=fence)
         if first:
             rec.record_span("first_dispatch_compile", dispatch_s * 1e3,
                             step=self.global_step)
@@ -301,7 +455,9 @@ class Trainer:
         at this layer is what removes the per-dispatch time.monotonic
         pressure the r12 note flagged; the t_done/t_end reads stay
         unconditional (the live-line blocked accounting needs them
-        regardless of telemetry)."""
+        regardless of telemetry).  A dispatch that crosses a --log_every
+        boundary is always timed (_DispatchClock.start's ``due``): its
+        record carries the fenced window and is always kept."""
         tel = self.telemetry
         if tel is None:
             return False
@@ -428,8 +584,7 @@ class Trainer:
             self.log(f"[resume] epoch {epoch}: skipped {start_step} "
                      f"already-trained batches")
         n = start_step
-        last = (t0, start_step)
-        self._blocked_since_log = 0.0
+        clock = _DispatchClock(self, epoch, t0, start_step)
         # --log_every N: a live loss/accuracy/throughput line every N
         # steps — the reference's tqdm descriptor observability
         # (resnet50_test.py:560-566) at 1/N its sync cost (tqdm's
@@ -442,14 +597,15 @@ class Trainer:
         # resnet50_test.py:522, TPU style); uint8 image augmentation runs
         # inside the step itself, keyed by the checkpointed step counter.
         # The while/next form (vs `for batch in ...`) exists so the data
-        # wait is observable: time spent blocked on the prefetch queue is
-        # a distinct telemetry field from the dispatch itself.
-        it = iter(device_prefetch(loader, self.put_batch,
-                                  depth=self.cfg.prefetch_depth))
+        # wait is observable: the iterator wraps the loader's next() and
+        # put_batch in their own trace phases and reports the seconds of
+        # each, distinct telemetry fields from the dispatch itself.
+        it = device_prefetch(loader, self.put_batch,
+                             depth=self.cfg.prefetch_depth)
         try:
             while True:
-                want = self._keep_dispatch_times(("host", 1))
-                t_rec = time.monotonic() if want else 0.0
+                clock.start(("host", 1), due=self._log_due(n + 1, 1))
+                it.step = clock.step
                 try:
                     batch = next(it)
                 except StopIteration:
@@ -462,28 +618,13 @@ class Trainer:
                     # saw this batch
                     n += 1
                     continue
-                t_disp = time.monotonic() if want else 0.0
-                self._prof_before(1)
-                state, metrics = self.train_step(state, batch)
-                t_done = time.monotonic()
+                with clock.dispatch(1):
+                    state, metrics = self.train_step(state, batch)
                 acc.add(metrics)
                 n += 1
                 self.global_step += 1
-                if self._sharding_expect is None:
-                    self._observe_state_placement(state)
-                self._prof_after(metrics)
-                if res is not None:
-                    state = self._resilience_hooks(state, epoch, n,
-                                                   metrics=metrics,
-                                                   group=(n - 1, 1))
-                t_end = time.monotonic()
-                self._blocked_since_log += t_end - t_done
-                self._record_dispatch(
-                    epoch, n, 1, t_end - t_rec if want else 0.0,
-                    t_done - t_disp if want else 0.0,
-                    t_disp - t_rec if want else 0.0,
-                    t_end - t_done, ("host", 1))
-                last = self._log_dispatch(epoch, n, 1, metrics, last)
+                state = clock.finish(state, n, 1, metrics, ("host", 1),
+                                     (n - 1, 1), h2d_s=it.h2d_s)
         except BaseException:
             # stranded prefetch worker cleanup (Preempted, injected
             # faults, Ctrl-C): cancel + join the loader's thread so an
@@ -491,14 +632,17 @@ class Trainer:
             if closer is not None:
                 closer()
             raise
-        if metrics is not None:
-            # fence: dispatch is asynchronous, and without it the
-            # reference-parity epoch timing (resnet50_test.py:519,614)
-            # would measure the enqueue
-            jax.block_until_ready(metrics["loss"])
+        clock.fence(metrics)
         self._last_epoch_steps = n
         elapsed = time.monotonic() - t0
         return state, acc.summary(), elapsed
+
+    def _log_due(self, n: int, kk: int) -> bool:
+        """Whether the dispatch that advanced the epoch to step ``n`` by
+        ``kk`` steps crossed a --log_every boundary."""
+        log_every = int(self.cfg.log_every or 0)
+        return bool(log_every) and (n // log_every) > ((n - kk)
+                                                       // log_every)
 
     def _log_dispatch(self, epoch: int, n: int, kk: int, metrics,
                       last) -> tuple:
@@ -513,12 +657,14 @@ class Trainer:
         no longer reads as a throughput dip (r12 satellite — the raw
         wall number made every save look like a regression in the live
         log while the epoch summary said otherwise)."""
-        log_every = int(self.cfg.log_every or 0)
-        if not log_every or (n // log_every) <= ((n - kk) // log_every):
+        if not self._log_due(n, kk):
             return last
         last_t, last_n = last
-        loss = float(metrics["loss"])
+        t_sync = time.monotonic()
+        with spans.phase("readback", step=self.global_step - kk + 1):
+            loss = float(metrics["loss"])
         now = time.monotonic()
+        self._sync_s += now - t_sync
         window = max(now - last_t, 1e-9)
         blocked = min(max(self._blocked_since_log, 0.0), window)
         self._blocked_since_log = 0.0
@@ -562,16 +708,16 @@ class Trainer:
             self.log(f"[resume] epoch {epoch}: skipped {start_step} "
                      f"already-trained batches")
         n = start_step
-        last = (t0, start_step)
-        self._blocked_since_log = 0.0
+        clock = _DispatchClock(self, epoch, t0, start_step)
         try:
             while True:
-                # t_rec unconditional here: the program key (and so the
-                # compile-marking decision) needs the group's length,
-                # which is only known after the islice this clock read
-                # brackets — one read per K steps is already amortized
-                t_rec = time.monotonic()
-                group = list(itertools.islice(it, self.k))
+                # clock reads unconditional here: the program key (and so
+                # the compile-marking decision) needs the group's length,
+                # which is only known after the islice — one read per K
+                # steps is already amortized
+                clock.start()
+                with clock.phase("data_wait"):
+                    group = list(itertools.islice(it, self.k))
                 if not group:
                     break
                 kk_full = len(group)
@@ -587,36 +733,23 @@ class Trainer:
                         n += kk_full
                         continue
                 kk = len(group)
-                want = self._keep_dispatch_times(("host", kk))
-                batch = self.put_stacked(_stack_host_batches(group))
-                t_disp = time.monotonic() if want else 0.0
-                self._prof_before(kk)
-                state, metrics = self._fused_step(kk)(state, batch)
-                t_done = time.monotonic()
+                t_put = time.monotonic()
+                with clock.phase("h2d"):
+                    # the K-stacked staging: host stack + ONE transfer
+                    batch = self.put_stacked(_stack_host_batches(group))
+                h2d_s = time.monotonic() - t_put
+                with clock.dispatch(kk):
+                    state, metrics = self._fused_step(kk)(state, batch)
                 acc.add(metrics)
                 n += kk_full
                 self.global_step += kk
-                if self._sharding_expect is None:
-                    self._observe_state_placement(state)
-                self._prof_after(metrics)
-                if res is not None:
-                    state = self._resilience_hooks(
-                        state, epoch, n, n_steps=kk, metrics=metrics,
-                        group=(n - kk_full, kk_full))
-                t_end = time.monotonic()
-                self._blocked_since_log += t_end - t_done
-                self._record_dispatch(
-                    epoch, n, kk, t_end - t_rec if want else 0.0,
-                    t_done - t_disp if want else 0.0,
-                    t_disp - t_rec if want else 0.0,
-                    t_end - t_done, ("host", kk))
-                last = self._log_dispatch(epoch, n, kk, metrics, last)
+                state = clock.finish(state, n, kk, metrics, ("host", kk),
+                                     (n - kk_full, kk_full), h2d_s=h2d_s)
         except BaseException:
             if closer is not None:
                 closer()
             raise
-        if metrics is not None:
-            jax.block_until_ready(metrics["loss"])   # fence (run_epoch)
+        clock.fence(metrics)
         self._last_epoch_steps = n
         return state, acc.summary(), time.monotonic() - t0
 
@@ -652,8 +785,7 @@ class Trainer:
                      f"{start_step} (device-resident order, no host "
                      f"replay)")
         n = start_step
-        last = (t0, start_step)
-        self._blocked_since_log = 0.0
+        clock = _DispatchClock(self, epoch, t0, start_step)
         while n < n_steps:
             kk = min(self.k, n_steps - n)
             # quarantine-aware dispatch plan: the common case is the
@@ -669,40 +801,17 @@ class Trainer:
                 continue
             run = sum(l for _, l in segs)
             key = ("resident", segs[-1][1])
-            want = self._keep_dispatch_times(key)
-            t_rec = time.monotonic() if want else 0.0
-            for s, l in segs[:-1]:
-                self._prof_before(l)
-                state, m = self._fused_step(l, resident)(
-                    state, data, order,
-                    jax.numpy.asarray(s, jax.numpy.int32))
-                acc.add(m)
-            s0, l0 = segs[-1]
-            self._prof_before(l0)
-            state, metrics = self._fused_step(l0, resident)(
-                state, data, order,
-                jax.numpy.asarray(s0, jax.numpy.int32))
-            t_done = time.monotonic()
-            acc.add(metrics)
+            clock.start(key, due=self._log_due(n + kk, run))
+            for s, l in segs:
+                with clock.dispatch(l):
+                    state, metrics = self._fused_step(l, resident)(
+                        state, data, order,
+                        jax.numpy.asarray(s, jax.numpy.int32))
+                acc.add(metrics)
             n += kk
             self.global_step += run
-            if self._sharding_expect is None:
-                self._observe_state_placement(state)
-            self._prof_after(metrics)
-            if res is not None:
-                state = self._resilience_hooks(state, epoch, n,
-                                               n_steps=run,
-                                               metrics=metrics,
-                                               group=(n - kk, kk))
-            t_end = time.monotonic()
-            self._blocked_since_log += t_end - t_done
-            self._record_dispatch(
-                epoch, n, run, t_end - t_rec if want else 0.0,
-                t_done - t_rec if want else 0.0, 0.0, t_end - t_done,
-                key)
-            last = self._log_dispatch(epoch, n, run, metrics, last)
-        if metrics is not None:
-            jax.block_until_ready(metrics["loss"])   # fence (run_epoch)
+            state = clock.finish(state, n, run, metrics, key, (n - kk, kk))
+        clock.fence(metrics)
         self._last_epoch_steps = n
         return state, acc.summary(), time.monotonic() - t0
 
@@ -726,11 +835,12 @@ class Trainer:
         wrap — the resident path's precedent); step faults and
         preemption inject as everywhere.
 
-        Timing note: the two clock reads bracketing ``buffer_for`` are
-        UNCONDITIONAL (unlike the host paths' --telemetry_every-gated
-        reads) — the swap wait is the stream-stall metric itself and
-        must be measured regardless of whether the step record is kept;
-        K>1 amortizes them like every other per-dispatch cost."""
+        Timing note: the clock reads bracketing ``buffer_for`` are
+        UNCONDITIONAL (``clock.start()`` without a program key, unlike
+        the host path's --telemetry_every-gated reads) — the swap wait
+        is the stream-stall metric itself and must be measured
+        regardless of whether the step record is kept; K>1 amortizes
+        them like every other per-dispatch cost."""
         src = self.stream
         acc = MetricAccumulator()
         t0 = time.monotonic()
@@ -744,8 +854,7 @@ class Trainer:
                      f"host replay)")
         window = src.epoch_window(epoch, start_step)
         n = start_step
-        last = (t0, start_step)
-        self._blocked_since_log = 0.0
+        clock = _DispatchClock(self, epoch, t0, start_step)
         # the epoch-INITIAL buffer fill is un-overlapped by construction
         # (nothing trains while the first window loads) — exclude that
         # one wait from the steady-state stall accounting on every
@@ -753,9 +862,9 @@ class Trainer:
         epoch_cold = True
         try:
             while n < n_steps:
-                t_rec = time.monotonic()
-                base, hi, data = window.buffer_for(n)
-                t_disp = time.monotonic()
+                clock.start()
+                with clock.phase("data_wait"):
+                    base, hi, data = window.buffer_for(n)
                 kk = min(self.k, n_steps - n, hi - n)
                 # quarantine-aware plan (see _run_epoch_resident): the
                 # in-graph start is buffer-relative, so each segment
@@ -768,33 +877,16 @@ class Trainer:
                 run = sum(l for _, l in segs)
                 key = ("stream", segs[-1][1])
                 first = key not in self._dispatched
-                want = first or self._keep_dispatch_times(key)
-                for s, l in segs[:-1]:
-                    self._prof_before(l)
-                    state, m = self._fused_step(l, src)(
-                        state, data, src.dummy_order,
-                        jax.numpy.asarray(s - base, jax.numpy.int32))
-                    acc.add(m)
-                s0, l0 = segs[-1]
-                self._prof_before(l0)
-                state, metrics = self._fused_step(l0, src)(
-                    state, data, src.dummy_order,
-                    jax.numpy.asarray(s0 - base, jax.numpy.int32))
-                t_done = time.monotonic()
-                acc.add(metrics)
+                for s, l in segs:
+                    with clock.dispatch(l):
+                        state, metrics = self._fused_step(l, src)(
+                            state, data, src.dummy_order,
+                            jax.numpy.asarray(s - base, jax.numpy.int32))
+                    acc.add(metrics)
                 n += kk
                 self.global_step += run
-                if self._sharding_expect is None:
-                    self._observe_state_placement(state)
-                self._prof_after(metrics)
-                t_step = time.monotonic()
-                if res is not None:
-                    state = self._resilience_hooks(state, epoch, n,
-                                                   n_steps=run,
-                                                   metrics=metrics,
-                                                   group=(n - kk, kk))
-                t_end = time.monotonic()
-                self._blocked_since_log += t_end - t_done
+                state = clock.finish(state, n, run, metrics, key,
+                                     (n - kk, kk))
                 if not first and not epoch_cold:
                     # steady-state stall accounting for stream_stall_pct:
                     # compile-carrying first dispatches AND each epoch's
@@ -803,21 +895,14 @@ class Trainer:
                     # hooks — checkpoint/rendezvous time has its own
                     # overhead metric and must not dilute "fraction of
                     # STEP time blocked on data"
-                    self._stream_stall_s += t_disp - t_rec
-                    self._stream_wall_s += t_step - t_rec
+                    self._stream_stall_s += clock.t_disp - clock.t_rec
+                    self._stream_wall_s += clock.t_hooks - clock.t_rec
                 epoch_cold = False
-                self._record_dispatch(
-                    epoch, n, run, t_end - t_rec if want else 0.0,
-                    t_done - t_disp if want else 0.0,
-                    t_disp - t_rec if want else 0.0,
-                    t_end - t_done, key)
-                last = self._log_dispatch(epoch, n, run, metrics, last)
         finally:
             # normal AND abnormal exits reclaim the refill thread (the
             # prefetch-closer contract the host paths honor in except:)
             window.close()
-        if metrics is not None:
-            jax.block_until_ready(metrics["loss"])   # fence (run_epoch)
+        clock.fence(metrics)
         self._last_epoch_steps = n
         return state, acc.summary(), time.monotonic() - t0
 
@@ -862,7 +947,9 @@ class Trainer:
             # rollback-replay actually excises it.  May raise LossSpike
             # (restartable; the supervisor replays from the newest
             # valid checkpoint with the indicted batches quarantined).
+            t_sync = time.monotonic()
             loss = float(jax.device_get(metrics["loss"]))
+            self._sync_s += time.monotonic() - t_sync
             if res.faults is not None:
                 loss = res.faults.perturb_loss(step, loss)
             sent.observe(epoch, group[0], group[1], loss, step)

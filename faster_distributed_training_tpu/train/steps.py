@@ -251,8 +251,9 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
             from faster_distributed_training_tpu.data.augment import (
                 augment_batch)
             k_aug = jax.random.fold_in(aug_root, state.step)
-            batch = dict(batch, image=augment_batch(
-                k_aug, batch["image"], train=True))
+            with jax.named_scope("fdt/augment"):
+                batch = dict(batch, image=augment_batch(
+                    k_aug, batch["image"], train=True))
         step_key = jax.random.fold_in(state.rng, state.step)
         k_mix, k_drop = jax.random.split(step_key)
         if cfg.dropout_rng_impl == "rbg" and cfg.dropout_impl == "xla":
@@ -285,16 +286,18 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
             def loss_fn(params):
                 variables = {"params": params["model"],
                              "batch_stats": state.batch_stats}
-                logits, mutated = state.apply_fn(
-                    variables, batch["tokens"],
-                    token_types=batch.get("token_types"),
-                    mask=None, train=True,
-                    rngs={"dropout": k_drop, "mixup": k_mix},
-                    mutable=["batch_stats"], **pp_kwargs)
-                loss_total, correct, total = lm_shift_metrics(
-                    logits, batch["tokens"], batch.get("mask"))
-                loss = loss_total / jnp.maximum(total, 1.0)
-                scaled = scale_loss(loss, state.loss_scale, fp16)
+                with jax.named_scope("fdt/model"):
+                    logits, mutated = state.apply_fn(
+                        variables, batch["tokens"],
+                        token_types=batch.get("token_types"),
+                        mask=None, train=True,
+                        rngs={"dropout": k_drop, "mixup": k_mix},
+                        mutable=["batch_stats"], **pp_kwargs)
+                with jax.named_scope("fdt/loss"):
+                    loss_total, correct, total = lm_shift_metrics(
+                        logits, batch["tokens"], batch.get("mask"))
+                    loss = loss_total / jnp.maximum(total, 1.0)
+                    scaled = scale_loss(loss, state.loss_scale, fp16)
                 if nan_at is not None:
                     # multiplicative poison: the NaN flows through the
                     # backward pass, so every gradient leaf is NaN too —
@@ -306,11 +309,15 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
 
             grads, (loss, loss_total, correct, total, new_stats) = jax.grad(
                 loss_fn, has_aux=True)(state.params)
-            grads = reduce_grads(grads)
-            grads, finite = unscale_and_check(grads, state.loss_scale, fp16)
+            with jax.named_scope("fdt/grad_reduce"):
+                grads = reduce_grads(grads)
+                grads, finite = unscale_and_check(grads, state.loss_scale,
+                                                  fp16)
             ok = _sentinel_ok(loss, grads, finite) if sentinel_on \
                 else finite
-            updated = state.apply_gradients(grads).replace(
+            with jax.named_scope("fdt/optimizer"):
+                updated = state.apply_gradients(grads)
+            updated = updated.replace(
                 batch_stats=new_stats,
                 loss_scale=update_loss_scale(state.loss_scale, finite,
                                              fp16))
@@ -344,42 +351,45 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
             variables = {"params": model_params,
                          "batch_stats": state.batch_stats}
             if is_text:
-                out, mutated = state.apply_fn(
-                    variables, batch["tokens"],
-                    token_types=batch.get("token_types"),
-                    mask=batch.get("mask"), train=True,
-                    rngs={"dropout": k_drop, "mixup": k_mix},
-                    mutable=["batch_stats"], **pp_kwargs)
+                with jax.named_scope("fdt/model"):
+                    out, mutated = state.apply_fn(
+                        variables, batch["tokens"],
+                        token_types=batch.get("token_types"),
+                        mask=batch.get("mask"), train=True,
+                        rngs={"dropout": k_drop, "mixup": k_mix},
+                        mutable=["batch_stats"], **pp_kwargs)
                 logits, index, lam = out       # in-forward mixup triplet
                 y_a, y_b = y, y[index]
-                loss = mx.mixup_criterion(cross_entropy, logits, y_a, y_b,
-                                          lam)
             else:
                 x = batch["image"]
-                if mode == "meta":
-                    x, y_a, y_b, lam = mx.meta_mixup_apply(
-                        params["mixup_lambda"], k_mix, x, y)
-                elif mode == "attn":
-                    x, y_a, y_b, lam = mx.attn_mixup_apply(
-                        params["mixup_lambda"], k_mix, x, y)
-                elif mode == "static":
-                    x, y_a, y_b, lam = mx.mixup_data(k_mix, x, y, cfg.alpha)
-                elif mode == "intra":
-                    x, y_a, y_b, lam = mx.mixup_data(k_mix, x, y, cfg.alpha,
-                                                     intra_only=True)
-                else:
-                    x, y_a, y_b, lam = x, y, y, jnp.asarray(1.0)
-                logits, mutated = state.apply_fn(
-                    variables, x, train=True,
-                    rngs={"dropout": k_drop, "mixup": k_mix},
-                    mutable=["batch_stats"])
-                if mode in ("meta", "attn"):
+                with jax.named_scope("fdt/mixup"):
+                    if mode == "meta":
+                        x, y_a, y_b, lam = mx.meta_mixup_apply(
+                            params["mixup_lambda"], k_mix, x, y)
+                    elif mode == "attn":
+                        x, y_a, y_b, lam = mx.attn_mixup_apply(
+                            params["mixup_lambda"], k_mix, x, y)
+                    elif mode == "static":
+                        x, y_a, y_b, lam = mx.mixup_data(k_mix, x, y,
+                                                         cfg.alpha)
+                    elif mode == "intra":
+                        x, y_a, y_b, lam = mx.mixup_data(
+                            k_mix, x, y, cfg.alpha, intra_only=True)
+                    else:
+                        x, y_a, y_b, lam = x, y, y, jnp.asarray(1.0)
+                with jax.named_scope("fdt/model"):
+                    logits, mutated = state.apply_fn(
+                        variables, x, train=True,
+                        rngs={"dropout": k_drop, "mixup": k_mix},
+                        mutable=["batch_stats"])
+            with jax.named_scope("fdt/loss"):
+                if mode in ("meta", "attn") and not is_text:
                     loss = mx.mixup_criterion_meta(
                         per_sample_cross_entropy, logits, y_a, y_b, lam)
                 else:
                     loss = mx.mixup_criterion(cross_entropy, logits, y_a,
                                               y_b, lam)
-            scaled = scale_loss(loss, state.loss_scale, fp16)
+                scaled = scale_loss(loss, state.loss_scale, fp16)
             if nan_at is not None:
                 scaled = scaled * jnp.where(state.step == nan_at,
                                             jnp.nan, 1.0)
@@ -388,11 +398,14 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
 
         grads, (loss, logits, y_a, y_b, lam, new_stats) = jax.grad(
             loss_fn, has_aux=True)(state.params)
-        grads = reduce_grads(grads)
-        grads, finite = unscale_and_check(grads, state.loss_scale, fp16)
+        with jax.named_scope("fdt/grad_reduce"):
+            grads = reduce_grads(grads)
+            grads, finite = unscale_and_check(grads, state.loss_scale, fp16)
         ok = _sentinel_ok(loss, grads, finite) if sentinel_on else finite
 
-        updated = state.apply_gradients(grads).replace(
+        with jax.named_scope("fdt/optimizer"):
+            updated = state.apply_gradients(grads)
+        updated = updated.replace(
             batch_stats=new_stats,
             loss_scale=update_loss_scale(state.loss_scale, finite, fp16))
         if fp16 or sentinel_on:

@@ -22,10 +22,17 @@ Reads the run manifest + every ``host_<pi>.jsonl`` the run emitted
     byte table, per-epoch device watermarks, sharding-drift detections;
   * (r15, ``--flight``) crash flight dumps: the failing host's reason/
     exception, the spans open at death, the in-memory record ring and
-    the goodput snapshot (telemetry/flight.py).
+    the goodput snapshot (telemetry/flight.py);
+  * (``--trace <dir>``) the profiler trace ``--profile_steps A:B``
+    captured (``<telemetry_dir>/trace_steps_A_B``): device ms per step by
+    ``fdt/*`` scope (forward, backward, augment, NGD, its Fisher
+    refresh, ``unscoped``), by ``fdt_*`` Pallas kernel, and host seconds
+    by ``fdt/*`` phase (telemetry/trace_report.py).  Alone, or after the
+    directory's report.
 
 Run:  python scripts/telemetry_report.py <telemetry_dir>
-          [--straggler_ratio 2.0] [--json] [--flight]
+          [--straggler_ratio 2.0] [--json] [--flight] [--trace <dir>]
+      python scripts/telemetry_report.py --trace <trace_dir>
 
 Smoke-tested (tier-1, milliseconds) against the recorded fixture
 ``tests/fixtures/telemetry/`` by tests/test_telemetry.py.
@@ -136,8 +143,13 @@ def render(report: dict) -> str:
     s = report.get("summary", {})
     pod = s.get("pod")
     if pod:
-        lines.append("step-time percentiles (dispatch_ms / K, compile "
-                     "excluded):")
+        fenced = {st.get("step_time_source")
+                  for st in s.get("hosts", {}).values()} == {"fenced"}
+        lines.append("step-time percentiles (fence_ms / fence_steps per "
+                     "--log_every window):" if fenced else
+                     "step-time percentiles (fenced windows where "
+                     "recorded, else dispatch_ms / K = the ENQUEUE; "
+                     "compile excluded):")
         lines.append(_fmt_pct_row("pod", pod))
         # numeric sort: aggregate_run stringifies host keys, and a
         # lexicographic sort would list host 10 before host 2
@@ -253,8 +265,14 @@ def render(report: dict) -> str:
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("directory", help="a run's telemetry directory "
-                                      "(<checkpoint_dir>/telemetry)")
+    ap.add_argument("directory", nargs="?",
+                    help="a run's telemetry directory "
+                         "(<checkpoint_dir>/telemetry)")
+    ap.add_argument("--trace", metavar="DIR",
+                    help="a profiler trace directory (--profile_steps "
+                         "writes <telemetry_dir>/trace_steps_A_B): device "
+                         "ms per step by fdt/* scope and fdt_* kernel, "
+                         "host seconds by fdt/* phase")
     ap.add_argument("--straggler_ratio", type=float, default=2.0)
     ap.add_argument("--json", action="store_true",
                     help="emit the raw report dict as JSON")
@@ -263,12 +281,22 @@ def main(argv=None) -> dict:
                          "flight.py): reason, exception, open spans, "
                          "the in-memory record ring, goodput at crash")
     args = ap.parse_args(argv)
-    report = run(args.directory, straggler_ratio=args.straggler_ratio,
-                 with_flight=args.flight)
+    if args.directory is None and args.trace is None:
+        ap.error("give a telemetry directory, --trace <dir>, or both")
+    report: dict = {}
+    if args.directory is not None:
+        report = run(args.directory, straggler_ratio=args.straggler_ratio,
+                     with_flight=args.flight)
+    if args.trace is not None:
+        from faster_distributed_training_tpu.telemetry import trace_report
+        report["trace"] = trace_report.report(args.trace)
     if args.json:
         print(json.dumps(report, indent=1, default=str))
     else:
-        print(render(report))
+        if args.directory is not None:
+            print(render(report))
+        if args.trace is not None:
+            print(trace_report.render(report["trace"]))
     return report
 
 
