@@ -14,6 +14,8 @@ from faster_distributed_training_tpu.data.agnews import (HashTokenizer,
 from faster_distributed_training_tpu.data.loader import (device_prefetch,
                                                          shard_for_host)
 from faster_distributed_training_tpu.data import download as dl
+from faster_distributed_training_tpu.data.augment import (random_crop,
+                                                          random_flip)
 
 
 class TestSharding:
@@ -206,6 +208,111 @@ class TestAugment:
         x = jnp.full((2, 32, 32, 3), 255, jnp.uint8)
         out = normalize(x)
         assert float(out.max()) < 4.0  # (1-0.44)/0.2 ~ 2.7
+
+    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("dtype", [np.float32, np.uint8])
+    @pytest.mark.parametrize("padding", [0, 2, 4])
+    @pytest.mark.parametrize("n", [1, 8, 33])
+    def test_random_crop_is_bitwise_the_per_sample_slice(self, n, padding,
+                                                         dtype, seed):
+        x = jnp.asarray(_images(n, 12, 20, dtype))   # h != w
+        key = jax.random.PRNGKey(seed)
+        out = random_crop(key, x, padding)
+        assert out.shape == x.shape and out.dtype == x.dtype
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(_per_sample_crop(key, x, padding)))
+
+    def test_random_crop_border_sits_where_the_offset_says(self):
+        n, h, w, p = 64, 6, 10, 4
+        draw = lambda s: np.asarray(jax.random.randint(        # noqa: E731
+            jax.random.PRNGKey(s), (n, 2), 0, 2 * p + 1))
+        # a key whose draw holds both extremes on both axes, found not forced
+        seed = next(s for s in range(64) if all(
+            v in draw(s)[:, a] for a in (0, 1) for v in (0, 2 * p)))
+        off = draw(seed)
+        out = np.asarray(random_crop(jax.random.PRNGKey(seed),
+                                     jnp.ones((n, h, w, 3), jnp.float32), p))
+        # the window starts `off` into the padded image: offset 0 shows the
+        # whole leading border, offset 2p the whole trailing one
+        r, c = np.arange(h)[None, :, None], np.arange(w)[None, None, :]
+        oy, ox = off[:, 0, None, None], off[:, 1, None, None]
+        inside = ((r + oy >= p) & (r + oy < p + h)
+                  & (c + ox >= p) & (c + ox < p + w))
+        np.testing.assert_array_equal(
+            out, np.broadcast_to(inside[..., None], out.shape)
+            .astype(np.float32))
+        top, bottom = out[off[:, 0] == 0], out[off[:, 0] == 2 * p]
+        assert not top[:, :p].any() and top[:, p:].any(axis=(1, 2, 3)).all()
+        assert not bottom[:, h - p:].any() and bottom[:, :h - p].any()
+        left, right = out[off[:, 1] == 0], out[off[:, 1] == 2 * p]
+        assert not left[:, :, :p].any() and left[:, :, p:].any()
+        assert not right[:, :, w - p:].any() and right[:, :, :w - p].any()
+
+    def test_augment_batch_is_bitwise_normalize_slice_flip_under_jit(self):
+        x = jnp.asarray(synthetic_cifar(33)[0])
+        key = jax.random.PRNGKey(5)
+
+        def oracle(k, v):
+            k_crop, k_flip = jax.random.split(k)
+            return random_flip(k_flip, _per_sample_crop(k_crop, normalize(v),
+                                                        4))
+
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(lambda k, v: augment_batch(k, v, True))(key,
+                                                                       x)),
+            np.asarray(jax.jit(oracle)(key, x)))
+
+    def test_train_augmentation_has_no_per_sample_indexing_or_loop(self):
+        """The step's crop must stay dense: a gather over the batch is what
+        XLA:TPU expands into one sequential iteration per image."""
+        jaxpr = jax.make_jaxpr(lambda k, v: augment_batch(k, v, True))(
+            jax.random.PRNGKey(0), jnp.zeros((1024, 32, 32, 3), jnp.uint8))
+        names = set(_primitive_names(jaxpr.jaxpr))
+        assert {"select_n", "pad", "slice"} <= names   # the walk saw the crop
+        assert not [p for p in names if p.startswith("scatter") or p in (
+            "gather", "dynamic_slice", "dynamic_update_slice", "while",
+            "scan")], sorted(names)
+
+    def test_random_crop_stays_local_to_each_batch_shard(self, devices8):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from faster_distributed_training_tpu.parallel import make_mesh
+        mesh = make_mesh(("dp",), (4,), devices8[:4])
+        rows = NamedSharding(mesh, P("dp"))
+        x = jnp.asarray(_images(32, 12, 20, np.float32))
+        x_sharded = jax.device_put(x, rows)
+        key = jax.random.PRNGKey(3)
+        crop = jax.jit(random_crop, in_shardings=(None, rows),
+                       out_shardings=rows)
+        out = crop(key, x_sharded)
+        assert out.sharding.is_equivalent_to(rows, out.ndim)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(jax.jit(random_crop)(key, x)))
+        hlo = crop.lower(key, x_sharded).compile().as_text()
+        for collective in ("all-gather", "all-to-all", "collective-permute"):
+            assert collective not in hlo, collective
+
+
+def _images(n, h, w, dtype):
+    v = np.random.default_rng(n).integers(1, 256, (n, h, w, 3))
+    return v.astype(dtype)
+
+
+def _per_sample_crop(key, x, padding):
+    """The crop as one slice per image at its own offset: the oracle."""
+    n, h, w, c = x.shape
+    xp = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
+    off = jax.random.randint(key, (n, 2), 0, 2 * padding + 1)
+    return jax.vmap(lambda img, o: jax.lax.dynamic_slice(
+        img, (o[0], o[1], 0), (h, w, c)))(xp, off)
+
+
+def _primitive_names(jaxpr):
+    """Every primitive of a jaxpr and of the jaxprs its equations hold
+    (jit/pjit bodies, branches), recursively."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitive_names(sub)
 
 
 class TestText:
