@@ -89,7 +89,8 @@ def main(argv=None) -> int:
         sides = {"program": programs[seed]}
         t = time.monotonic()
         sides["reference"] = reference_steps.first_steps(
-            reference, sizes, config["training"], seed, batches, spe, pseed)
+            reference, sizes, config["training"], seed, batches, spe, pseed,
+            log=print)
         t_ref = time.monotonic() - t
         if i < args.controls:
             sides["control"] = reference_steps.first_steps(

@@ -233,18 +233,19 @@ def _zeros(params):
     return [jnp.zeros_like(p) for p in params]
 
 
-# training.optimizer -> (the state before the first step, one step, the
-# gradient as the optimizer kept it from its state after the first step)
+# training.optimizer -> (the state before the first step, one step, where
+# the state after the first step keeps the first gradient: the leaves that
+# hold it and the number they are to be divided by; the division is the
+# reader's, inside its reduction, so that no second tree is made)
 _MOMENTUM = (lambda params: ({}, _zeros(params)), _momentum_update,
-             lambda state, training: state[1])
+             lambda state, training: (state[1], 1.0))
 OPTIMIZERS = {
     "ngd": _MOMENTUM,
     "sgd": _MOMENTUM,
     "adamw": (lambda params: (_zeros(params), _zeros(params),
                               jnp.zeros((), jnp.int32)), _adamw_update,
-              lambda state, training: [
-                  m / (1 - float(training["adamw"]["b1"]))
-                  for m in state[0]]),
+              lambda state, training: (
+                  state[0], 1 - float(training["adamw"]["b1"]))),
 }
 
 
@@ -270,8 +271,10 @@ def update(params: list, grads: list, state, lr, first: bool,
     return optimizer(training)[1](params, grads, state, lr, first, training)
 
 
-def kept_gradient(state, training: dict) -> list:
-    """The first gradient as the optimizer kept it, from its state after
-    the first step: the momentum trace (clipped, decayed, preconditioned at
-    equal norm) or Adam's first moment over 1 - b1 (clipped)."""
+def kept_gradient(state, training: dict) -> tuple:
+    """(leaves, over): the first gradient as the optimizer kept it is
+    ``leaves / over``, from its state after the first step: the momentum
+    trace (clipped, decayed, preconditioned at equal norm) over 1, or
+    Adam's first moment over 1 - b1 (clipped).  The leaves are the
+    state's own, not copies."""
     return optimizer(training)[2](state, training)

@@ -51,25 +51,26 @@ def _one_state(opt_state, kind, what: str):
 
 def _momentum_trace(opt_state, training: dict):
     import optax
-    return _one_state(opt_state, optax.TraceState, "momentum trace").trace
+    return _one_state(opt_state, optax.TraceState,
+                      "momentum trace").trace, 1.0
 
 
 def _adam_first_moment(opt_state, training: dict):
-    import jax
     import optax
-    b1 = float(training["adamw"]["b1"])
     mu = _one_state(opt_state, optax.ScaleByAdamState, "Adam state").mu
-    return jax.tree.map(lambda m: m / (1.0 - b1), mu)
+    return mu, 1.0 - float(training["adamw"]["b1"])
 
 
-# training.optimizer -> the first gradient as the optimizer kept it, from
-# the program's optimizer state after ONE step: the momentum trace (it
-# started from zero), or Adam's first moment over 1 - b1 (likewise)
+# training.optimizer -> where the program's optimizer state after ONE step
+# keeps the first gradient, as (tree, over): the momentum trace over 1 (it
+# started from zero), or Adam's first moment over 1 - b1 (likewise).  The
+# tree is the state's own; ``reference/steps.py::leaf_norms`` divides inside
+# its reduction, so the gradient as kept is never a second tree in memory
 KEPT_GRADIENT = {"sgd": _momentum_trace, "ngd": _momentum_trace,
                  "adamw": _adam_first_moment}
 
 
-def kept_gradient(opt_state, training: dict):
+def kept_gradient(opt_state, training: dict) -> tuple:
     name = training["optimizer"]
     if name not in KEPT_GRADIENT:
         raise ValueError(f"training.optimizer {name!r} is not in "
@@ -229,6 +230,7 @@ class Session:
         cfg = self.cfg
         if self.feed is not None:
             self.feed.close()
+        self.state = None       # the seed before's, freed before the next
         train_ds = generate(traffic["data"], seed)
         eval_ds = generate(dict(traffic["data"], rows=cfg.batch_size),
                            seed + 1)
@@ -280,6 +282,7 @@ class Session:
             jax.random.PRNGKey(cfg.seed),
             jnp.asarray(seed32(seed), jnp.int32))
         self.state = shard_train_state(state, self.mesh, cfg)
+        del state               # its placed twin is the one state there is
         jax.block_until_ready(self.state.params)
         self.lap("state: made on the device in one call, placed")
         self.feed = Feed(train_loader, annotate=self._annotate)
@@ -288,8 +291,10 @@ class Session:
             self.trainer.global_step = 0
 
     def lap(self, what: str) -> None:
+        from benchmark.reference.steps import allocator
         now = time.monotonic()
-        self.log(f"[bench] set-up: {what} {now - self._lap_t:.2f} s")
+        self.log(f"[bench] set-up: {what} {now - self._lap_t:.2f} s; "
+                 f"{allocator()}")
         self._lap_t = now
 
     # -- driving ----------------------------------------------------------
@@ -311,19 +316,22 @@ class Session:
         first, the normalisations' running statistics and the norm of every
         leaf of the gradient as the optimizer kept it (``KEPT_GRADIENT``);
         and the norm of every leaf's change after ``n_checked`` steps.  Then
-        the rest of the warm-up."""
-        import jax
-        import jax.numpy as jnp
+        the rest of the warm-up.
 
-        leaf_norms = jax.jit(lambda tree: jax.tree.map(
-            lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))),
-            tree))
-        diff_norms = jax.jit(lambda a, b: jax.tree.map(
-            lambda x, y: jnp.sqrt(jnp.sum(jnp.square(
-                x.astype(jnp.float32) - y.astype(jnp.float32)))), a, b))
-        start = jax.tree.map(jnp.copy, self.state.params["model"])
+        On the device, set-up holds the state the window holds and no
+        tree more: the starting weights are kept on the HOST (one read
+        before the first step, one after the last checked one, the
+        difference taken there), and the gradient as kept is read as norms
+        from the optimizer's own state."""
+        import jax
+
+        from benchmark import correct
+        from benchmark.reference.steps import leaf_norms
+
+        training = self._config["training"]
+        start = jax.device_get(self.state.params["model"])
+        self.lap("starting weights read to the host")
         losses, grad, stats = [], None, None
-        self.lap("copy of the starting weights")
         for i in range(n_checked):
             _, _, summary = self.run(limit=1, keep=1)
             losses.append(float(summary["loss"]))
@@ -331,14 +339,15 @@ class Session:
                      + (" (compiles or loads the step program)" if i == 0
                         else ""))
             if i == 0:
-                kept = kept_gradient(self.state.opt_state,
-                                     self._config["training"])
+                kept, over = kept_gradient(self.state.opt_state, training)
                 grad, stats = jax.device_get(
-                    (leaf_norms(kept["model"]), self.state.batch_stats))
-        change = jax.device_get(diff_norms(self.state.params["model"],
-                                           start))
+                    (leaf_norms(kept["model"], over),
+                     self.state.batch_stats))
+        change = jax.tree.unflatten(
+            jax.tree.structure(start), correct.diff_norms(
+                jax.device_get(self.state.params["model"]), start))
         del start
-        self.lap("leaf norms read back")
+        self.lap("weights read again, change taken on the host")
         if warmup > n_checked:
             self.run(limit=warmup - n_checked)
             self.lap(f"warm-up to {warmup} steps")
@@ -468,7 +477,7 @@ def run(cell, config, traffic, seed, seconds, trace, out_dir, t0, device,
     t = time.monotonic()
     ref = reference_steps.first_steps(
         reference, sizes, config["training"], seed, batches,
-        steps_per_epoch, program_seed=cfg.seed)
+        steps_per_epoch, program_seed=cfg.seed, log=log)
     log(f"[bench] reference over {len(batches)} steps: "
         f"{time.monotonic() - t:.2f} s, loss {ref['loss']}")
     compared = correct.compare(program, ref, traffic["limits"])

@@ -51,7 +51,9 @@ def tiny_cell(limits=None, rows=TINY_BATCH * 6):
     traffic["warmup_steps"] = 4
     if limits is not None:
         traffic["limits"] = limits
-    cell = dict(FIRST_CELL, name="tiny.cell", traffic="tiny",
+    # a name, and so a directory under benchmark_out/, of this process's own:
+    # every run empties its directory first, and the workers run side by side
+    cell = dict(FIRST_CELL, name=f"tiny.cell.{os.getpid()}", traffic="tiny",
                 why="CPU rehearsal")
     return BENCH, cell, config, traffic
 

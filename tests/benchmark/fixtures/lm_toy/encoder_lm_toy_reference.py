@@ -1,5 +1,6 @@
-"""Plain reference of the ``encoder_lm_toy`` fixture: the program's own
-encoder (``models/transformer.py``) as a causal language model, as
+"""Plain reference of the ``encoder_lm_toy`` and ``encoder_lm_0p67b``
+fixtures (it reads every size from the configuration's file): the program's
+own encoder (``models/transformer.py``) as a causal language model, as
 ``--task lm --lm_causal`` runs it with dropout off.  Token, learned-position
 and segment embeddings scaled by sqrt(d), the sinusoidal table added and the
 sum added to the embeddings AGAIN (the reference repository's quirk, kept by
@@ -9,14 +10,21 @@ LayerNorm; the head tied to the raw token table; the mean next-token
 cross-entropy.  Straightforward ``jax.numpy`` in float32 at ``highest``;
 imports nothing of the program.  Sizes under the catalog's names.
 
-``low`` is the control (matrix products with bfloat16 operands, the nearest
-precision below the float32 the fixture states), ``fault="half_batch"`` the
-planted fault.  No normalisation keeps running statistics: ``loss_fn``
-returns an empty tree of them.
+Each block runs under ``jax.checkpoint``: the backward pass holds one
+block's float32 activations at a time, beside the rows between blocks, so
+that the reference of a configuration that fills the chip with its state
+fits beside it.
+
+``low`` is the control: the operands of every matrix product rounded to the
+nearest precision below the one ``training.precision`` states (bfloat16
+under ``fp32``; float8 e4m3 with one scale a tensor under ``bf16``).
+``fault="half_batch"`` is the planted fault.  No normalisation keeps running
+statistics: ``loss_fn`` returns an empty tree of them.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -93,38 +101,58 @@ def layer_norm(x, p):
     return p["scale"] * ((x - mean) / (jnp.sqrt(var) + LN_EPS)) + p["bias"]
 
 
-def logits_fn(params, tokens, sizes: dict, low: bool):
-    def mm(spec, a, b):
-        if low:
-            a, b = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
-        return jnp.einsum(spec, a, b, precision=HI,
-                          preferred_element_type=jnp.float32)
+def fp8(x):
+    """Round to float8 e4m3 with one scale per tensor; the gradient passes
+    straight through."""
+    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12) / 448.0
+    q = (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
+    return x + lax.stop_gradient(q - x)
 
+
+# training.precision -> how the control rounds a product's operands
+LOWER = {"fp32": lambda x: x.astype(jnp.bfloat16), "bf16": fp8}
+
+
+def product(spec, a, b, lower=None):
+    if lower is not None:
+        a, b = lower(a), lower(b)
+    return jnp.einsum(spec, a, b, precision=HI,
+                      preferred_element_type=jnp.float32)
+
+
+def block(p, x, mm):
+    length, d = x.shape[1:]
+    causal = jnp.tril(jnp.ones((length, length), bool))
+    a = layer_norm(x, p["ln_attn"])
+    qkv = mm("bld,dthk->blthk", a, p["attn"]["qkv"]["kernel"]) \
+        + p["attn"]["qkv"]["bias"]
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]        # (B, L, h, dk)
+    scores = mm("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    scores = jnp.where(causal[None, None], scores, -1.0e9)
+    ctx = mm("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+    ctx = ctx.reshape(ctx.shape[0], length, d)
+    x = x + mm("bld,de->ble", ctx, p["attn"]["out"]["kernel"]) \
+        + p["attn"]["out"]["bias"]
+    f = layer_norm(x, p["ln_ffn"])
+    f = mm("bld,df->blf", f, p["ffn"]["Dense_0"]["kernel"]) \
+        + p["ffn"]["Dense_0"]["bias"]
+    f = jax.nn.gelu(f, approximate=False)
+    return x + mm("blf,fd->bld", f, p["ffn"]["Dense_1"]["kernel"]) \
+        + p["ffn"]["Dense_1"]["bias"]
+
+
+def logits_fn(params, tokens, sizes: dict, lower=None):
+    """``lower`` rounds the operands of every product (the control)."""
+    mm = functools.partial(product, lower=lower)
     d = sizes["hidden_size"]
     length = tokens.shape[1]
     emb = params["Embeddings_0"]
     e = (emb["token_embedding"][tokens] + emb["pos_embedding"][None, :length]
          + emb["segment_embedding"][0]) * math.sqrt(d)
     x = e + (e + jnp.asarray(sinusoidal_table(length, d))[None])
-    causal = jnp.tril(jnp.ones((length, length), bool))
+    one_block = jax.checkpoint(functools.partial(block, mm=mm))
     for i in range(sizes["num_hidden_layers"]):
-        p = params[f"layer_{i}"]
-        a = layer_norm(x, p["ln_attn"])
-        qkv = mm("bld,dthk->blthk", a, p["attn"]["qkv"]["kernel"]) \
-            + p["attn"]["qkv"]["bias"]
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]    # (B, L, h, dk)
-        scores = mm("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
-        scores = jnp.where(causal[None, None], scores, -1.0e9)
-        ctx = mm("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
-        ctx = ctx.reshape(ctx.shape[0], length, d)
-        x = x + mm("bld,de->ble", ctx, p["attn"]["out"]["kernel"]) \
-            + p["attn"]["out"]["bias"]
-        f = layer_norm(x, p["ln_ffn"])
-        f = mm("bld,df->blf", f, p["ffn"]["Dense_0"]["kernel"]) \
-            + p["ffn"]["Dense_0"]["bias"]
-        f = jax.nn.gelu(f, approximate=False)
-        x = x + mm("blf,fd->bld", f, p["ffn"]["Dense_1"]["kernel"]) \
-            + p["ffn"]["Dense_1"]["bias"]
+        x = one_block(params[f"layer_{i}"], x)
     x = layer_norm(x, params["ln_final"])
     return mm("bld,vd->blv", x, emb["token_embedding"])
 
@@ -136,7 +164,8 @@ def loss_fn(params, batch, sizes: dict, training: dict, seed, step,
     tokens = batch["tokens"]
     if fault == "half_batch":
         tokens = tokens[:tokens.shape[0] // 2]
-    logits = logits_fn(params, tokens, sizes, low)[:, :-1]
+    lower = LOWER[training["precision"]] if low else None
+    logits = logits_fn(params, tokens, sizes, lower)[:, :-1]
     logp = jax.nn.log_softmax(logits, axis=-1)
     picked = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
     return -jnp.mean(picked), {}

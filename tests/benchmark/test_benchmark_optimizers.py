@@ -93,10 +93,14 @@ def test_kept_gradient_is_the_clipped_first_gradient_on_both_sides(name):
     if name == "sgd":
         want = [g + training["weight_decay"] * p
                 for g, p in zip(want, small_tree())]
-    for got in (optim.kept_gradient(state, training),
-                train.kept_gradient(opt_state, training)):
-        for a, b in zip(got, want):
-            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    for held, over in (optim.kept_gradient(state, training),
+                       train.kept_gradient(opt_state, training)):
+        for a, b in zip(held, want):
+            np.testing.assert_allclose(a / over, b, rtol=1e-5, atol=1e-7)
+        # and as the runner reads it: norms, divided inside the reduction
+        np.testing.assert_allclose(
+            steps.leaf_norms(held, over),
+            [np.linalg.norm(np.ravel(b)) for b in want], rtol=1e-5)
 
 
 def test_kept_gradient_from_a_hand_made_adam_state():
@@ -104,13 +108,17 @@ def test_kept_gradient_from_a_hand_made_adam_state():
     state = (optax.EmptyState(),
              (optax.ScaleByAdamState(count=jnp.asarray(1), mu=mu, nu=mu),
               optax.EmptyState()))
-    got = train.kept_gradient(state, {"optimizer": "adamw",
-                                      "adamw": {"b1": 0.75}})
-    np.testing.assert_allclose(got["model"]["w"], [0.4, -0.8], rtol=1e-6)
-    np.testing.assert_allclose(got["model"]["b"], [1.2], rtol=1e-6)
+    held, over = train.kept_gradient(state, {"optimizer": "adamw",
+                                             "adamw": {"b1": 0.75}})
+    assert held is mu and over == 0.25       # the state's own tree, no copy
+    got = steps.leaf_norms(held, over)
+    np.testing.assert_allclose(got["model"]["w"], 0.8 * 5 ** 0.5 / 2,
+                               rtol=1e-6)
+    np.testing.assert_allclose(got["model"]["b"], 1.2, rtol=1e-6)
     trace = (optax.TraceState(trace=mu), optax.EmptyState())
     for name in ("sgd", "ngd"):
-        assert train.kept_gradient(trace, {"optimizer": name}) is mu
+        held, over = train.kept_gradient(trace, {"optimizer": name})
+        assert held is mu and over == 1.0
     with pytest.raises(ValueError, match="one Adam state"):
         train.kept_gradient(trace, {"optimizer": "adamw",
                                     "adamw": {"b1": 0.9}})
