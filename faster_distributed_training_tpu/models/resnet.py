@@ -15,8 +15,8 @@ Deliberate fixes over the reference (SURVEY.md §7):
     (reference normalizes with batch stats even at eval, resnet.py:83-100);
   * under pjit with a sharded batch all BN statistics are global —
     cross-replica SyncBN for free;
-  * optional `remat` wraps each residual block in jax.checkpoint,
-    extending the kernels' recompute-in-backward trick to whole blocks.
+  * optional `remat` wraps each residual block in jax.checkpoint
+    (recompute-in-backward for whole blocks: a memory lever).
 """
 
 from __future__ import annotations
@@ -59,16 +59,20 @@ class FusedConvBNLayer(nn.Module):
     momentum: float = 0.1        # torch exp_avg_factor (resnet.py:117)
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
-    conv_remat: bool = True      # backward recomputes the conv output
-                                 # (reference parity, resnet.py:107-108).
-                                 # Measured FASTER than the autodiff path on
-                                 # v5e (3650 vs 3443 img/s/chip @ bs=1024):
-                                 # the step is HBM-bound, so recomputing the
-                                 # activation beats re-reading it.  Distinct
-                                 # from ResNet.remat (block checkpointing);
-                                 # not plumbed through the model factories —
-                                 # it is a measured default, togglable on
-                                 # the layer for experiments
+    conv_remat: bool = True      # True: ops/conv_bn.py's custom_vjp with
+                                 # the hand-derived BatchNorm backward
+                                 # (residuals X, W, mean, sqrt_var as the
+                                 # reference's, resnet.py:107-108).  In
+                                 # the compiled program XLA still keeps
+                                 # the conv output y and recomputes the
+                                 # normalisation in its consumers; only
+                                 # the expanding 1x1 (cout > cin) reads x
+                                 # in y's place, by linearity, chosen by
+                                 # the kernel's shape in conv_bn_train.
+                                 # False: plain autodiff.  Distinct from
+                                 # ResNet.remat (block checkpointing); not
+                                 # plumbed through the model factories,
+                                 # togglable on the layer for experiments
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool) -> jax.Array:
@@ -182,16 +186,17 @@ class ResNet(nn.Module):
     num_classes: int = 10
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
-    conv_remat: bool = True   # FusedConvBN recompute-in-backward (the
-                          # measured-faster default); False = plain
-                          # autodiff conv+BN (bag-of-tricks ablation arm)
+    conv_remat: bool = True   # FusedConvBNLayer.conv_remat: the
+                          # hand-derived conv+BN backward (the default);
+                          # False = plain autodiff conv+BN (bag-of-tricks
+                          # ablation arm)
     remat: bool = False   # checkpoint every residual block.  Measured on
                           # v5e @ bs=1024 bf16 NGD: 3196 vs 3858 img/s/chip
                           # — the step is HBM-bound and block-recompute adds
                           # more traffic than it saves, so this stays OFF by
                           # default; it is a memory lever for bigger batches,
-                          # not a speed lever (cf. conv_bn.py's per-conv
-                          # recompute, which IS the faster path).
+                          # not a speed lever (conv_bn.py's backward
+                          # recomputes nothing: it reads fewer bytes).
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = True) -> jax.Array:

@@ -1,12 +1,25 @@
-"""Fused Conv2D + BatchNorm with recompute-in-backward.
+"""Fused Conv2D + BatchNorm with a hand-derived backward.
 
 TPU-native re-design of the reference's ``FusedConvBN2DFunction``
 (``resnet.py:72-113``): one ``jax.custom_vjp`` primitive whose forward
-saves only ``(X, W, sum, sqrt_var)`` and whose backward *recomputes* the
-convolution output before applying a hand-derived BatchNorm backward and
-the convolution transpose — the same activation-rematerialization memory
-trick as the reference (``resnet.py:107-108``), expressed so XLA fuses
-the normalize into the conv epilogue on the MXU.
+saves only ``(X, W, mean, sqrt_var)`` and whose backward applies a
+hand-derived BatchNorm backward and the convolution transpose, expressed
+so XLA fuses the normalize into the conv epilogue on the MXU.
+
+What the backward costs in HBM bytes, as the compiled v5e program shows
+(ISSUE 28): ``_fused_bwd`` writes ``conv2d(x, w)`` again, as the
+reference does (``resnet.py:107-108``), but XLA merges that convolution
+with the forward's identical one: the program holds no second
+convolution, keeps every conv output ``y`` from forward to backward, and
+recomputes the cheap ``relu(normalize(y))`` inside each consumer.  So
+that path saves neither bytes nor memory over autodiff; what it buys is
+the hand-derived BatchNorm backward.  The step is HBM-bound, and the
+lever left is the byte count: for the EXPANDING 1x1 convolution (each
+bottleneck's last, ``cin -> 4 cin``) ``_expand1x1_bwd`` reads the
+convolution's input ``x`` in ``y``'s place, by linearity (``y = x W`` and
+BatchNorm's backward is linear in ``y``), so ``y`` is neither kept nor
+read in the backward.  ``conv_bn_train`` picks the path by the kernel's
+shape.
 
 Semantics matched to the reference:
   * BN has no affine γ/β (``resnet.py:85-99``),
@@ -19,9 +32,10 @@ Why there is no Pallas kernel here (a deliberate decision, unlike
 single XLA HLO that the TPU conv emitter tiles onto the MXU, and the BN
 normalize is an elementwise chain XLA fuses into that conv's epilogue —
 there is no leftover fusion for a hand-written kernel to claim, only the
-risk of losing the emitter's layout/pipelining.  The fused-kernel value
-on this path is the *backward recompute policy* below, which is a
-differentiation-level decision, not a kernel-level one.
+risk of losing the emitter's layout/pipelining (a hand kernel would pay
+a layout copy per array).  The value on this path is in *which arrays
+the backward reads*, a differentiation-level decision, not a
+kernel-level one.
 
 Differences (deliberate, documented per SURVEY.md §7 "bugs to fix"):
   * layout is NHWC / HWIO (TPU-native) instead of NCHW / OIHW;
@@ -114,8 +128,9 @@ def fused_conv_bn(x: jax.Array, w: jax.Array, stride: int = 1,
 def _fused_fwd(x, w, stride, padding, eps):
     out, _, mean, var = _conv_bn_forward(x, w, stride, padding, eps)
     sqrt_var = jnp.sqrt(var)
-    # Save only (X, W, mean, sqrt_var) — NOT the conv output y, which is the
-    # big NHWC buffer. Backward recomputes it (resnet.py:107-108 parity).
+    # Residuals are (X, W, mean, sqrt_var), as the reference's
+    # (resnet.py:107-108).  _fused_bwd asks for y again; XLA serves it the
+    # forward's (module docstring).
     return (out, mean, var), (x, w, mean, sqrt_var)
 
 
@@ -123,8 +138,8 @@ def _fused_bwd(stride, padding, eps, res, cts):
     x, w, mean, sqrt_var = res
     g, _, _ = cts  # cotangents for (out, mean, var); stats are stats-only outputs
 
-    # (1) recompute the conv output — the rematerialization step, done through
-    # jax.vjp so the same computation also yields the conv transpose closure.
+    # (1) the conv output again (XLA merges this with the forward's conv and
+    # keeps y), through jax.vjp so the same trace yields the conv transpose.
     y, conv_vjp = jax.vjp(lambda x_, w_: conv2d(x_, w_, stride, padding), x, w)
 
     # (2) hand-derived BatchNorm backward (matches batch_norm_backward,
@@ -145,7 +160,7 @@ def _fused_bwd(stride, padding, eps, res, cts):
     d_var = d_s / (2.0 * jnp.maximum(sqrt_var, 1e-12))
     dy = g32 / s + centered * (2.0 * d_var / (n - 1)) - g_sum / (s * n)
 
-    # (3) conv backward through the recomputed vjp.
+    # (3) conv backward through that vjp.
     dx, dw = conv_vjp(dy.astype(y.dtype))
     return dx, dw
 
@@ -153,20 +168,93 @@ def _fused_bwd(stride, padding, eps, res, cts):
 fused_conv_bn.defvjp(_fused_fwd, _fused_bwd)
 
 
+def _expands_1x1(w_shape, stride, padding) -> bool:
+    """A 1x1, stride-1, unpadded convolution with more channels out than in:
+    where reading the input (cin) is cheaper than reading the output (cout)."""
+    kh, kw, cin, cout = w_shape
+    return (kh == kw == 1 and stride == 1 and cout > cin
+            and _norm_padding(padding) == ((0, 0), (0, 0)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _expand1x1_conv_bn(x: jax.Array, w: jax.Array, eps: float):
+    """``fused_conv_bn`` for the expanding 1x1 convolution: the same forward
+    to the bit, a backward that never touches the convolution's output."""
+    out, _, mean, var = _conv_bn_forward(x, w, 1, 0, eps)
+    return out, mean, var
+
+
+def _expand1x1_fwd(x, w, eps):
+    return _fused_fwd(x, w, 1, 0, eps)
+
+
+def _expand1x1_bwd(eps, res, cts):
+    """``_fused_bwd``'s mathematics with ``y = x W`` substituted: with
+    ``dy = a g + b y + c`` per channel, every use of ``y`` (the reduction
+    ``sum g (y - mean)``, ``dW = x^T dy``, ``dx = dy W^T``) moves onto ``x``,
+    four times smaller, and onto ``[K, K]`` / ``[K, C]`` algebra."""
+    x, w, mean, sqrt_var = res
+    g, _, _ = cts
+    with jax.named_scope("fdt/conv1x1_bn_bwd"):
+        sd = mean.dtype
+        hi = lax.Precision.HIGHEST
+        rows = (0, 1, 2)
+        n = x.size // x.shape[-1]
+        W = w[0, 0].astype(sd)                                     # [K, C]
+        # The passes over the big arrays: operands as they are (bf16 in the
+        # bf16 program), accumulation in fp32+, as conv_vjp(dy) has it.
+        G = jnp.einsum("nhwk,nhwc->kc", x, g, preferred_element_type=sd)
+        S = jnp.einsum("nhwk,nhwl->kl", x, x, preferred_element_type=sd)
+        xsum = jnp.sum(x.astype(sd), axis=rows)
+        g_sum = jnp.sum(g.astype(sd), axis=rows)
+
+        # BatchNorm backward per channel (see _fused_bwd), with
+        # sum_rows g (y - mean) = sum_k W[k,c] G[k,c] - mean[c] g_sum[c].
+        s = sqrt_var + eps
+        gy = jnp.sum(W * G, axis=0) - mean * g_sum
+        d_s = -gy / (s * s)
+        d_var = d_s / (2.0 * jnp.maximum(sqrt_var, 1e-12))   # _fused_bwd's guard
+        a = 1.0 / s
+        b = 2.0 * d_var / (n - 1)
+        c = -b * mean - g_sum / (s * n)
+
+        # The small algebra carries its own precision: it must not hang on
+        # the caller's jax_default_matmul_precision.
+        Wb = W * b
+        dw = G * a + jnp.matmul(S, Wb, precision=hi) + jnp.outer(xsum, c)
+        M = jnp.matmul(Wb, W.T, precision=hi)                       # [K, K]
+        Wc = jnp.matmul(W, c, precision=hi)                         # [K]
+
+        # dx = g (W a)^T + x M + (W c)^T.  x M + Wc is written once at the
+        # input's size and dtype and added in the big product's epilogue.
+        xm = (jnp.einsum("nhwk,kl->nhwl", x, M.astype(x.dtype),
+                         preferred_element_type=sd) + Wc).astype(x.dtype)
+        dx = jnp.einsum("nhwc,kc->nhwk", g, (W * a).astype(x.dtype),
+                        preferred_element_type=sd) + xm.astype(sd)
+        return dx.astype(x.dtype), dw[None, None].astype(w.dtype)
+
+
+_expand1x1_conv_bn.defvjp(_expand1x1_fwd, _expand1x1_bwd)
+
+
 def conv_bn_train(x: jax.Array, w: jax.Array, stride: int = 1,
                   padding: Padding = 1, eps: float = 1e-3,
                   remat: bool = True):
     """Training-mode fused conv+BN returning ``(out, mean, var)``.
 
-    remat=True (default) uses the custom_vjp kernel above: backward
-    recomputes the conv output — the reference's memory trick, which on
-    TPU is ALSO the faster path (v5e @ bs=1024: 3650 vs 3443 img/s/chip)
-    because the train step is HBM-bandwidth-bound and recomputing the
-    activation on the MXU beats re-reading it from HBM.  remat=False
-    leaves differentiation to autodiff (saves the conv output).
-    Identical forward numerics; gradients agree except at the
-    degenerate var==0 clamp edge, where autodiff zeroes the var path
-    and the hand-written backward bounds it (tests/test_ops.py)."""
+    remat=True (default) takes a custom_vjp with the hand-derived
+    BatchNorm backward, chosen by the kernel's shape: the expanding 1x1
+    convolution (1x1, stride 1, no padding, ``cout > cin``) takes
+    ``_expand1x1_conv_bn``, whose backward reads ``x`` where the other
+    reads ``y`` (reading ``y`` costs ``cout``, reading ``x`` costs
+    ``cin``: only there does the algebra save bytes); every other
+    convolution takes ``fused_conv_bn``.  remat=False leaves
+    differentiation to autodiff.  Identical forward numerics on all
+    three; gradients agree except at the degenerate var==0 clamp edge,
+    where autodiff zeroes the var path and the hand-written backwards
+    bound it (tests/test_ops.py)."""
+    if remat and _expands_1x1(w.shape, stride, padding):
+        return _expand1x1_conv_bn(x, w, eps)
     if remat:
         return fused_conv_bn(x, w, stride, padding, eps)
     out, _, mean, var = _conv_bn_forward(x, w, stride, padding, eps)

@@ -59,7 +59,8 @@ step of the dispatch the phase belongs to, which is the step record's
                    boundary: the loop's only device->host sync
   ``epoch_fence``  ``run_epoch``'s closing ``block_until_ready``
 
-Device scopes (``jax.named_scope`` in train/steps.py and optim/ngd.py;
+Device scopes (``jax.named_scope`` in train/steps.py, optim/ngd.py,
+ops/quant.py and ops/conv_bn.py;
 metadata only: they live in the HLO's ``op_name`` debug locations, never
 in ``lowered.as_text()``, so program fingerprints and compile-cache keys
 do not move).  A transform wraps ONE path element (``jvp(fdt/model)``,
@@ -73,6 +74,12 @@ set-up on the chip's host (PERF.md section 6, PR 24):
   ``fdt/mixup``        the image-space mixup variants
   ``fdt/model``        ``state.apply_fn`` inside ``loss_fn``: forward =
                        ``jvp(fdt/model)``, backward = its ``transpose``
+  ``fdt/conv1x1_bn_bwd``  inside the model's backward: ops/conv_bn.py's
+                       backward of the expanding 1x1 conv+BN from the
+                       convolution's input (16 of ResNet-50's 46
+                       FusedConvBNLayers, none in ResNet-18/34); the
+                       scope's presence in a program is the path's
+                       counter
   ``fdt/loss``         the (mixup) criterion
   ``fdt/grad_reduce``  ``reduce_grads`` + ``unscale_and_check``
   ``fdt/optimizer``    ``state.apply_gradients``; inside it the bare

@@ -9,7 +9,8 @@ and nothing else:
     execution of the step program goes to the innermost operation running
     then, and the operation to the scope its ``op_name`` carries
     (:func:`scope_of`; forward = ``jvp(fdt/model)``, backward = its
-    ``transpose``; an operation without a scope inherits the loop or
+    ``transpose``, of which a scope entered inside it has a row of its own;
+    an operation without a scope inherits the loop or
     conditional around it, and ``unscoped`` is the rest);
   * device milliseconds per step by Pallas KERNEL: the operations whose
     ``op_name`` or name carries an ``fdt_<kernel>`` name;
@@ -58,7 +59,7 @@ OP_NAME_STAT = "tf_op"
 UNSCOPED = "unscoped"
 FORWARD = "jvp(fdt/model)"
 BACKWARD = "transpose(jvp(fdt/model))"
-_SCOPE = re.compile(r"fdt/[a-z_]+")
+_SCOPE = re.compile(r"fdt/[a-z0-9_]+")
 _KERNEL = re.compile(r"fdt_[a-z0-9_]+")
 
 
@@ -196,7 +197,10 @@ def scope_of(op_name: str) -> Optional[str]:
     if "fdt/optimizer/ngd" in op_name:
         return "fdt/optimizer/ngd"
     if BACKWARD in op_name:
-        return BACKWARD
+        # a scope entered inside the model's backward (ops/conv_bn.py's
+        # fdt/conv1x1_bn_bwd) is a part of it, shown apart
+        inner = _SCOPE.search(op_name.split(BACKWARD, 1)[1])
+        return f"{BACKWARD}/{inner.group(0)}" if inner else BACKWARD
     if FORWARD in op_name:
         return FORWARD
     m = _SCOPE.search(op_name)

@@ -202,6 +202,167 @@ class TestConvBNTrain:
         assert np.isfinite(np.asarray(g)).all()
 
 
+class TestExpanding1x1ConvBN:
+    """conv_bn_train's second custom_vjp: the expanding 1x1 convolution
+    (1x1, stride 1, unpadded, cout > cin) whose backward reads the
+    convolution's INPUT where fused_conv_bn's reads its output (ISSUE 28).
+    The oracle is autodiff of conv_bn_reference."""
+
+    @staticmethod
+    def _xwc(cin, cout, dtype=jnp.float64, shift=0.0, hw=8, n=4):
+        k = jax.random.PRNGKey(100 * cin + cout)
+        x = jax.random.normal(jax.random.fold_in(k, 0), (n, hw, hw, cin),
+                              jnp.float64) * 2.0 + shift
+        w = jax.random.normal(jax.random.fold_in(k, 1), (1, 1, cin, cout),
+                              jnp.float64)
+        cot = jax.random.normal(jax.random.fold_in(k, 2), (n, hw, hw, cout),
+                                jnp.float64)
+        return x.astype(dtype), w.astype(dtype), cot.astype(dtype)
+
+    @staticmethod
+    def _grads(fn, x, w, cot):
+        return jax.grad(lambda x_, w_: jnp.sum(fn(x_, w_) * cot),
+                        argnums=(0, 1))(x, w)
+
+    # shift != 0: sum g (y - mean) is a difference of two large sums on
+    # this path (sum_k W G - mean g_sum): the cancellation must be exact
+    @pytest.mark.parametrize("shift", [0.0, 7.5])
+    @pytest.mark.parametrize("cin,cout", [(4, 16), (64, 256), (3, 5)])
+    def test_gradients_match_reference(self, cin, cout, shift):
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x, w, cot = self._xwc(cin, cout, shift=shift)
+        got = self._grads(lambda x_, w_: conv_bn_train(x_, w_, 1, 0)[0],
+                          x, w, cot)
+        ref = self._grads(lambda x_, w_: conv_bn_reference(x_, w_, 1, 0),
+                          x, w, cot)
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-8, atol=1e-10)
+
+    @pytest.mark.parametrize("cin,cout", [(4, 16), (3, 5)])
+    def test_forward_bitwise_equal_to_shared_forward(self, cin, cout):
+        from faster_distributed_training_tpu.ops.conv_bn import (
+            _conv_bn_forward, conv_bn_train)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            x, w, _ = self._xwc(cin, cout, dtype, shift=1.0)
+            out, _, mean, var = _conv_bn_forward(x, w, 1, 0, 1e-3)
+            for a, b in zip(conv_bn_train(x, w, 1, 0, 1e-3),
+                            (out, mean, var)):
+                assert a.dtype == b.dtype
+                np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                              np.asarray(b, np.float32))
+
+    def test_stats_cotangents_ignored_as_in_fused(self):
+        """mean/var are stats-only outputs on both custom_vjps: a loss that
+        reads them gets the gradient of its `out` term alone."""
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x, w, cot = self._xwc(4, 16, shift=1.0)
+
+        def loss(x_, w_):
+            out, mean, var = conv_bn_train(x_, w_, 1, 0)
+            return jnp.sum(out * cot) + 3.0 * jnp.sum(mean) + jnp.sum(var ** 2)
+
+        got = jax.grad(loss, argnums=(0, 1))(x, w)
+        ref = self._grads(lambda x_, w_: conv_bn_reference(x_, w_, 1, 0),
+                          x, w, cot)
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-8, atol=1e-10)
+
+    # what the shape dispatch leaves alone must read what it read: the
+    # reducing and the square 1x1, a strided 1x1, a padded 1x1, a 3x3
+    @pytest.mark.parametrize("k,cin,cout,stride,padding", [
+        (1, 4, 4, 1, 0), (1, 16, 4, 1, 0), (1, 4, 16, 2, 0),
+        (1, 4, 16, 1, 1), (3, 4, 16, 1, 1)])
+    def test_other_shapes_bitwise_equal_fused_conv_bn(self, k, cin, cout,
+                                                      stride, padding):
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        key = jax.random.PRNGKey(7)
+        x = jax.random.normal(jax.random.fold_in(key, 0), (4, 8, 8, cin),
+                              jnp.float32)
+        w = jax.random.normal(jax.random.fold_in(key, 1), (k, k, cin, cout),
+                              jnp.float32)
+
+        def grads(fn):
+            return jax.grad(lambda x_, w_: jnp.sum(fn(x_, w_)[0] ** 2),
+                            argnums=(0, 1))(x, w)
+
+        got = grads(lambda x_, w_: conv_bn_train(x_, w_, stride, padding))
+        ref = grads(lambda x_, w_: fused_conv_bn(x_, w_, stride, padding))
+        for a, b in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("k,cin,cout,remat,scoped", [
+        (1, 4, 16, True, True), (1, 4, 16, False, False),
+        (1, 16, 4, True, False), (3, 4, 16, True, False)])
+    def test_scope_names_the_layers_that_take_the_path(self, k, cin, cout,
+                                                       remat, scoped):
+        """`fdt/conv1x1_bn_bwd` is the path's static counter: it is in the
+        lowered program exactly where the new backward is."""
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x = jnp.ones((2, 4, 4, cin), jnp.float32)
+        w = jnp.ones((k, k, cin, cout), jnp.float32)
+        text = jax.jit(jax.grad(lambda x_: jnp.sum(conv_bn_train(
+            x_, w, 1, k // 2, remat=remat)[0] ** 2))).lower(x).as_text(
+                debug_info=True)
+        assert ("fdt/conv1x1_bn_bwd" in text) == scoped
+
+    def test_bf16_within_2e2_of_float32_oracle(self):
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x, w, cot = self._xwc(64, 256, jnp.float32, shift=0.5, hw=16)
+        ref = self._grads(lambda x_, w_: conv_bn_reference(x_, w_, 1, 0),
+                          x, w, cot)
+        got = self._grads(lambda x_, w_: conv_bn_train(x_, w_, 1, 0)[0],
+                          *(a.astype(jnp.bfloat16) for a in (x, w, cot)))
+        for a, b in zip(got, ref):
+            assert a.dtype == jnp.bfloat16
+            gap = (np.linalg.norm(np.asarray(a, np.float32) - np.asarray(b))
+                   / np.linalg.norm(np.asarray(b)))
+            assert gap < 2e-2, gap
+
+    def test_degenerate_constant_channel_finite(self):
+        """TestConvBNTrain's clamp-edge case on THIS path: var == 0."""
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x = jnp.ones((2, 4, 4, 1), jnp.float32)
+        w = jnp.ones((1, 1, 1, 4), jnp.float32)
+        gx, gw = jax.grad(lambda x_, w_: jnp.sum(
+            (conv_bn_train(x_, w_, 1, 0, 1e-3)[0] + 1.0) ** 2),
+            argnums=(0, 1))(x, w)
+        assert np.isfinite(np.asarray(gx)).all()
+        assert np.isfinite(np.asarray(gw)).all()
+
+    def test_no_conv_output_shaped_backward_residual(self):
+        """The point of the path: nothing of the output's [N,H,W,cout] size
+        lives from forward to backward (fused_conv_bn's residuals are the
+        same four, but its backward asks for y again and XLA keeps it)."""
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x, w, _ = self._xwc(4, 16, jnp.float32)
+        out, vjp = jax.vjp(lambda x_, w_: conv_bn_train(x_, w_, 1, 0)[0],
+                           x, w)
+        leaves = jax.tree.leaves(vjp)
+        assert any(np.shape(leaf) == x.shape for leaf in leaves)
+        for leaf in leaves:
+            assert np.size(leaf) < out.size, np.shape(leaf)
+
+    def test_batch_sharded_gives_global_sums(self, devices8):
+        """The contractions over rows are plain sums over the batch axis:
+        under a batch-sharded mesh they stay GLOBAL, as the statistics."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x, w, cot = self._xwc(4, 16, n=8)
+        fn = jax.jit(lambda x_, w_, c_: self._grads(
+            lambda a, b: conv_bn_train(a, b, 1, 0)[0], x_, w_, c_))
+        ref = fn(x, w, cot)
+        mesh = Mesh(np.asarray(devices8), ("dp",))
+        rows = NamedSharding(mesh, P("dp"))
+        got = fn(jax.device_put(x, rows),
+                 jax.device_put(w, NamedSharding(mesh, P())),
+                 jax.device_put(cot, rows))
+        for a, b in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-10, atol=1e-12)
+
+
 class TestFusedFFNSublayer:
     """ops/fused_ffn.py — the whole pre-LN FFN sublayer (LN -> Dense ->
     GELU -> dropout -> Dense -> dropout -> +residual) as ONE Pallas

@@ -546,6 +546,9 @@ def test_reader_splits_a_chip_trace_by_scope(chip_trace):
     ("jit(step)/jvp(fdt/model)/ResNet/conv", "jvp(fdt/model)"),
     ("jit(step)/transpose(jvp(fdt/model))/ResNet/conv",
      "transpose(jvp(fdt/model))"),
+    ("jit(step)/fdt/model/transpose(jvp(fdt/model))/ResNet/BottleNeck_0/"
+     "FusedConvBNLayer_2/fdt/conv1x1_bn_bwd/nhwk,nhwc->kc/dot_general",
+     "transpose(jvp(fdt/model))/fdt/conv1x1_bn_bwd"),
     ("jit(step)/fdt/optimizer/ngd/vmap()/mul", "fdt/optimizer/ngd"),
     ("jit(step)/fdt/optimizer/ngd/cond/branch_1_fun/fisher_update/eigh",
      "fdt/optimizer/ngd/fisher_update"),
