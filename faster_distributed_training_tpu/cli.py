@@ -214,50 +214,22 @@ def apply_subset(ds, stride: int):
 
 # The dense path's backward holds ~3 score-shaped fp32 tensors at peak
 # (saved probs residual + ds/dp transients — same accounting as flash's
-# _DENSE_BWD_BUDGET_BYTES, validated by the measured +1.6 GB at
-# bs256/seq256 ≈ 3 x 537 MB).  The routing budget below caps that
-# footprint so auto-routing can never walk a big-batch config into HBM
-# exhaustion: the materialized probs scale with B·L².  Override via
-# FDT_DENSE_ATTN_BUDGET_MB (0 forces flash everywhere).
+# _DENSE_BWD_BUDGET_BYTES; an r5 chip reading of +1.6 GB at
+# bs256/seq256 ≈ 3 x 537 MB agreed, not measured since).  The routing
+# budget below caps that footprint so auto-routing can never walk a
+# big-batch config into HBM exhaustion: the materialized probs scale
+# with B·L².  Override via FDT_DENSE_ATTN_BUDGET_MB (0 forces flash
+# everywhere).
 _DENSE_ATTN_BUDGET_MB = 4096
 
-# The measured attention routing surface (VERDICT r5 #5; extended to
-# the 4-impl {dense, flash, ring, ulysses} surface in r11).  Every cell
-# the auto-router serves cites the bench arm that measures it; the arms
-# (attn_route_*) land in BENCH_LATEST.json per round under the
-# regression guard, so a crossover drift shows up as a flagged move.
-#
-# Row format: (bs, seq, routed impl, bench arm, mesh condition).
-# mesh condition "" = mesh-independent (1D / no model axis); "sp" = the
-# mesh has a sequence-capable model axis (a dedicated sp axis, or tp —
-# the axis NAME doesn't change the shard_map math, so tp-axis routing
-# cites the same arms) whose size divides both heads and seq (ulysses
-# eligible); "sp_ragged" = model axis present but heads/seq don't
-# divide (ring, which accepts any axis size).
-_ATTN_ROUTE_SURFACE = (
-    (256, 256, "dense", "transformer_agnews_ex_per_sec_bs256_seq256", ""),
-    (512, 128, "dense", "attn_route_bs512_seq128_dense_step_ms", ""),
-    (1024, 128, "dense", "attn_route_bs1024_seq128_dense_step_ms", ""),
-    (512, 256, "dense", "attn_route_bs512_seq256_dense_step_ms", ""),
-    (1024, 256, "flash", "attn_route_bs1024_seq256_flash_step_ms", ""),
-    (256, 384, "flash", "attn_route_bs256_seq384_flash_step_ms", ""),
-    (64, 512, "flash", "transformer_agnews_ex_per_sec_bs64_seq512", ""),
-    # r11 sequence-parallel cells (bench.ATTN_ROUTE_SP_BENCH_CELLS
-    # measures flash/ring/ulysses at each; the flash arm is the
-    # single-chip-replicated alternative the sp routing must beat):
-    (8, 2048, "ulysses", "attn_route_bs8_seq2048_ulysses_step_ms", "sp"),
-    (8, 2048, "ring", "attn_route_bs8_seq2048_ring_step_ms", "sp_ragged"),
-    (4, 4096, "ulysses", "attn_route_bs4_seq4096_ulysses_step_ms", "sp"),
-    (4, 4096, "ring", "attn_route_bs4_seq4096_ring_step_ms", "sp_ragged"),
-)
-
-# Sequence length from which a (data, model) mesh's model axis routes
-# attention sequence-parallel instead of single-chip dense/flash — the
-# boundary sits at the first measured sp cell (bs8/seq2048,
-# attn_route_bs8_seq2048_* arms); below it the 1D surface still rules
+# Sequence length from which a tp model axis routes attention
+# sequence-parallel instead of single-chip dense/flash; below it the
+# axis serves tensor parallelism and the dense/flash rule applies
 # (dense/flash are tp-compatible: dense head-shards, flash is rerouted
-# by build_model's capability fallback).  Provisional pending the first
-# live TPU record — PARITY "r6 A/B follow-up decision" step (f).
+# by build_model's capability fallback).  This boundary and the
+# dense/flash crossover in resolve_attention come from r5/r6 chip
+# readings older than most of this code, not re-measured since;
+# ROADMAP S8 decides them in the encoder's cells.
 _SEQ_PARALLEL_MIN_LEN = 2048
 
 
@@ -285,58 +257,30 @@ def _route_model_axis(cfg: TrainConfig, ax_size: int) -> Optional[str]:
     ulysses when the axis also divides the heads (lower interconnect
     volume — O(B·H·L·D/sp) per tensor, collective-free inner kernel;
     the documented trade in ops/ulysses_attention.py), ring otherwise
-    (any head count).  Per-cell attn_route_*_{ring,ulysses}_step_ms
-    arms measure both sides so the preference stays falsifiable."""
+    (any head count).  The preference is not measured on the chip
+    (ROADMAP S8)."""
     if cfg.seq_len % ax_size:
         return None
     return "ulysses" if cfg.n_heads % ax_size == 0 else "ring"
 
 
 def resolve_attention(cfg: TrainConfig, mesh=None) -> str:
-    """'' auto-resolves from the measured 4-impl surface
-    {dense, flash, ring, ulysses}.  Explicit --attention always wins.
+    """'' auto-resolves among {dense, flash, ring, ulysses}; an explicit
+    --attention always wins.
 
-    Mesh-dependent tier first (_ATTN_ROUTE_SURFACE's "sp"/"sp_ragged"
-    rows): a dedicated sp axis routes sequence-parallel whenever it can
-    serve the shape (_route_model_axis: seq must divide the axis —
-    both strategies shard L over it; ulysses when the heads divide too,
-    else ring — r6 routed a blanket "ring" here; the split is now
-    measured per cell by the attn_route_bs8_seq2048_* / bs4_seq4096_*
-    arm triples); a tp axis routes sequence-parallel only from
-    _SEQ_PARALLEL_MIN_LEN up (below it the model axis serves tensor
-    parallelism and the 1D surface rules).  Shapes the model axis
-    can't serve fall through to the mesh-independent 2D dense/flash
-    crossover: on TPU, DENSE inside the measured envelope and flash
-    beyond; dense off-TPU.
+    A causal LM routes dense (the only impl that takes a full
+    query-by-key mask).  A sequence-capable model axis routes
+    sequence-parallel (_route_model_axis: ulysses when the axis divides
+    seq and heads, ring when it divides seq only) from
+    _SEQ_PARALLEL_MIN_LEN up, or at any length on a dedicated sp axis;
+    a seq_len the axis can't divide falls through to the single-chip
+    rule.  Off TPU that rule is dense; on TPU dense while seq_len <= 256
+    and three fp32 [B,H,L,L] score tensors fit the budget
+    (_dense_attn_fits), else flash.
 
-    The 2D surface (r5 + r6 bench arms, v5e, NGD full step):
-
-      * seq<=256, bs<=256 — DENSE: 99.8 ms/step dense vs 111.9 flash @
-        bs256/seq256 once dense prob dropout went through the stateless
-        hash engine — at L<=256 the monolithic kernel's per-(b,h)-
-        instance overhead exceeds XLA's batched GEMM+softmax cost
-        (r5 probe; guarded per-round by
-        transformer_agnews_ex_per_sec_bs256_seq256).
-      * seq<=256, bs in {512, 1024} — DENSE while the probs fit: at
-        fixed L the per-example cost of both paths scales ~linearly in
-        B, so the L-crossover carries over; pinned per-round by the
-        attn_route_bs512_seq128 / bs1024_seq128 / bs512_seq256
-        dense-vs-flash step-ms arm pairs in BENCH_LATEST.json.
-      * memory-headroom bound (_dense_attn_fits): dense materializes
-        ~3 fp32 [B,H,L,L] score tensors at the backward peak (measured
-        +1.6 GB at bs256/seq256), so cells past the budget route flash
-        regardless — bs1024/seq256 is 3·4·1024·8·256² = 6.4 GB > the
-        4 GB default budget (flash side measured by
-        attn_route_bs1024_seq256_flash_step_ms; dense deliberately not
-        benched, the bound exists to keep it un-runnable configs away).
-      * seq >= 384 — FLASH: flash wins from L=512 down (58.6 vs 69.6 ms
-        @ bs64/seq512, transformer_agnews_ex_per_sec_bs64_seq512), and
-        the seq=384 arm pair (attn_route_bs256_seq384_*_step_ms) pins
-        the boundary cell between the measured 256 and 512 points.
-
-    The surface is recorded row-by-row in _ATTN_ROUTE_SURFACE (cell ->
-    impl -> measuring arm -> mesh condition) and tests/test_substrate.py
-    asserts every routed cell's arm actually exists in bench.py."""
+    The crossover comes from r5/r6 chip readings older than most of
+    this code, not re-measured since; ROADMAP S8 decides it in the
+    encoder's cells."""
     if cfg.attention:
         return cfg.attention
     if (getattr(cfg, "task", "cls") == "lm"
@@ -958,11 +902,10 @@ def run_training(cfg: TrainConfig,
         resident.verify_upload()
         log("[sentinel] device-resident upload verified: post-upload "
             "readback matches the host-side encode checksums")
-    # -- telemetry (r12): every run emits the structured surface bench.py
-    # used to monopolize — per-dispatch JSONL + manifest + span breakdown
-    # + (pods) the epoch straggler fold.  build_telemetry returns None
-    # under --no_telemetry / FDT_TELEMETRY=0 and the hot loop gets zero
-    # new work.
+    # -- telemetry (r12): every run emits a structured record of itself
+    # — per-dispatch JSONL + manifest + span breakdown + (pods) the
+    # epoch straggler fold.  build_telemetry returns None under
+    # --no_telemetry and the hot loop gets zero new work.
     from faster_distributed_training_tpu.telemetry import (
         build_telemetry, flight, programs, resolve_telemetry_dir, spans,
         write_manifest)
@@ -1003,8 +946,8 @@ def run_training(cfg: TrainConfig,
             # schedule accounting into the telemetry stream: the
             # analytic bubble (the executed program pays exactly this —
             # fill/drain ticks compute on discarded microbatches) and the
-            # per-stage idle/active tick split the pp_stage_idle_ms
-            # bench arm scales by measured tick time
+            # per-stage idle/active tick split (idle ms = idle ticks x
+            # a measured tick time; not measured on the chip)
             telemetry.recorder.record_event(
                 "pp_bubble", n_stages=pipeline.n_stages,
                 n_microbatches=pipeline.n_microbatches,
@@ -1027,7 +970,7 @@ def run_training(cfg: TrainConfig,
                                        .goodput_event_sink)
         log(f"[telemetry] recording to {telemetry.directory} "
             f"(host {telemetry.pi}/{telemetry.pc}; disable with "
-            f"--no_telemetry or FDT_TELEMETRY=0)")
+            f"--no_telemetry)")
     if telemetry is not None and telemetry.observatory is not None:
         # r17 instant restart: the persistent executable cache rides the
         # compile observatory (lookup-before-compile / store-after-
@@ -1205,8 +1148,8 @@ def run_training(cfg: TrainConfig,
            "best_acc": trainer.best_acc, "cfg": cfg}
     if stream is not None and trainer.stream_stall_pct is not None:
         # the streamed input path's headline: steady-state % of step
-        # time blocked on the window refill (<1% target, bench arm
-        # stream_stall_pct measures it under the guard)
+        # time blocked on the window refill (<1% target; not measured
+        # on the chip: no cell streams, PERF.md 7 row 2)
         out["stream_stall_pct"] = round(trainer.stream_stall_pct, 3)
         log(f"[stream] steady-state stall: {out['stream_stall_pct']}% of "
             f"step time blocked on the data window (target <1%)")
@@ -1271,7 +1214,7 @@ def run_serving(cfg: TrainConfig, requests=None,
     prev_rec = None
     obs = None
     prev_obs = None
-    if cfg.telemetry and os.environ.get("FDT_TELEMETRY", "1") != "0":
+    if cfg.telemetry:
         import dataclasses
         import time as time_mod
 
@@ -1339,7 +1282,7 @@ def run_serving(cfg: TrainConfig, requests=None,
             # replicas round-robin over local devices; fewer replicas
             # than chips occupy only min(n, devices) of them — the
             # per-chip headline divides by chips actually SERVING, not
-            # the host's total (a 2-replica bench on an 8-chip host
+            # the host's total (a 2-replica run on an 8-chip host
             # would otherwise understate qps/chip 4x)
             chips_serving = min(n_rep, len(devs))
         with spans.span("serve_warmup"):
@@ -1430,7 +1373,7 @@ def run_decode_serving(cfg: TrainConfig, prompts=None,
     prev_rec = None
     obs = None
     prev_obs = None
-    if cfg.telemetry and os.environ.get("FDT_TELEMETRY", "1") != "0":
+    if cfg.telemetry:
         import dataclasses
         import time as time_mod
 

@@ -206,7 +206,7 @@ def write_array_dataset(directory: str, arrays: Dict[str, np.ndarray],
                         rows_per_shard: int = 4096,
                         meta: Optional[dict] = None) -> dict:
     """Convenience wrapper: one in-memory dict of full arrays -> shards.
-    Used by the image data-path bench arm and the tests; real corpora
+    Used by scripts/shard_dataset.py and the tests; real corpora
     stream through ``write_stream_dataset``'s chunk iterable."""
     return write_stream_dataset(directory, [arrays],
                                 rows_per_shard=rows_per_shard, meta=meta)

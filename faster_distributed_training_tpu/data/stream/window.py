@@ -29,8 +29,8 @@ Telemetry: each refill emits a ``stream_refill`` event (+ a
 ``stream_refill`` span from the producer thread, so the cost also lands
 in the span breakdown / XLA trace vocabulary), and each buffer swap the
 consumer had to WAIT for emits a ``stream_stall`` event — the numerator
-of bench's ``stream_stall_pct`` (<1% steady-state target, the input-
-pipeline sibling of ``ckpt_async_overhead_pct``)."""
+of ``Trainer.stream_stall_pct`` (<1% steady-state target; not measured
+on the chip)."""
 
 from __future__ import annotations
 

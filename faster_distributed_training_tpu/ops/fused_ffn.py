@@ -3,8 +3,8 @@ LN -> Dense(d_ff) -> GELU -> dropout -> Dense(d_model) -> dropout -> +residual.
 
 Motivation (VERDICT r4 #1 "attack the gap"): the round-5 identity-LN
 probe measured the transformer's 13 LayerNorm sites at ~7.5 ms of the
-112 ms step @ bs256/seq256 (`scripts/transformer_roofline.py
-ngd_256_256_noln`) — pure HBM round-trips, which XLA cannot fuse into
+112 ms step @ bs256/seq256 (an identity-LayerNorm A/B on the chip in
+r5, not measured since) — pure HBM round-trips, which XLA cannot fuse into
 the adjacent GEMMs (reductions only fuse with elementwise consumers,
 never into a dot).  This kernel computes the WHOLE pre-LN FFN sublayer
 of `models/transformer.py::EncoderLayer` per row-block with every

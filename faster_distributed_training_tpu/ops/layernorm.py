@@ -27,7 +27,7 @@ Two entry points:
     pass, so residual traffic per site drops to the input (alive
     anyway, it feeds the sublayer residual add) plus 2 scalars/row.
     Kill switch ``FDT_LN_SAVED_STATS=0`` restores default autodiff for
-    A/B probes (scripts/transformer_roofline.py).
+    A/B probes (none is in the tree: ROADMAP D3).
 
 The backward math, for y = γ·x̂ + β with x̂ = (x − μ)·r,
 r = 1/(σ + eps), σ = √(Σ(x−μ)²/(n−1)) (UNBIASED, n−1):

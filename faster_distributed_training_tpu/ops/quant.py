@@ -2,8 +2,9 @@
 delayed scaling (the ROADMAP "close the MFU gap with low-precision
 compute" lever).
 
-BENCH_LATEST pins the transformer at 29.1% MFU against a measured 35%
-bf16 GEMM ceiling — ~6 points of headroom left at this precision.  The
+The r5 chip readings put the transformer at 29.1% MFU against a 35%
+bf16 GEMM ceiling (not measured since; no cell of the ledger runs the
+encoder, PERF.md 7 row 0b) — ~6 points of headroom at this precision.  The
 MXU's int8/fp8 throughput is ~2x its bf16 peak, so the big remaining
 lever is dropping the GEMM operand precision while keeping fp32
 accumulation.  This module follows the established low-precision

@@ -55,8 +55,8 @@ not gate them.
 
 Enablement: the tp layer is ON by default; ``FDT_KERNEL_SHARD=0`` kills
 it, restoring the r11/r13 warned capability fallbacks — which also
-makes the kill switch the bench A/B arm (kernel-via-shard_map vs
-forced fallback, ``transformer_tp2_*`` arms).  Non-dividing shapes
+makes the kill switch the A/B seam (kernel-via-shard_map vs forced
+fallback; not measured on the chip, ROADMAP D3).  Non-dividing shapes
 (heads/d_ff/seq not divisible by tp) take the same registered warned
 fallbacks; ``scripts/check_kernel_routing.py`` (tier-1) lints that no
 NEW call site reaches a Pallas kernel entry point outside this layer
@@ -80,7 +80,7 @@ ENV_KILL = "FDT_KERNEL_SHARD"
 
 
 def enabled() -> bool:
-    """FDT_KERNEL_SHARD=0 kill switch (read per call so bench children
+    """FDT_KERNEL_SHARD=0 kill switch (read per call so child processes
     and tests can flip it): False restores the pre-r19 warned
     capability fallbacks on tp meshes."""
     return os.environ.get(ENV_KILL, "1") != "0"

@@ -333,8 +333,8 @@ def schedule_ticks(n_stages: int, n_microbatches: int
 
 def stage_idle_ticks(spec: PipelineSpec) -> Tuple[int, ...]:
     """Bubble slot-ticks per stage — the per-stage accounting the
-    ``pp_stage`` telemetry records and the ``pp_stage_idle_ms`` bench
-    arm scales by the measured tick time.  Each of a stage's V/S slots
+    ``pp_stage`` telemetry records carry (idle ms = these ticks x a
+    measured tick time; not measured on the chip).  Each of a stage's V/S slots
     idles exactly V-1 = T-M of the T ticks under the rotation
     schedule, so a stage's idle total is (V/S)(V-1): S-1 for 1f1b,
     2(2S-1) under v=2 interleaving (the lengthened fill/drain the

@@ -328,8 +328,8 @@ def zero_opt_state_specs(opt_state: Any, params: Any, param_specs: Any,
 # param leaf class cannot silently re-replicate over pp.
 #
 # Honest scope note (the CPU-measurable claim): this is RESIDENCY —
-# bytes at rest per chip scale with 1/pp, which is what the
-# pp_param_bytes_per_chip bench arms measure.  On the steady path the
+# bytes at rest per chip scale with 1/pp (the ``memory`` telemetry
+# event's per-chip byte table shows it).  On the steady path the
 # unrolled tick loop applies each layer once per tick, and GSPMD
 # gathers a stage's shard set at first use and CSEs the gather across
 # ticks (ZeRO-3-class traffic, one gather per layer per step); the

@@ -21,8 +21,7 @@ layered on machinery the repo already has:
     corruption) that the CPU test suite drives;
   * ``goodput``     — :class:`GoodputTracker`: productive time vs.
     checkpoint/restore/restart badput (and restart MTTR), surfaced per
-    epoch through ``train/metrics.py`` and benched by the ``ckpt_*`` /
-    ``restart_mttr_s`` bench.py arms;
+    epoch through ``train/metrics.py``;
   * ``coordinator`` — :class:`PodCoordinator` (r10): pod-coordinated
     restarts (shared-fs generation rendezvous so every host restarts
     into the same generation) + the cluster health watchdog (per-host

@@ -964,8 +964,8 @@ class PodCoordinator:
             # r17: this host is a warm spare completing its first
             # release after claiming a seat — the claim→release wall
             # time IS the swap (restore + catch-up + readiness barrier;
-            # programs were warmed while parked), the number the
-            # warm_spare_swap_s bench arm commits.  Tracked beside the
+            # programs were warmed while parked), reported as
+            # goodput's warm_spare_swap_s.  Tracked beside the
             # badput segments, not among them: the window contains the
             # restore segment and productive catch-up steps.
             if self._goodput is not None:

@@ -11,9 +11,9 @@ slots in as a lookup-before-compile / store-after-compile hook
 .ProgramObservatory`): a fresh process deserializes its (train, eval,
 epoch-reshard, serve-predict) programs instead of recompiling them and
 records ``cache_source="deserialized"`` per program in the manifest
-``compile`` table, where the A/B against ``cache_source="compiled"``
-rounds is a committed number (bench ``restart_cached_mttr_s`` vs
-``restart_mttr_s``).
+``compile`` table (the A/B against ``cache_source="compiled"`` is
+``scripts/pod_restart_smoke.py --cache``'s, on the CPU; restart MTTR is
+not measured on the chip).
 
 Mechanics
 ---------
@@ -58,7 +58,7 @@ Mechanics
 
 Enablement: ``--executable_cache on`` (or an explicit directory/key
 prefix), env ``FDT_EXEC_CACHE`` (``0`` kills it, ``on``/path arms it —
-the bench/smoke seam).  The cache rides the observatory, so
+the smoke seam).  The cache rides the observatory, so
 ``FDT_PROGRAM_OBS=0`` disables it too.
 """
 
@@ -76,7 +76,7 @@ ENV_CACHE = "FDT_EXEC_CACHE"
 # yet"): the _exec_cache/ prefix is bounded by entry count AND total
 # payload bytes with LRU eviction by last_used — a long-lived
 # checkpoint_dir no longer accretes one executable per (HLO x
-# environment) key forever.  Env overrides for bench/tests.
+# environment) key forever.  Env overrides for tests.
 ENV_MAX_ENTRIES = "FDT_EXEC_CACHE_MAX_ENTRIES"
 ENV_MAX_BYTES = "FDT_EXEC_CACHE_MAX_BYTES"
 DEFAULT_MAX_ENTRIES = 64
@@ -372,7 +372,7 @@ def build_executable_cache(cfg, backend=None, mesh=None,
     = ``<checkpoint_dir>/_exec_cache`` through the run's storage
     backend, anything else = an explicit directory.  ``FDT_EXEC_CACHE``
     overrides (``0`` = force off — the kill switch; ``on``/path = force
-    on, the bench/smoke seam).  Arming the cache also zeroes the
+    on, the smoke seam).  Arming the cache also zeroes the
     persistent-compilation-cache store floor (:func:`arm_persistent_
     cache`) so the fallback tier serves sub-second programs."""
     spec = (getattr(cfg, "executable_cache", "") or "").strip()

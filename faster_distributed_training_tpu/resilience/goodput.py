@@ -9,9 +9,8 @@ crash).  Everything here is host-side bookkeeping: a few float adds per
 event, nothing per-step on the hot path.
 
 Consumed by: the Trainer (epoch ``[goodput]`` log line via
-``train/metrics.py:attach_goodput``), ``cli.run_training`` (summary in
-the result dict), and the ``ckpt_*`` arms in bench.py (checkpoint
-overhead per step, async vs sync vs off)."""
+``train/metrics.py:attach_goodput``) and ``cli.run_training`` (summary
+in the result dict)."""
 
 from __future__ import annotations
 

@@ -11,8 +11,8 @@ checkpoints don't give:
     (``jax.device_get`` of the state, unavoidable: the very next train
     step donates those buffers) and the orbax serialization + disk
     write, which run on a single background worker.  Only the snapshot
-    time touches step latency; bench.py's ``ckpt_async_*`` arms measure
-    it at <1% of median step time;
+    time touches step latency (target <1% of median step time; not
+    measured on the chip);
   * keep-last-K retention — committed checkpoints beyond ``keep`` are
     pruned after each successful commit, and uncommitted residue
     (half-written directories from a previous crash) is swept;
@@ -87,7 +87,7 @@ class AsyncCheckpointManager:
     one process, complementary ``shard_owner`` functions, one shared
     directory = a simulated two-host pod save).  ``force_sharded``
     routes even a single-process manager down the per-host shard-
-    streaming path (bench's ``ckpt_async_sharded`` arm)."""
+    streaming path (tests/test_pod_scale.py)."""
 
     def __init__(self, directory: str, prefix: str = "ckpt",
                  every_steps: int = 0, every_secs: float = 0.0,
@@ -118,7 +118,7 @@ class AsyncCheckpointManager:
         self.backend = backend if backend is not None \
             else storage_mod.posix_backend()
         # per-host shard-streaming saves whenever >1 process (the r7
-        # sync-collective fallback is gone), or forced for bench/tests,
+        # sync-collective fallback is gone), or forced for tests,
         # or whenever the backend is not plain POSIX (see above)
         self._sharded = (bool(force_sharded) or self._pc > 1
                          or self.backend.kind != "posix")

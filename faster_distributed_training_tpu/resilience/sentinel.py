@@ -145,7 +145,7 @@ class QuarantineLedger:
          "batches": {"<epoch>": [position, ...]},
          "shards":  [shard_index, ...]}
 
-    ``backend=None`` (no resilience bundle — bench probes) degrades to
+    ``backend=None`` (no resilience bundle — library probes) degrades to
     in-memory only."""
 
     def __init__(self, backend=None, key: str = LEDGER_KEY):
@@ -199,8 +199,8 @@ class Sentinel:
     the deterministic skips for the dispatch loops.
 
     ``observe(...)`` is only called in ``full`` mode — it costs one
-    device->host loss readback per dispatch (the documented sync the
-    ``sentinel_overhead_pct`` bench arm measures); ``guard`` mode adds
+    device->host loss readback per dispatch (the documented sync; its
+    cost is not measured on the chip); ``guard`` mode adds
     ZERO host work (the in-graph guard is self-contained)."""
 
     def __init__(self, mode: str, backend=None, goodput=None,

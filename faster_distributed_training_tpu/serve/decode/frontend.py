@@ -152,7 +152,7 @@ def worker_main(argv: Optional[List[str]] = None) -> int:
     recorder = None
     obs = None
     prev_obs = None
-    if cfg.telemetry and os.environ.get("FDT_TELEMETRY", "1") != "0":
+    if cfg.telemetry:
         tdir = resolve_telemetry_dir(cfg)
         recorder = TelemetryRecorder(tdir, log=log)
         update_manifest(tdir, {"decode_worker": {

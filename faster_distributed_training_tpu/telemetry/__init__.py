@@ -1,7 +1,7 @@
 """Run-scoped telemetry subsystem (r12).
 
-Every training run — not just ``bench.py`` — emits a structured,
-machine-readable record of itself:
+Every training run emits a structured, machine-readable record of
+itself:
 
   * ``recorder``  — :class:`TelemetryRecorder`: low-overhead host-side
     ring buffer of per-dispatch records (step, wall ms, examples/s,
@@ -23,10 +23,10 @@ machine-readable record of itself:
     ``--profile_steps A:B`` (utils/profiling.StepWindowProfiler) starts/
     stops ``jax.profiler`` around a step range mid-run.
 
-Kill switch: ``FDT_TELEMETRY=0`` (or ``--no_telemetry``) disables the
-whole subsystem — :func:`build_telemetry` returns None and the Trainer's
-hot loop has zero new work.  The ``telemetry_overhead_pct`` bench arm
-guards the enabled cost at <1% of median step time.
+Kill switch: ``--no_telemetry`` disables the whole subsystem —
+:func:`build_telemetry` returns None and the Trainer's hot loop has zero
+new work.  The enabled cost is meant to stay under 1% of median step
+time; not measured on the chip.
 """
 
 from __future__ import annotations
@@ -45,7 +45,7 @@ from faster_distributed_training_tpu.telemetry.programs import (  # noqa: F401,E
     ObservedJit, ProgramObservatory, sharding_fingerprint, sharding_table,
     state_bytes_table)
 from faster_distributed_training_tpu.telemetry.recorder import (  # noqa: F401,E501
-    ENV_KILL, MANIFEST, SCHEMA_VERSION, TELEMETRY_SCHEMA, TelemetryRecorder,
+    MANIFEST, SCHEMA_VERSION, TELEMETRY_SCHEMA, TelemetryRecorder,
     update_manifest, write_manifest)
 
 
@@ -144,10 +144,8 @@ class RunTelemetry:
 def build_telemetry(cfg, log: Callable[[str], None] = print
                     ) -> Optional[RunTelemetry]:
     """RunTelemetry for a TrainConfig, or None when disabled
-    (``--no_telemetry`` / ``FDT_TELEMETRY=0`` — the kill switch the
-    bench overhead arm and emergency rollbacks rely on)."""
-    if os.environ.get(ENV_KILL, "1") == "0":
-        return None
+    (``--no_telemetry`` — the kill switch emergency rollbacks rely
+    on)."""
     if not getattr(cfg, "telemetry", True):
         return None
     recorder = TelemetryRecorder(

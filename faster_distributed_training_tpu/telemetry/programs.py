@@ -20,8 +20,8 @@ pieces close the gap:
     (argument/output/temp/generated).  Steady-state calls go straight to
     the AOT executable (measured ~0.5 us over the jit C++ fast path on
     CPU — program collection happens at compile boundaries, never
-    per-dispatch, which is what keeps ``telemetry_overhead_pct`` under
-    its <1% guard).
+    per-dispatch, which is what keeps telemetry's cost a per-compile
+    one).
   * the RETRACE detector — lowerings are counted per program name; a
     name lowering again with the SAME signature, or with a signature
     that differs only in dtype/weak-type (the classic non-weak-type
@@ -34,7 +34,7 @@ pieces close the gap:
   * HBM attribution helpers — :func:`state_bytes_table` splits the
     train state's per-chip bytes params vs opt_state vs batch_stats
     (``opt_state_bytes_per_chip`` is THE number ROADMAP's ZeRO item is
-    specified against; bench.py lands it as a committed baseline), and
+    specified against), and
     :func:`sharding_fingerprint` / :func:`sharding_table` are the
     sharding-DRIFT guard: the Trainer fingerprints the live state's
     shardings after step 1 and re-checks at every epoch boundary,

@@ -1,18 +1,18 @@
 """Run-scoped telemetry: per-dispatch records + run manifest as JSONL.
 
-Every ordinary training run emits machine-readable evidence — not just
-dedicated ``bench.py`` runs: a :class:`TelemetryRecorder` buffers one
-small host-side record per dispatch (step, wall ms, examples/s,
-data-wait ms, checkpoint-blocking ms, K, epoch) plus span/epoch/goodput
-events, and a single background writer appends them as JSONL to
+Every ordinary training run emits machine-readable evidence: a
+:class:`TelemetryRecorder` buffers one small host-side record per
+dispatch (step, wall ms, examples/s, data-wait ms, checkpoint-blocking
+ms, K, epoch) plus span/epoch/goodput events, and a single background
+writer appends them as JSONL to
 ``<telemetry_dir>/host_<pi>.jsonl`` — the r7 off-critical-path idiom
 (one worker thread, the step thread only appends to a list under a
 lock).  A run manifest (config, mesh, jax/jaxlib versions, device kind)
 is written once at startup (:func:`write_manifest`) so a telemetry
 directory is self-describing.
 
-Cost accounting (the ``telemetry_overhead_pct`` bench arm pins <1% of
-median step): the hot-path cost per dispatch is a few ``time.monotonic``
+Cost accounting (target <1% of median step; not measured on the
+chip): the hot-path cost per dispatch is a few ``time.monotonic``
 reads, one dict construction, and one lock-guarded list append; JSON
 encoding and file IO happen on the background thread.  The buffer is a
 RING in spirit — bounded, never a backlog: when ``capacity`` records
@@ -20,8 +20,8 @@ accumulate they are handed to the writer as one batch, and if the
 writer falls more than a few batches behind (a wedged filesystem) new
 batches are DROPPED and counted (``dropped_records``) rather than
 queued — observability must never grow unbounded host memory or stall
-the step loop.  ``FDT_TELEMETRY=0`` kills the whole subsystem
-(cli.build_telemetry).
+the step loop.  ``--no_telemetry`` kills the whole subsystem
+(telemetry.build_telemetry).
 
 Schema (APPEND-ONLY — fields may be added, never renamed; consumers
 must ignore unknown fields).  One JSON object per line, discriminated
@@ -146,7 +146,6 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, Optional, Tuple
 
 SCHEMA_VERSION = 1
-ENV_KILL = "FDT_TELEMETRY"
 MANIFEST = "manifest.json"
 
 # -- APPEND-ONLY schema registry (scripts/check_telemetry_schema.py) ------
@@ -210,7 +209,7 @@ TELEMETRY_SCHEMA: Dict[str, Optional[frozenset]] = {
     # r18 streaming data plane (data/stream/window.py) — append-only:
     # one stream_refill per background buffer fill (disk read + H2D
     # split out), one stream_stall per buffer swap the consumer had to
-    # wait for (the numerator of bench's stream_stall_pct, <1% target)
+    # wait for (the numerator of Trainer.stream_stall_pct, <1% target)
     "stream_refill": frozenset({"epoch", "base", "batches", "bytes",
                                 "read_ms", "h2d_ms"}),
     "stream_stall": frozenset({"epoch", "step", "wait_ms"}),

@@ -28,7 +28,7 @@ than threaded through every constructor: the instrumented seams live in
 modules that predate telemetry (resilience/manager.py's background
 writer thread, data/device_resident.py's upload path) and must stay
 usable — at zero overhead beyond two clock reads and the trace
-annotation — when no recorder is active (bench floors, library use).
+annotation — when no recorder is active (telemetry-off runs, library use).
 The recorder's buffer is lock-guarded, so spans may be recorded from
 any thread (the checkpoint background writer does).
 

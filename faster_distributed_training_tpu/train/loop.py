@@ -251,8 +251,8 @@ class Trainer:
         # DiskStreamSource, or None): the split lives on disk; each
         # epoch trains through a double-buffered device window refilled
         # by a background thread.  Steady-state stall accounting
-        # (fraction of step time blocked on data — bench's
-        # stream_stall_pct) accumulates here across epochs, excluding
+        # (fraction of step time blocked on data — the
+        # stream_stall_pct property) accumulates here across epochs, excluding
         # each program's compile-marked first dispatch.
         self.stream = stream
         self._stream_stall_s = 0.0
@@ -909,8 +909,8 @@ class Trainer:
     @property
     def stream_stall_pct(self) -> Optional[float]:
         """Steady-state fraction (percent) of streamed step time spent
-        blocked on the data window — the run-level number bench/smoke
-        read (None before any steady-state streamed dispatch)."""
+        blocked on the data window — the run-level number cli and the
+        stream smoke read (None before any steady-state streamed dispatch)."""
         if self.stream is None or self._stream_wall_s <= 0:
             return None
         return 100.0 * self._stream_stall_s / self._stream_wall_s
@@ -938,7 +938,7 @@ class Trainer:
         if (sent is not None and sent.mode == "full" and metrics is not None
                 and group is not None):
             # the ONE per-dispatch device sync --sentinel full buys
-            # (bench's sentinel_overhead_pct): the dispatch loss is a
+            # (its cost is not measured on the chip): the dispatch loss is a
             # replicated global scalar, so every host reads the same
             # value, reaches the same spike verdict, and writes the
             # same quarantine ledger — no cross-host protocol needed.
@@ -1063,10 +1063,9 @@ class Trainer:
         elapsed = time.monotonic() - t0
         # eval throughput made visible per epoch (VERDICT r5 #7): the
         # routing changes this repo makes at eval shapes must not be
-        # able to regress inference silently — bench.py tracks the
-        # compiled eval step (resnet_eval_img_per_sec_* /
-        # transformer_eval_ex_per_sec_*) under the regression guard,
-        # and this line surfaces the full-pipeline number per run.
+        # able to regress inference silently — no cell of the ledger
+        # evaluates inside its window (PERF.md 3), so this line, the
+        # full-pipeline number per run, is the only place it shows.
         total = summary.get("total_sum")
         if total:
             self.log(f"[eval] {total:.0f} samples in {elapsed:.1f}s "

@@ -108,13 +108,12 @@ def format_goodput(tracker) -> str:
         bits.append(f"detect {s['detect_s']:.2f}s")
     if s.get("restart_mttr_s"):
         # detect + backoff + restore per restart — the pod-coordinated
-        # recovery headline (resilience/coordinator.py, bench
-        # restart_mttr_s arm)
+        # recovery headline (resilience/coordinator.py)
         bits.append(f"mttr {s['restart_mttr_s']:.2f}s/restart")
     if s.get("readmission_hold_s"):
         # r14 elastic recovery: survivor parked time while a failed
-        # slice restarted and rejoined (the hold component of the
-        # restart_slice_mttr_s bench arm)
+        # slice restarted and rejoined (the hold component of a
+        # slice restart's MTTR)
         bits.append(f"readmit hold {s['readmission_hold_s']:.2f}s")
     counts = ", ".join(f"{int(s[k])} {k.rstrip('s') if s[k] == 1 else k}"
                        for k in ("saves", "skipped_saves", "restores",

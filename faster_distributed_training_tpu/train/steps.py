@@ -160,7 +160,7 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
     identical to an uninterrupted one (ROADMAP "augmentation-stream
     resume"), and (b) the fused K-step dispatch can advance the stream
     on device with zero host involvement.  Pre-normalized float batches
-    (bench/synthetic probes, the eval staging path) pass through
+    (synthetic probes, the eval staging path) pass through
     untouched.
 
     pipeline: a parallel.pipeline.PipelineSpec on a pp>1 mesh — the

@@ -16,7 +16,8 @@ single-thread loading) — then writes the cumulative-time comparison
 curve to figures/tricks_time.png and prints one JSON line with the
 steady-state speedups.
 
-One process per chip (bench.py's process model): the parent stays off
+One process per chip (a chip belongs to one process at a time, and a
+parent that has touched JAX holds it): the parent stays off
 JAX and runs each arm in its OWN child process, one at a time; each
 arm's JSON names the device it ran on, and an arm that dies makes the
 parent exit non-zero.  Dataset is the

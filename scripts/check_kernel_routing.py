@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Kernel-routing lint (ISSUE 15 satellite; the check_bench_arms.py /
+"""Kernel-routing lint (ISSUE 15 satellite; the
 check_telemetry_schema.py idiom applied to Pallas dispatch).
 
 The repo shipped THREE silent tp-capability gaps in a row (flash r11,

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Append-only telemetry schema lint (ISSUE 11 satellite; the
-check_bench_arms.py idiom applied to the JSONL stream).
+"""Append-only telemetry schema lint (ISSUE 11 satellite; an AST
+lint against a committed registry, applied to the JSONL stream).
 
 The telemetry stream's contract is APPEND-ONLY: fields may be added,
 never renamed or removed — consumers (scripts/telemetry_report.py,

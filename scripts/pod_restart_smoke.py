@@ -329,8 +329,7 @@ def _run_cache_scenario(check, ref_digest: str,
     reference — equivalent coverage, because cold-restart ≡
     uninterrupted is already pinned bitwise by the resilience e2e
     suite (kill-at-N resume, r7) — and leaves the cold-acquisition
-    A/B to the bench `restart_mttr_s` vs `restart_cached_mttr_s`
-    arms; the manual script run keeps the full twin."""
+    A/B to the manual script run, which keeps the full twin."""
     die_at = 13
     runs = {}
     for mode in (("cold", "cached") if cold_twin else ("cached",)):

@@ -6,8 +6,9 @@ DataLoader worker processes (--workers, resnet50_test.py:52,321-352;
 transformer_test.py uses the same loaders).  Here the equivalent is
 ParallelBatchIterator threads over the GIL-releasing C++ WordPiece core.
 This script answers: does clean+tokenize+bucket at bs=256 keep up with
-the measured transformer step rate (bench.py
-transformer_agnews_ex_per_sec_bs256_seq256)?
+the transformer's step rate?  That rate is not measured on the chip
+(no cell of BENCHMARK.json runs the encoder; PERF.md 7 row 0b): compare
+with the ledger's once it has one.
 
 No TPU needed — it measures the HOST side in isolation:
   * build a realistic corpus (AG News-like title+description lengths),

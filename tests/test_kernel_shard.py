@@ -504,7 +504,7 @@ class TestE2ETrain2D:
     def test_flash_quant_tp_matches_forced_fallback(self, run_kernel,
                                                     tmp_path,
                                                     monkeypatch):
-        """FDT_KERNEL_SHARD=0 (the bench A/B arm) must reproduce the
+        """FDT_KERNEL_SHARD=0 (the A/B seam) must reproduce the
         same training trajectory within the r11 2D parity pin — the
         shard_map layer changes the program, not the math."""
         monkeypatch.setenv(kernel_shard.ENV_KILL, "0")

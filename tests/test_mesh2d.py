@@ -325,7 +325,7 @@ class TestRingUlyssesOverTpAxis:
         assert model1.attention_impl in ("ring", "ulysses", "dense")
         assert any("cannot run head-sharded" in str(w.message)
                    for w in rec)
-        # kill switch restores the pre-r19 reroute (the bench A/B arm)
+        # kill switch restores the pre-r19 reroute
         monkeypatch.setenv("FDT_KERNEL_SHARD", "0")
         with warnings.catch_warnings(record=True) as rec:
             warnings.simplefilter("always")

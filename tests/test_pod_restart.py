@@ -1471,7 +1471,7 @@ def test_pod_restart_smoke_fake_object_store(monkeypatch):
 # cache_source=deserialized contract keeps tier-1 coverage via the
 # manifest compile-table tests and the decode program-pin test (which
 # round-trips the executable cache), and the MTTR A/B stays with the
-# bench restart_mttr_s vs restart_cached_mttr_s arms
+# manual script run (not measured on the chip)
 def test_pod_restart_smoke_cache(monkeypatch):
     """r17 acceptance: scripts/pod_restart_smoke.py --cache — crash +
     process relaunch with the executable cache armed: the relaunched
@@ -1480,9 +1480,9 @@ def test_pod_restart_smoke_cache(monkeypatch):
     (cache_cold_twin=False): the digest compares against the
     UNINTERRUPTED reference, which the resilience e2e suite already
     pins bitwise-equal to a cold restart (kill-at-N resume, r7), and
-    the cold-acquisition A/B stays with the bench restart_mttr_s vs
-    restart_cached_mttr_s arms — the manual script run keeps the full
-    cold twin (~25 s of extra compile this wrapper spares tier-1)."""
+    the cold-acquisition A/B stays with the manual script run, which
+    keeps the full cold twin (~25 s of extra compile this wrapper
+    spares tier-1)."""
     mod = _load_smoke_module(monkeypatch)
     assert mod.main(ref_digest=_smoke_reference_digest(mod),
                     cache=True, cache_cold_twin=False) == 0

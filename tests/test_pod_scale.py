@@ -1,7 +1,7 @@
 """Pod-scale hot path tests (r9): per-host sharded device residency +
 shard-streaming async checkpoints, plus the ride-along satellites
 (packed metric collective, donation version gate, retention delete
-hook, bench live-record guard).
+hook).
 
 Everything here is tier-1: CPU, ONE process, using the pure-function /
 simulated-``process_index`` seams — ``pod_epoch_order`` and
@@ -521,7 +521,7 @@ class TestShardedCheckpoint:
                 ok(np.asarray(bad, np.int32))
 
     def test_force_sharded_single_process_roundtrip(self, tmp_path, tiny):
-        # the bench ckpt_async_sharded arm's configuration
+        # one process down the per-host shard-streaming path
         m = AsyncCheckpointManager(str(tmp_path), every_steps=1,
                                    force_sharded=True,
                                    log=lambda *_: None)
@@ -600,20 +600,3 @@ class TestPackedMetricCollective:
             all_gather_across_processes)
         got = all_gather_across_processes(np.asarray(7, np.int32))
         assert got.shape == (1,) and int(got[0]) == 7
-
-
-def test_bench_live_record_guard():
-    """Satellite (r6/r7 standing note): *_step_ms A/B pairs are only
-    compared against a LIVE bench record — never the r5 record_note
-    reconstruction."""
-    import bench
-    assert bench._is_live_record({"bench_unix_time": 1.0, "value": 2.0})
-    assert not bench._is_live_record({"record_note": "reconstructed",
-                                      "value": 2.0})
-    assert not bench._is_live_record({"value": 2.0})   # no timestamp
-    prev = {"metric": "m", "a_step_ms": 100.0, "b_ex_per_sec": 50.0}
-    now = {"metric": "m", "a_step_ms": 200.0, "b_ex_per_sec": 20.0}
-    regs = bench._find_regressions(now, prev, compare_step_ms=False)
-    assert [r["metric"] for r in regs] == ["b_ex_per_sec"]
-    regs = bench._find_regressions(now, prev, compare_step_ms=True)
-    assert {r["metric"] for r in regs} == {"a_step_ms", "b_ex_per_sec"}

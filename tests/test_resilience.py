@@ -7,8 +7,8 @@ The end-to-end tests drive the REAL cli.run_training path with faults
 injected through the FDT_FAULT_* env knobs, exactly as the preemption
 smoke script (scripts/preemption_smoke.py) does across processes.
 donate=False throughout: these tests run several train programs in one
-pytest process, and multiple DONATING programs per process is the known
-backend hazard bench.py's process model exists to avoid."""
+pytest process, and multiple DONATING programs per process is a known
+backend hazard."""
 
 import json
 import os

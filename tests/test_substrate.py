@@ -76,141 +76,67 @@ def test_compile_cache_rule(case, monkeypatch, tmp_path):
 
 
 @pytest.mark.parametrize("case", ["parent_off_jax", "dead_child_exits_1"])
-def test_bench_one_process_per_chip(case):
-    """bench.py's parent only spawns and collects: it never imports jax
-    (a chip belongs to the process that touched JAX), and a child that
-    dies makes it exit non-zero.  Runs the real parent in a subprocess
-    with the spawn stubbed and a platform no JAX could initialise."""
+def test_ablation_one_process_per_chip(case, tmp_path):
+    """scripts/bag_of_tricks.py's parent only spawns and collects: it
+    never imports jax (a chip belongs to the process that touched JAX),
+    and an arm that dies makes it exit non-zero.  Runs the real parent
+    in a subprocess with the spawn stubbed and a platform no JAX could
+    initialise, from a temporary directory (it writes figures/ under
+    its working directory)."""
+    import json
     import os
     import subprocess
     import sys
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = os.path.join(repo, "scripts", "bag_of_tricks.py")
     child_ok = case == "parent_off_jax"
     code = f"""
-import json, sys
-import bench
+import importlib.util, json, os, sys
+spec = importlib.util.spec_from_file_location("bag_of_tricks", {script!r})
+tricks = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(tricks)
 seen = []
 class Done:
     returncode = {0 if child_ok else 1}
     stderr = "boom"
-    stdout = json.dumps({{"elapsed": 1.0, "mem": None, "state_bytes": {{
-                             "params_bytes_per_chip": 1,
-                             "opt_state_bytes_per_chip": 2}},
-                         "device": {{"platform": "tpu", "kind": "TPU v5 lite",
-                                    "count": 1}}}})
-def spawn(*a, **k):
-    seen.append("jax" in sys.modules)
-    return Done()
-bench.subprocess.run = spawn
+    def __init__(self, arm):
+        self.stdout = json.dumps({{
+            "arm": arm, "epoch_times": [9.0, 2.0, 2.0],
+            "device": {{"platform": "tpu", "kind": "TPU v5 lite",
+                       "count": 1}}}})
+def spawn(argv, env=None, **k):
+    seen.append(("jax" in sys.modules, env["FDT_TRICKS_CHILD"]))
+    return Done(env["FDT_TRICKS_CHILD"])
+tricks.subprocess.run = spawn
 try:
-    bench.main()
+    tricks.main()
     rc = 0
 except SystemExit as e:
     rc = e.code
 print(json.dumps({{"rc": rc, "spawns": seen,
-                  "jax_imported": "jax" in sys.modules}}))
+                  "jax_imported": "jax" in sys.modules,
+                  "wrote": sorted(os.listdir("figures"))}}))
 """
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("FDT_TRICKS_")}
     out = subprocess.run(
-        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        [sys.executable, "-c", code], cwd=tmp_path, capture_output=True,
         text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "no_such_platform",
-             "FDT_BENCH_FAST": "1"})
+        env={**env, "JAX_PLATFORMS": "no_such_platform"})
     assert out.returncode == 0, out.stderr[-2000:]
-    import json
     got = json.loads(out.stdout.strip().splitlines()[-1])
-    assert got["spawns"] and not any(got["spawns"])
+    # one child per arm, one at a time, none started from a parent
+    # that holds JAX
+    assert [arm for _, arm in got["spawns"]] == [
+        "resnet50_on", "resnet50_off", "transformer_on", "transformer_off"]
+    assert not any(held for held, _ in got["spawns"])
     assert not got["jax_imported"]
     assert got["rc"] == (0 if child_ok else 1)
-
-
-def test_bench_unknown_device_kind_raises(monkeypatch):
-    """An MFU over a guessed peak is not a measurement: a device_kind
-    that is not in the table is an error, not 197."""
-    import importlib.util
-    import os
-    import types
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_peak", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    monkeypatch.delenv("FDT_PEAK_TFLOPS", raising=False)
-    monkeypatch.setattr(jax, "devices", lambda *a: [
-        types.SimpleNamespace(device_kind="TPU v5 lite")])
-    assert bench.device_peak_tflops()[0] == 197.0
-    monkeypatch.setattr(jax, "devices", lambda *a: [
-        types.SimpleNamespace(device_kind="mystery accelerator")])
-    with pytest.raises(ValueError, match="mystery accelerator"):
-        bench.device_peak_tflops()
-
-
-def test_bench_regression_guard():
-    """VERDICT r4 #2c: bench flags >5% wrong-way moves per metric
-    direction (throughput/speedup/MFU up=good; ms/overhead/mem up=bad)."""
-    import importlib.util
-    import os as _os
-    spec = importlib.util.spec_from_file_location(
-        "bench", _os.path.join(_os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    prev = {"value": 100.0, "ngd_overhead_pct": 5.0,
-            "attn_fwdbwd_ms_L2048": 8.0, "attn_fwdbwd_ms_L4096": 14.0,
-            "tricks_speedup_x": 2.7,
-            "transformer_bs256_seq256_mfu_pct": 25.0,
-            "resnet_ngd_step_ms": 130.0}
-    rec = {"value": 90.0,                 # -10% throughput: regression
-           "ngd_overhead_pct": 7.0,       # +2 pp: past the 1.5pp tolerance
-           "attn_fwdbwd_ms_L2048": 9.0,   # +12.5%: run-to-run noise, NOT flagged
-           "attn_fwdbwd_ms_L4096": 20.0,  # +43%: past the 25% ladder band
-           "tricks_speedup_x": 2.9,       # up = good
-           "transformer_bs256_seq256_mfu_pct": 26.0,  # up = good
-           "resnet_ngd_step_ms": 125.0,   # down = good
-           "baseline_note": "strings are skipped"}
-    regs = bench._find_regressions(rec, prev)
-    assert {r["metric"] for r in regs} == {
-        "value", "ngd_overhead_pct", "attn_fwdbwd_ms_L4096"}
-    by = {r["metric"]: r for r in regs}
-    assert by["value"]["change_pct"] == -10.0
-    assert by["ngd_overhead_pct"]["change_pct"] == 2.0  # pp, not relative
-    assert by["attn_fwdbwd_ms_L4096"]["prev"] == 14.0
-    # a pp metric IMPROVING is never flagged
-    assert not bench._find_regressions({"ngd_overhead_pct": 3.0},
-                                       {"ngd_overhead_pct": 5.0})
-    # a tracked metric VANISHING (child subprocess death) is flagged
-    gone = bench._find_regressions({"value": 100.0},
-                                   {"value": 100.0,
-                                    "attn_fwdbwd_ms_L2048": 8.0,
-                                    "untracked_thing": 3.0})
-    assert gone == [{"metric": "attn_fwdbwd_ms_L2048", "prev": 8.0,
-                     "now": None, "missing": True}]
-    # VERDICT r5 #2: a published measured noise band raises the metric's
-    # threshold — a move inside the band is NOT flagged, outside IS, and
-    # the band itself is metadata, never a compared metric
-    key = "transformer_agnews_ex_per_sec_bs64_seq512"
-    inside = bench._find_regressions(
-        {key: 1030.0, f"{key}_noise_band_pct": 7.0}, {key: 1098.0})
-    assert inside == []
-    outside = bench._find_regressions(
-        {key: 950.0, f"{key}_noise_band_pct": 7.0}, {key: 1098.0})
-    assert [r["metric"] for r in outside] == [key]
-    assert "noise band" in outside[0]["note"]
-    assert not bench._find_regressions(
-        {"value": 100.0}, {"value": 100.0, f"{key}_noise_band_pct": 7.0})
-    # VERDICT r5 #1: the repo's real previous record parses — driver
-    # wrappers whose `parsed` is null and whose tail is a truncated
-    # mid-record fragment (BENCH_r05.json) are SKIPPED, never returned,
-    # and the committed BENCH_LATEST.json full record backstops them
-    import os as _os2
-    assert bench._load_bench_record(
-        _os2.path.join(_os2.path.dirname(bench.__file__),
-                       "BENCH_r05.json")) is None
-    prev_rec, prev_file = bench._prev_bench_record()
-    assert prev_rec and (prev_file.startswith("BENCH_r")
-                         or prev_file == bench.BENCH_LATEST)
-    assert "value" in prev_rec and "attn_fwdbwd_ms_L8192" in prev_rec
+    assert got["wrote"] == ["tricks_time.png", "tricks_times.json"]
+    if child_ok:
+        record = json.loads(out.stdout.strip().splitlines()[-2])
+        assert record["tricks_speedup_resnet50_e2e"] == 1.0
 
 
 def test_config_mixup_mode_flag():
@@ -291,7 +217,7 @@ def test_resolve_attention_seq_length_routing(monkeypatch, devices8):
     assert resolve_attention(
         TrainConfig(seq_len=512, batch_size=256)) == "flash"
     # r6 2D surface: large batches stay dense at short seq while the
-    # probs fit (attn_route_* bench arms), flash past the memory bound
+    # probs fit, flash past the memory bound
     assert resolve_attention(
         TrainConfig(seq_len=128, batch_size=512)) == "dense"
     assert resolve_attention(
@@ -357,50 +283,43 @@ def test_resolve_attention_seq_length_routing(monkeypatch, devices8):
     assert resolve_attention(TrainConfig(seq_len=512), tp_mesh) == "dense"
 
 
-def test_attn_route_surface_cells_cite_measured_arms():
-    """VERDICT r5 #5 acceptance: every cell the 2D routing surface
-    serves cites a bench arm that bench.py actually measures — either an
-    attn_route_* cell in bench.ATTN_ROUTE_BENCH_CELLS or a tracked
-    transformer arm present in the committed BENCH_LATEST.json."""
-    import importlib.util
-    import json as _json
-    import os as _os
-    import re as _re
+# (bs, seq, mesh condition, routed impl).  Mesh condition "" = mesh-
+# independent (1D / no model axis), evaluated as on a TPU; "sp" = the
+# mesh has a sequence-capable model axis (a dedicated sp axis, or tp —
+# the axis NAME doesn't change the shard_map math) whose size divides
+# both heads and seq (ulysses eligible); "sp_ragged" = model axis
+# present but the heads don't divide (ring, which accepts any head
+# count).  The cells are the ones the r5/r6/r11 chip readings covered;
+# none has been measured since (ROADMAP S8).
+_ROUTED_CELLS = (
+    (256, 256, "", "dense"),
+    (512, 128, "", "dense"),
+    (1024, 128, "", "dense"),
+    (512, 256, "", "dense"),
+    (1024, 256, "", "flash"),     # 3 fp32 score tensors = 6.4 GB > 4 GB
+    (256, 384, "", "flash"),
+    (64, 512, "", "flash"),
+    (8, 2048, "sp", "ulysses"),
+    (8, 2048, "sp_ragged", "ring"),
+    (4, 4096, "sp", "ulysses"),
+    (4, 4096, "sp_ragged", "ring"),
+)
 
-    from faster_distributed_training_tpu.cli import _ATTN_ROUTE_SURFACE
 
-    here = _os.path.join(_os.path.dirname(__file__), "..")
-    spec = importlib.util.spec_from_file_location(
-        "bench", _os.path.join(here, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    with open(_os.path.join(here, "BENCH_LATEST.json")) as fh:
-        latest = _json.load(fh)
-
-    assert _ATTN_ROUTE_SURFACE, "routing surface must not be empty"
-    cell = {c[:2]: c[2] for c in (bench.ATTN_ROUTE_BENCH_CELLS
-                                  + bench.ATTN_ROUTE_SP_BENCH_CELLS)}
-    for bs, seq, impl, arm, cond in _ATTN_ROUTE_SURFACE:
-        if arm.startswith("attn_route_"):
-            m = _re.match(r"attn_route_bs(\d+)_seq(\d+)_(\w+?)_step_ms$",
-                          arm)
-            assert m, arm
-            abs_, aseq, aimpl = int(m.group(1)), int(m.group(2)), m.group(3)
-            assert (abs_, aseq) == (bs, seq), (arm, bs, seq)
-            assert (bs, seq) in cell, f"{arm}: no bench arm for cell"
-            assert aimpl in cell[(bs, seq)], f"{arm}: impl not measured"
-        else:
-            # r5-measured cells ride the round-tracked transformer arms
-            assert arm in latest, f"{arm} not in BENCH_LATEST.json"
-        # the surface's impl must agree with what resolve_attention's
-        # rule actually returns for the cell (table and code in sync)
-        assert impl == expect_route(bs, seq, cond), (bs, seq, impl, cond)
+@pytest.mark.parametrize(
+    "bs,seq,cond,impl", _ROUTED_CELLS,
+    ids=[f"{bs}-{seq}-{cond or '1d'}" for bs, seq, cond, _ in _ROUTED_CELLS])
+def test_resolve_attention_routes_cell(bs, seq, cond, impl):
+    """Each cell of the auto-router's surface goes where
+    resolve_attention's rule says, through the REAL function with a
+    mesh matching the cell's condition."""
+    assert expect_route(bs, seq, cond) == impl
 
 
 def expect_route(bs, seq, cond):
-    """What resolve_attention's code actually returns for a surface row
-    — evaluated through the REAL function with a mesh matching the
-    row's condition, so the table cannot drift from the rule."""
+    """What resolve_attention's code actually returns for a cell —
+    evaluated through the REAL function with a mesh matching the
+    cell's condition."""
     import jax
 
     from faster_distributed_training_tpu.cli import resolve_attention
@@ -462,7 +381,7 @@ def test_ffn_impl_pallas_mesh_routing(devices8, monkeypatch):
                            mesh=mesh)
     assert model.ffn_impl == "flax"
     assert any("cannot run the Megatron" in str(r.message) for r in rec)
-    # kill switch: the pre-r19 reroute comes back (the bench A/B arm)
+    # kill switch: the pre-r19 reroute comes back
     monkeypatch.setenv("FDT_KERNEL_SHARD", "0")
     mesh = make_mesh(("dp", "tp"), (1, 8), devices8)
     with _w.catch_warnings(record=True) as rec:

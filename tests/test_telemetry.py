@@ -80,11 +80,8 @@ class TestRecorder:
                     if r["kind"] == "step"]) == 20
         assert rec.dropped_records == 0
 
-    def test_kill_switch_and_flag(self, tmp_path, monkeypatch):
+    def test_kill_switch_and_flag(self, tmp_path):
         cfg = TrainConfig(checkpoint_dir=str(tmp_path))
-        monkeypatch.setenv("FDT_TELEMETRY", "0")
-        assert build_telemetry(cfg) is None
-        monkeypatch.delenv("FDT_TELEMETRY")
         assert build_telemetry(cfg.replace(telemetry=False)) is None
         tel = build_telemetry(cfg, log=lambda *_: None)
         assert tel is not None
@@ -387,11 +384,10 @@ class TestEndToEnd:
         assert "trace started before step 3" in text
         assert "trace stopped after step 5" in text
 
-    def test_no_telemetry_runs_clean(self, tmp_path, monkeypatch):
+    def test_no_telemetry_runs_clean(self, tmp_path):
         from faster_distributed_training_tpu.cli import run_training
 
-        monkeypatch.setenv("FDT_TELEMETRY", "0")
-        out = run_training(_tiny_cfg(tmp_path, epochs=1),
+        out = run_training(_tiny_cfg(tmp_path, epochs=1, telemetry=False),
                            log=lambda *_: None)
         assert "telemetry_dir" not in out
         assert not os.path.exists(os.path.join(str(tmp_path), "telemetry"))
