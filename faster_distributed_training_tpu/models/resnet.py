@@ -68,8 +68,12 @@ class FusedConvBNLayer(nn.Module):
                                  # normalisation in its consumers; only
                                  # the expanding 1x1 (cout > cin) reads x
                                  # in y's place, by linearity, chosen by
-                                 # the kernel's shape in conv_bn_train.
-                                 # False: plain autodiff.  Distinct from
+                                 # the kernel's shape in conv_bn_train:
+                                 # forward statistics from x's Gram
+                                 # matrix (y is never written), backward
+                                 # from x.  False: plain autodiff; the
+                                 # forwards agree to rounding, not to the
+                                 # bit.  Distinct from
                                  # ResNet.remat (block checkpointing); not
                                  # plumbed through the model factories,
                                  # togglable on the layer for experiments

@@ -6,8 +6,8 @@ saves only ``(X, W, mean, sqrt_var)`` and whose backward applies a
 hand-derived BatchNorm backward and the convolution transpose, expressed
 so XLA fuses the normalize into the conv epilogue on the MXU.
 
-What the backward costs in HBM bytes, as the compiled v5e program shows
-(ISSUE 28): ``_fused_bwd`` writes ``conv2d(x, w)`` again, as the
+What the path costs in HBM bytes, as the compiled v5e program shows
+(ISSUE 28, ISSUE 30): ``_fused_bwd`` writes ``conv2d(x, w)`` again, as the
 reference does (``resnet.py:107-108``), but XLA merges that convolution
 with the forward's identical one: the program holds no second
 convolution, keeps every conv output ``y`` from forward to backward, and
@@ -15,11 +15,20 @@ recomputes the cheap ``relu(normalize(y))`` inside each consumer.  So
 that path saves neither bytes nor memory over autodiff; what it buys is
 the hand-derived BatchNorm backward.  The step is HBM-bound, and the
 lever left is the byte count: for the EXPANDING 1x1 convolution (each
-bottleneck's last, ``cin -> 4 cin``) ``_expand1x1_bwd`` reads the
-convolution's input ``x`` in ``y``'s place, by linearity (``y = x W`` and
-BatchNorm's backward is linear in ``y``), so ``y`` is neither kept nor
-read in the backward.  ``conv_bn_train`` picks the path by the kernel's
-shape.
+bottleneck's last, ``cin -> 4 cin``) everything that would read ``y``
+reads the convolution's input ``x`` in its place, by linearity
+(``y = x W``).  The forward (``_expand1x1_forward``) takes BatchNorm's
+mean and variance from ``x``'s column sums and its ``[cin, cin]`` Gram
+matrix ``S = x^T x``, two passes over ``x``; with the statistics known
+before the convolution runs, the normalisation (and the block's ``add``
+and ``relu``) is the convolution's epilogue and ``y`` is never written.
+The backward (``_expand1x1_bwd``) takes ``S`` and the column sums from
+the forward and reads ``x`` where BatchNorm's backward, linear in ``y``,
+would read ``y``.  ``conv_bn_train`` picks the path by the kernel's
+shape.  The three paths' forwards (this one, ``fused_conv_bn``, plain
+autodiff) agree to rounding, not to the bit: this one's statistics are
+float32 accumulations over the operands, the others' are taken from ``y``
+after its rounding to the compute dtype.
 
 Semantics matched to the reference:
   * BN has no affine γ/β (``resnet.py:85-99``),
@@ -176,16 +185,45 @@ def _expands_1x1(w_shape, stride, padding) -> bool:
             and _norm_padding(padding) == ((0, 0), (0, 0)))
 
 
+def _expand1x1_forward(x, w, eps):
+    """``_conv_bn_forward`` for ``y = x W`` with the statistics taken from
+    ``x``: ``y`` is linear in ``x``, so its mean is ``(xsum / n) W`` and its
+    ``E[y^2]`` is ``sum_k W * ((S / n) W)`` per column, with ``xsum`` the
+    column sums and ``S = x^T x`` the ``[K, K]`` Gram matrix of the input.
+    With the statistics known before the convolution runs, nothing reads
+    ``y`` but the normalisation: it becomes the convolution's epilogue (with
+    the block's ``add`` and ``relu``) and ``y`` is never written.
+    Returns (out, mean, var, S, xsum)."""
+    with jax.named_scope("fdt/conv1x1_bn_stats"):
+        sd = _stats_dtype(x.dtype)
+        hi = lax.Precision.HIGHEST
+        n = x.size // x.shape[-1]
+        W = w[0, 0].astype(sd)                                     # [K, C]
+        # The two passes over x: operands as they are, accumulation in fp32+.
+        S = jnp.einsum("nhwk,nhwl->kl", x, x, preferred_element_type=sd)
+        xsum = jnp.sum(x.astype(sd), axis=(0, 1, 2))
+        # _bn_stats' formula, clamp and unbiased estimator on K x K algebra,
+        # which carries its own precision (see _expand1x1_bwd).
+        mean = jnp.matmul(xsum / n, W, precision=hi)
+        mean_sq = jnp.sum(W * jnp.matmul(S / n, W, precision=hi), axis=0)
+        var = jnp.maximum(mean_sq - jnp.square(mean), 0.0) * (n / (n - 1))
+    # _conv_bn_forward's last line: y is rounded to its dtype, then normalised.
+    y = conv2d(x, w, 1, 0)
+    out = ((y.astype(sd) - mean) / (jnp.sqrt(var) + eps)).astype(y.dtype)
+    return out, mean, var, S, xsum
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _expand1x1_conv_bn(x: jax.Array, w: jax.Array, eps: float):
-    """``fused_conv_bn`` for the expanding 1x1 convolution: the same forward
-    to the bit, a backward that never touches the convolution's output."""
-    out, _, mean, var = _conv_bn_forward(x, w, 1, 0, eps)
-    return out, mean, var
+    """``fused_conv_bn`` for the expanding 1x1 convolution: neither the
+    forward nor the backward touches the convolution's output outside the
+    convolution's own fusion.  Equal to ``_conv_bn_forward`` to rounding."""
+    return _expand1x1_forward(x, w, eps)[:3]
 
 
 def _expand1x1_fwd(x, w, eps):
-    return _fused_fwd(x, w, 1, 0, eps)
+    out, mean, var, S, xsum = _expand1x1_forward(x, w, eps)
+    return (out, mean, var), (x, w, mean, jnp.sqrt(var), S, xsum)
 
 
 def _expand1x1_bwd(eps, res, cts):
@@ -193,7 +231,7 @@ def _expand1x1_bwd(eps, res, cts):
     ``dy = a g + b y + c`` per channel, every use of ``y`` (the reduction
     ``sum g (y - mean)``, ``dW = x^T dy``, ``dx = dy W^T``) moves onto ``x``,
     four times smaller, and onto ``[K, K]`` / ``[K, C]`` algebra."""
-    x, w, mean, sqrt_var = res
+    x, w, mean, sqrt_var, S, xsum = res
     g, _, _ = cts
     with jax.named_scope("fdt/conv1x1_bn_bwd"):
         sd = mean.dtype
@@ -204,8 +242,6 @@ def _expand1x1_bwd(eps, res, cts):
         # The passes over the big arrays: operands as they are (bf16 in the
         # bf16 program), accumulation in fp32+, as conv_vjp(dy) has it.
         G = jnp.einsum("nhwk,nhwc->kc", x, g, preferred_element_type=sd)
-        S = jnp.einsum("nhwk,nhwl->kl", x, x, preferred_element_type=sd)
-        xsum = jnp.sum(x.astype(sd), axis=rows)
         g_sum = jnp.sum(g.astype(sd), axis=rows)
 
         # BatchNorm backward per channel (see _fused_bwd), with
@@ -245,14 +281,19 @@ def conv_bn_train(x: jax.Array, w: jax.Array, stride: int = 1,
     remat=True (default) takes a custom_vjp with the hand-derived
     BatchNorm backward, chosen by the kernel's shape: the expanding 1x1
     convolution (1x1, stride 1, no padding, ``cout > cin``) takes
-    ``_expand1x1_conv_bn``, whose backward reads ``x`` where the other
-    reads ``y`` (reading ``y`` costs ``cout``, reading ``x`` costs
-    ``cin``: only there does the algebra save bytes); every other
-    convolution takes ``fused_conv_bn``.  remat=False leaves
-    differentiation to autodiff.  Identical forward numerics on all
-    three; gradients agree except at the degenerate var==0 clamp edge,
-    where autodiff zeroes the var path and the hand-written backwards
-    bound it (tests/test_ops.py)."""
+    ``_expand1x1_conv_bn``, whose forward takes the batch statistics from
+    ``x`` (column sums and the ``[cin, cin]`` Gram matrix) and whose
+    backward reads ``x`` where the other reads ``y`` (reading or writing
+    ``y`` costs ``cout``, reading ``x`` costs ``cin``: only there does
+    the algebra save bytes); every other convolution takes
+    ``fused_conv_bn``.  remat=False leaves differentiation to autodiff.
+    ``fused_conv_bn`` and remat=False share ``_conv_bn_forward`` to the
+    bit; the expanding path's forward agrees with it to rounding (mean
+    and variance to 1e-5 in float32; in bf16 closer to float64 than the
+    shared forward's, which reads ``y`` after its rounding).  Gradients
+    agree except at the degenerate var==0 clamp edge, where autodiff
+    zeroes the var path and the hand-written backwards bound it
+    (tests/test_ops.py)."""
     if remat and _expands_1x1(w.shape, stride, padding):
         return _expand1x1_conv_bn(x, w, eps)
     if remat:

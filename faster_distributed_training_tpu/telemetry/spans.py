@@ -74,6 +74,12 @@ set-up on the chip's host (PERF.md section 6, PR 24):
   ``fdt/mixup``        the image-space mixup variants
   ``fdt/model``        ``state.apply_fn`` inside ``loss_fn``: forward =
                        ``jvp(fdt/model)``, backward = its ``transpose``
+  ``fdt/conv1x1_bn_stats``  inside the model's forward: ops/conv_bn.py's
+                       batch statistics of the expanding 1x1 conv+BN
+                       from the convolution's input (the K x K Gram
+                       matrix and the column sums: two passes over
+                       ``x``, so the convolution's output is never
+                       written); the same 16 layers as the next row
   ``fdt/conv1x1_bn_bwd``  inside the model's backward: ops/conv_bn.py's
                        backward of the expanding 1x1 conv+BN from the
                        convolution's input (16 of ResNet-50's 46

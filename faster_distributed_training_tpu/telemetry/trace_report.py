@@ -196,13 +196,13 @@ def scope_of(op_name: str) -> Optional[str]:
         return "fdt/optimizer/ngd/fisher_update"
     if "fdt/optimizer/ngd" in op_name:
         return "fdt/optimizer/ngd"
-    if BACKWARD in op_name:
-        # a scope entered inside the model's backward (ops/conv_bn.py's
-        # fdt/conv1x1_bn_bwd) is a part of it, shown apart
-        inner = _SCOPE.search(op_name.split(BACKWARD, 1)[1])
-        return f"{BACKWARD}/{inner.group(0)}" if inner else BACKWARD
-    if FORWARD in op_name:
-        return FORWARD
+    for outer in (BACKWARD, FORWARD):      # BACKWARD first: it holds FORWARD
+        if outer in op_name:
+            # a scope entered inside the model's backward or forward
+            # (ops/conv_bn.py's fdt/conv1x1_bn_bwd, fdt/conv1x1_bn_stats)
+            # is a part of it, shown apart
+            inner = _SCOPE.search(op_name.split(outer, 1)[1])
+            return f"{outer}/{inner.group(0)}" if inner else outer
     m = _SCOPE.search(op_name)
     return m.group(0) if m else None
 

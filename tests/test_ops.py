@@ -239,18 +239,71 @@ class TestExpanding1x1ConvBN:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-8, atol=1e-10)
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("cin,cout", [(4, 16), (3, 5)])
-    def test_forward_bitwise_equal_to_shared_forward(self, cin, cout):
+    def test_forward_matches_shared_forward(self, cin, cout, dtype):
+        """The forward takes mean and variance from the INPUT's column sums
+        and Gram matrix (ISSUE 30), so it equals ``_conv_bn_forward`` to
+        rounding, not to the bit: the statistics within 1e-5 (float32) /
+        1e-3 (bfloat16: the shared forward's are taken from ``y`` after its
+        rounding) - the mean against the channel's standard deviation, the
+        variance relative - and ``out`` within 1e-5 / one bfloat16 ulp at
+        the larger of the value's binade and 1."""
         from faster_distributed_training_tpu.ops.conv_bn import (
             _conv_bn_forward, conv_bn_train)
-        for dtype in (jnp.float32, jnp.bfloat16):
-            x, w, _ = self._xwc(cin, cout, dtype, shift=1.0)
-            out, _, mean, var = _conv_bn_forward(x, w, 1, 0, 1e-3)
-            for a, b in zip(conv_bn_train(x, w, 1, 0, 1e-3),
-                            (out, mean, var)):
-                assert a.dtype == b.dtype
-                np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                              np.asarray(b, np.float32))
+        x, w, _ = self._xwc(cin, cout, dtype, shift=1.0)
+        out, _, mean, var = (np.asarray(a, np.float32) for a in
+                             _conv_bn_forward(x, w, 1, 0, 1e-3))
+        got = conv_bn_train(x, w, 1, 0, 1e-3)
+        assert [a.dtype for a in got] == [dtype, jnp.float32, jnp.float32]
+        g_out, g_mean, g_var = (np.asarray(a, np.float32) for a in got)
+        tol = 1e-5 if dtype == jnp.float32 else 1e-3
+        assert np.max(np.abs(g_mean - mean) / np.sqrt(var)) < tol
+        np.testing.assert_allclose(g_var, var, rtol=tol)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g_out, out, rtol=1e-5, atol=1e-5)
+        else:
+            ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(out), 1.0))) - 7)
+            assert np.all(np.abs(g_out - out) <= ulp)
+
+    def test_statistics_no_further_from_float64_than_shared_forward(self):
+        """bf16 post-ReLU input with a large mean (the cancellation in
+        ``E[y^2] - mean^2``), 64 -> 256, against float64 on the same bf16
+        operands: the Gram route accumulates in float32 from the operands,
+        the shared forward reads ``y`` after its rounding to bf16."""
+        from faster_distributed_training_tpu.ops.conv_bn import (
+            _conv_bn_forward, conv_bn_train)
+        x, w, _ = self._xwc(64, 256, jnp.float32, shift=7.5, hw=16)
+        x, w = jax.nn.relu(x).astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+        y = np.einsum("nhwk,kc->nhwc", np.asarray(x, np.float64),
+                      np.asarray(w, np.float64)[0, 0])
+        mean = y.mean((0, 1, 2))
+        var = y.var((0, 1, 2), ddof=1)
+        assert np.median(mean ** 2 / var) > 5.0  # the cancellation is there
+
+        def gaps(m, v):
+            return (np.max(np.abs(np.asarray(m, np.float64) - mean)
+                           / np.sqrt(var)),
+                    np.max(np.abs(np.asarray(v, np.float64) / var - 1.0)))
+
+        _, _, s_mean, s_var = _conv_bn_forward(x, w, 1, 0, 1e-3)
+        _, g_mean, g_var = conv_bn_train(x, w, 1, 0, 1e-3)
+        for got, shared in zip(gaps(g_mean, g_var), gaps(s_mean, s_var)):
+            assert got <= shared, (got, shared)
+            assert got < 1e-4, got
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_forward_clamps_a_constant_channel(self, dtype):
+        """A constant input: ``E[y^2] - mean^2`` is round-off of either sign
+        and the forward's clamp holds it at zero or above, as ``_bn_stats``'
+        does; ``out`` is finite and (nearly) centred."""
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x = jnp.full((4, 8, 8, 3), 3.3, dtype)
+        w = jax.random.normal(jax.random.PRNGKey(5), (1, 1, 3, 7)).astype(dtype)
+        out, mean, var = (np.asarray(a, np.float32)
+                          for a in conv_bn_train(x, w, 1, 0, 1e-3))
+        assert np.all(var >= 0.0) and np.all(var < 1e-4 * (1 + mean ** 2))
+        assert np.isfinite(out).all() and np.max(np.abs(out)) < 20.0
 
     def test_stats_cotangents_ignored_as_in_fused(self):
         """mean/var are stats-only outputs on both custom_vjps: a loss that
@@ -292,20 +345,23 @@ class TestExpanding1x1ConvBN:
         for a, b in zip(got, ref):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    @pytest.mark.parametrize("scope", ["fdt/conv1x1_bn_stats",
+                                       "fdt/conv1x1_bn_bwd"])
     @pytest.mark.parametrize("k,cin,cout,remat,scoped", [
         (1, 4, 16, True, True), (1, 4, 16, False, False),
         (1, 16, 4, True, False), (3, 4, 16, True, False)])
     def test_scope_names_the_layers_that_take_the_path(self, k, cin, cout,
-                                                       remat, scoped):
-        """`fdt/conv1x1_bn_bwd` is the path's static counter: it is in the
-        lowered program exactly where the new backward is."""
+                                                       remat, scoped, scope):
+        """`fdt/conv1x1_bn_stats` (forward) and `fdt/conv1x1_bn_bwd` are the
+        path's static counters: each is in the lowered program exactly
+        where the path is taken."""
         from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
         x = jnp.ones((2, 4, 4, cin), jnp.float32)
         w = jnp.ones((k, k, cin, cout), jnp.float32)
         text = jax.jit(jax.grad(lambda x_: jnp.sum(conv_bn_train(
             x_, w, 1, k // 2, remat=remat)[0] ** 2))).lower(x).as_text(
                 debug_info=True)
-        assert ("fdt/conv1x1_bn_bwd" in text) == scoped
+        assert (scope in text) == scoped
 
     def test_bf16_within_2e2_of_float32_oracle(self):
         from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
@@ -343,6 +399,22 @@ class TestExpanding1x1ConvBN:
         assert any(np.shape(leaf) == x.shape for leaf in leaves)
         for leaf in leaves:
             assert np.size(leaf) < out.size, np.shape(leaf)
+
+    def test_backward_takes_gram_from_forward(self):
+        """``S = x^T x`` and ``xsum`` are the forward's (its statistics need
+        them) and reach the backward as residuals: the lowered backward
+        holds no ``nhwk,nhwl->kl`` contraction of its own and the residuals
+        hold the ``[K, K]`` leaf (none of the output's size: the next test)."""
+        from faster_distributed_training_tpu.ops.conv_bn import conv_bn_train
+        x, w, cot = self._xwc(4, 16, jnp.float32)
+        gram = "nhwk,nhwl->kl"
+        whole = jax.jit(jax.grad(lambda x_: jnp.sum(
+            conv_bn_train(x_, w, 1, 0)[0] * cot))).lower(x).as_text(
+                debug_info=True)
+        assert gram in whole
+        _, vjp = jax.vjp(lambda x_, w_: conv_bn_train(x_, w_, 1, 0)[0], x, w)
+        assert gram not in jax.jit(vjp).lower(cot).as_text(debug_info=True)
+        assert (4, 4) in [np.shape(leaf) for leaf in jax.tree.leaves(vjp)]
 
     def test_batch_sharded_gives_global_sums(self, devices8):
         """The contractions over rows are plain sums over the batch axis:

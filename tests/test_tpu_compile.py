@@ -13,6 +13,7 @@ nothing about results or times.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -210,19 +211,20 @@ def test_quant_dense_on_dp4_compiles(topo, meshes):
 
 # -- the conv path has no kernel: what XLA plans for it is the thing to hold ---
 
-def test_expanding_1x1_backward_keeps_no_conv_output(topo, one_chip):
-    """ResNet-50's stage 1 at the benchmark cell's batch, three bottlenecks
-    (256 -> 64 -> 64 -> 256) forward+backward at bf16[1024,32,32,256]:
-    with conv_bn_train's expanding-1x1 backward the program's scratch is
-    smaller than plain autodiff's by at least one array of the block's
-    output size U (the third convolutions' outputs no longer live to the
-    backward; 1.5 U when written) and it moves at least 2 U fewer HBM
-    bytes (4.0 U when written).  The cell's batch on purpose: XLA lays
-    these arrays out with the batch in lanes or sublanes, and at batch
-    128 one block alone moves the SAME bytes on both paths."""
+STAGE1 = (1024, 32, 32, 256)       # the benchmark cell's stage 1, bf16
+U = int(np.prod(STAGE1)) * 2       # bytes of one array of the block's output
+
+
+@pytest.fixture(scope="module")
+def stage1(topo, one_chip):
+    """{conv_remat: compiled}: ResNet-50's stage 1 at the benchmark cell's
+    batch, three bottlenecks (256 -> 64 -> 64 -> 256) forward+backward at
+    bf16[1024,32,32,256], with ``conv_bn_train``'s custom_vjps and under
+    plain autodiff.  The cell's batch on purpose: XLA lays these arrays
+    out with the batch in lanes or sublanes, and at batch 128 one block
+    alone moves the SAME bytes on both paths."""
     import flax.linen as nn
     from faster_distributed_training_tpu.models.resnet import BottleNeck
-    shape = (1024, 32, 32, 256)
 
     def compiled(conv_remat):
         class Stage(nn.Module):
@@ -243,20 +245,62 @@ def test_expanding_1x1_backward_keeps_no_conv_output(topo, one_chip):
         with jax.enable_x64(False):
             variables = jax.eval_shape(
                 lambda: stage.init(jax.random.PRNGKey(0),
-                                   jnp.zeros(shape, BF16), True))
+                                   jnp.zeros(STAGE1, BF16), True))
             params, stats = (jax.tree.map(
                 lambda a: _struct(a.shape, a.dtype, one_chip), variables[k])
                 for k in ("params", "batch_stats"))
             return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-                params, _struct(shape, BF16, one_chip), stats).compile()
+                params, _struct(STAGE1, BF16, one_chip), stats).compile()
 
-    path, autodiff = compiled(True), compiled(False)
+    return {True: compiled(True), False: compiled(False)}
+
+
+def test_expanding_1x1_backward_keeps_no_conv_output(stage1):
+    """With conv_bn_train's expanding-1x1 path the program's scratch is
+    smaller than plain autodiff's by at least one array of the block's
+    output size U (the third convolutions' outputs no longer live to the
+    backward; 2.0 U when written, 1.5 U before ISSUE 30) and it moves at
+    least 2 U fewer HBM bytes (the backward alone: 4.0 U in ISSUE 28)."""
+    path, autodiff = stage1[True], stage1[False]
     assert path.as_text().count("/fdt/conv1x1_bn_bwd/") > 0
     assert "conv1x1_bn_bwd" not in autodiff.as_text()
-    u = int(np.prod(shape)) * 2
     temp_saved = (autodiff.memory_analysis().temp_size_in_bytes
                   - path.memory_analysis().temp_size_in_bytes)
     bytes_saved = (autodiff.cost_analysis()["bytes accessed"]
                    - path.cost_analysis()["bytes accessed"])
-    assert temp_saved >= u, (temp_saved / u)
-    assert bytes_saved >= 2 * u, (bytes_saved / u)
+    assert temp_saved >= U, (temp_saved / U)
+    assert bytes_saved >= 2 * U, (bytes_saved / U)
+
+
+def test_expanding_1x1_forward_writes_no_conv_output(stage1):
+    """The forward's statistics come from the convolution's input (ISSUE
+    30), so nothing reads ``y`` but its normalisation and XLA makes that,
+    the block's ``add`` and the ``relu`` the convolution's epilogue: (a)
+    the program moves at least 7 U fewer HBM bytes than plain autodiff
+    (9.25 U when written: 45.28 U against 54.53 U; 4.0 U with ISSUE 28's
+    backward alone); (b) each block holds ONE forward fusion named after
+    its third layer's convolution that takes an array of the block's size
+    (the shortcut) and writes one (the block's output)."""
+    path, autodiff = stage1[True], stage1[False]
+    text = path.as_text()
+    assert text.count("/fdt/conv1x1_bn_stats/") > 0
+    assert "conv1x1_bn_stats" not in autodiff.as_text()
+    bytes_saved = (autodiff.cost_analysis()["bytes accessed"]
+                   - path.cost_analysis()["bytes accessed"])
+    assert bytes_saved >= 7 * U, (bytes_saved / U)
+
+    block = "bf16[%s]" % ",".join(map(str, STAGE1))
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", text,
+                               re.M))
+    for i in range(3):
+        op_name = (f'/jvp(Stage)/BottleNeck_{i}/FusedConvBNLayer_2/'
+                   f'conv_general_dilated"')
+        fusions = [line for line in text.splitlines()
+                   if " fusion(" in line and op_name in line]
+        assert len(fusions) == 1, (i, fusions)
+        result, operands = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) fusion\((.*?)\), kind=",
+            fusions[0]).groups()
+        assert block in result, (i, result)
+        assert any(shape_of.get(o, "").startswith(block)
+                   for o in re.findall(r"%[\w.\-]+", operands)), (i, operands)

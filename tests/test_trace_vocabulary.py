@@ -130,24 +130,42 @@ def test_compiled_ngd_update_has_scopes_in_op_name_metadata():
                for n in names)
 
 
+def _package_calls(attr: str):
+    """(path relative to the package, Call node) of every ``<x>.<attr>(...)``
+    in the package, read from the source."""
+    for path in sorted(glob.glob(os.path.join(PKG, "**", "*.py"),
+                                 recursive=True)):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == attr):
+                yield os.path.relpath(path, PKG), node
+
+
+def test_every_named_scope_in_the_package_is_a_row_of_the_table():
+    """``jax.named_scope("...")`` literals: each is in
+    ``telemetry/spans.py``'s docstring, the one vocabulary table."""
+    found = {node.args[0].value: path
+             for path, node in _package_calls("named_scope")
+             if node.args and isinstance(node.args[0], ast.Constant)}
+    assert found["fdt/conv1x1_bn_stats"] == found["fdt/conv1x1_bn_bwd"] == (
+        os.path.join("ops", "conv_bn.py"))
+    for name, where in found.items():
+        assert f"``{name}``" in spans.__doc__, (name, where)
+
+
 # -- B. kernel names -------------------------------------------------------
 
 def _pallas_call_names() -> dict:
     """{(file, line): [name literals]} of every ``pl.pallas_call(...)`` in
-    the package, read from the source."""
+    the package."""
     sites = {}
-    for path in sorted(glob.glob(os.path.join(PKG, "**", "*.py"),
-                                 recursive=True)):
-        tree = ast.parse(open(path).read())
-        for node in ast.walk(tree):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "pallas_call"):
-                kw = {k.arg: k.value for k in node.keywords}
-                names = [c.value for c in ast.walk(kw["name"])
-                         if isinstance(c, ast.Constant)
-                         and isinstance(c.value, str)] if "name" in kw else []
-                sites[os.path.relpath(path, PKG), node.lineno] = names
+    for path, node in _package_calls("pallas_call"):
+        kw = {k.arg: k.value for k in node.keywords}
+        names = [c.value for c in ast.walk(kw["name"])
+                 if isinstance(c, ast.Constant)
+                 and isinstance(c.value, str)] if "name" in kw else []
+        sites[path, node.lineno] = names
     return sites
 
 
@@ -549,6 +567,11 @@ def test_reader_splits_a_chip_trace_by_scope(chip_trace):
     ("jit(step)/fdt/model/transpose(jvp(fdt/model))/ResNet/BottleNeck_0/"
      "FusedConvBNLayer_2/fdt/conv1x1_bn_bwd/nhwk,nhwc->kc/dot_general",
      "transpose(jvp(fdt/model))/fdt/conv1x1_bn_bwd"),
+    ("jit(step)/jvp(fdt/model)/ResNet/BottleNeck_0/FusedConvBNLayer_2/"
+     "fdt/conv1x1_bn_stats/nhwk,nhwl->kl/dot_general",
+     "jvp(fdt/model)/fdt/conv1x1_bn_stats"),
+    ("jit(step)/jvp(fdt/model)/ResNet/BottleNeck_0/FusedConvBNLayer_2/"
+     "conv_general_dilated", "jvp(fdt/model)"),
     ("jit(step)/fdt/optimizer/ngd/vmap()/mul", "fdt/optimizer/ngd"),
     ("jit(step)/fdt/optimizer/ngd/cond/branch_1_fun/fisher_update/eigh",
      "fdt/optimizer/ngd/fisher_update"),
