@@ -14,6 +14,7 @@ copy is ``benchmark_out/rehearsal/`` (git-ignored), made anew each time.
 This process never touches JAX: the chip is the child's.
 """
 
+import filecmp
 import json
 import os
 import shutil
@@ -42,7 +43,12 @@ def make_copy(dest: str, harness: str = ROOT) -> list:
                         (os.path.join(HERE, TRAFFIC + ".json"), "traffic")):
         to = os.path.join("benchmark", folder, os.path.basename(src))
         if os.path.exists(os.path.join(dest, to)):
-            raise FileExistsError(f"{to} is a file of the harness already")
+            # the same bytes: a tree in which the toy fixture, whose
+            # reference this one shares, was entered as a configuration
+            if not filecmp.cmp(src, os.path.join(dest, to), shallow=False):
+                raise FileExistsError(f"{to} is a file of the harness "
+                                      f"already")
+            continue
         shutil.copy(src, os.path.join(dest, to))
         added.append(to)
     with open(os.path.join(harness, "BENCHMARK.json")) as f:

@@ -1,91 +1,100 @@
-"""``correct`` can fail.  Under every cell's limits the control (the plain
-reference computed with float8 operands: the nearest precision below the
-bfloat16 the configuration states) and the planted fault (half of the batch
-left out, the mean over the rest), each put in the program's place, come out
-NOT correct, and so does a run of the harness whose timed path is broken
-underneath: a step that returns its state unchanged, and a step that leaves
-half of the batch out.  Tiny sizes on the CPU; the chip readings at the
-cells' own sizes are in PERF.md."""
+"""``correct`` can fail.  Under every held cell's limits (``BENCHMARK.json``'s
+and the language-model fixture's) the control (the plain reference computed
+in the nearest precision below the one the configuration states: float8
+operands under bfloat16, bfloat16 under float32) and the planted fault (half
+of the batch left out, the mean over the rest), each put in the program's
+place, come out NOT correct, and so does a run of the harness whose timed
+path is broken underneath: a step that returns its state unchanged, and a
+step that leaves half of the batch out.  Tiny sizes on the CPU, each
+configuration's from its rehearsal file (``tiny/<config>.py``); the chip
+readings at the cells' own sizes are in PERF.md."""
 
 import functools
 import importlib
 
+import jax
 import numpy as np
 import pytest
 
-from conftest import TINY_BATCH, TINY_SIZES, load
+from conftest import HELD, batch_and_length, first_batches, resolve, tiny
 
-from benchmark import correct
+from benchmark import configuration, correct
 from benchmark.reference import steps
-from benchmark.traffic.generate import generate
 
-BENCH = load("BENCHMARK.json")
-CELLS = {w["name"]: w for w in BENCH["workloads"]}
-
-
-def cell_limits(cell):
-    return load("benchmark", "traffic",
-                CELLS[cell]["traffic"] + ".json")["limits"]
-
-
-def tiny_problem(config_name):
-    """(reference module, sizes, training, batches) at a tiny size."""
-    config = load("benchmark", "configs", config_name + ".json")
-    ref = importlib.import_module(f"benchmark.configs.{config['reference']}")
-    sizes = dict(config["sizes"], batch_size=TINY_BATCH, **TINY_SIZES)
-    n = TINY_BATCH
-    x, y = generate({"kind": "images", "rows": 3 * n, "classes": 10,
-                     "shape": [32, 32, 3], "signal": 0.6,
-                     "noise_std": 40.0}, 5)
-    batches = [{"image": x[i:i + n], "label": y[i:i + n]}
-               for i in (0, n, 2 * n)]
-    return ref, sizes, config["training"], batches
+CELLS = sorted(w["name"] for w in HELD["workloads"])
+SEED = 5
 
 
 @functools.lru_cache(maxsize=None)
-def readings(config_name):
-    ref, sizes, training, batches = tiny_problem(config_name)
-    args = (ref, sizes, training, 5, batches, 100, 7)
-    return (steps.first_steps(*args),
-            steps.first_steps(*args, fault="half_batch"))
+def tiny_problem(tree, cell, control=False):
+    """A cell at its rehearsal's size, or at the size its control is shown
+    at: the reference module, ``sizes``, ``training``, the first three
+    ``batches``, the cell's ``limits`` and the one the control ``breaks``."""
+    _, entry, config, traffic = resolve(tree, cell)
+    r = tiny(entry["config"], config, traffic)
+    ref = importlib.import_module(f"benchmark.configs.{config['reference']}")
+    batch, seq_len = batch_and_length(config, traffic)
+    sizes = dict(configuration.sizes(config), batch_size=batch,
+                 seq_len=seq_len, **(r.CONTROL["sizes"] if control else {}))
+    batches = first_batches(traffic["data"], SEED, batch, seq_len)
+    return dict(ref=ref, sizes=sizes, training=config["training"],
+                batches=batches, limits=traffic["limits"],
+                breaks=r.CONTROL["breaks"])
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_planted_fault_in_the_programs_place_comes_out_not_correct(cell):
-    sound, half = readings(CELLS[cell]["config"])
-    limits = cell_limits(cell)
+@functools.lru_cache(maxsize=None)
+def readings(tree, cell, control=False, **kw):
+    p = tiny_problem(tree, cell, control)
+    return steps.first_steps(p["ref"], p["sizes"], p["training"], SEED,
+                             p["batches"], 100, 7, **kw)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_planted_fault_in_the_programs_place_comes_out_not_correct(
+        cell, tree):
+    sound = readings(tree, cell)
+    half = readings(tree, cell, fault="half_batch")
+    limits = tiny_problem(tree, cell)["limits"]
     same = correct.compare(sound, sound, limits)
     assert correct.verdict(same)
     assert all(c["value"] == 0 for c in same.values())
-    assert same["stats_gap"]["limit"] is not None
+    if jax.tree.leaves(sound["stats"]):      # running statistics are held
+        assert same["stats_gap"]["limit"] is not None
     compared = correct.compare(half, sound, limits)
     assert not correct.verdict(compared), compared
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_control_at_lower_precision_comes_out_not_correct(cell):
-    """By the number that is there for it, the forward pass layer by layer:
-    the statistics of the control's first step put in the program's place,
-    at all four stages with one bottleneck each (the rounding adds up with
-    depth: at two stages the control reads 0.026, at the cell's own size
-    0.058 on the chip, PERF.md section 2)."""
-    import jax
-    ref, sizes, training, batches = tiny_problem(CELLS[cell]["config"])
-    sizes = dict(sizes, stage_sizes=[1, 1, 1, 1],
-                 widths=[64, 128, 256, 512], strides=[1, 2, 2, 2])
-    params = ref.init_params(sizes, 5)
-    batch = {k: jax.numpy.asarray(v) for k, v in batches[0].items()}
-    forward = jax.jit(lambda low: ref.loss_fn(
-        params, batch, sizes, training, 7, 0, low, "")[1],
-        static_argnums=0)
-    sound, control = forward(False), forward(True)
-    start = steps.stats_start(ref, sound)
-    limit = cell_limits(cell)["stats_gap"]
-    assert correct.stats_gap(sound, sound, start) == 0
-    value = correct.stats_gap(control, sound, start)
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_at_lower_precision_comes_out_not_correct(cell, tree):
+    """The control, in the program's place at the sizes the rehearsal file
+    shows it at, breaks the limit that file names.  ``stats_gap`` is the
+    forward pass layer by layer and needs no step: the statistics of the
+    control's first forward pass.  Any other number is read from the three
+    steps followed at the control's precision."""
+    p = tiny_problem(tree, cell, control=True)
+    ref, sizes, training = p["ref"], p["sizes"], p["training"]
+    limits, name = p["limits"], p["breaks"]
+    limit = limits[name]
+    assert limit is not None, name
+    if name == "stats_gap":
+        params = ref.init_params(sizes, SEED)
+        batch = {k: jax.numpy.asarray(v) for k, v in p["batches"][0].items()}
+        forward = jax.jit(lambda low: ref.loss_fn(
+            params, batch, sizes, training, 7, 0, low, "")[1],
+            static_argnums=0)
+        sound, control = forward(False), forward(True)
+        start = steps.stats_start(ref, sound)
+        assert correct.stats_gap(sound, sound, start) == 0
+        value = correct.stats_gap(control, sound, start)
+    else:
+        sound = readings(tree, cell, control=True)
+        compared = correct.compare(
+            readings(tree, cell, control=True, precision="fp8"), sound,
+            limits)
+        assert correct.compare(sound, sound, limits)[name]["value"] == 0
+        value = compared[name]["value"]
     assert value > limit
-    assert not correct.verdict({"stats_gap": {"value": value,
-                                              "limit": limit}})
+    assert not correct.verdict({name: {"value": value, "limit": limit}})
 
 
 def broken_step(kind):
@@ -108,13 +117,14 @@ def broken_step(kind):
 
 
 @pytest.mark.parametrize("fault", ["sound", "state_unchanged", "half_batch"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_run_with_the_timed_path_broken_comes_out_not_correct(
-        fault, tiny_run, monkeypatch):
+        cell, fault, tiny_run, monkeypatch):
     if fault != "sound":
         monkeypatch.setattr(
             "faster_distributed_training_tpu.train.loop.make_train_step",
             broken_step(fault))
-    rc, line, _ = tiny_run(limits=cell_limits(sorted(CELLS)[0]))
+    rc, line, _ = tiny_run(cell)        # under the cell's own limits
     assert rc == 0
     assert line["correct"] is (fault == "sound"), line["compared"]
 

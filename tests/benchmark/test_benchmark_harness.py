@@ -1,6 +1,8 @@
-"""The harness is driven by data: every entry of BENCHMARK.json resolves to
-files by name, names keep to the contract's characters, and ``run.py`` prints
-the contract's line at a tiny size — and nothing without a chip."""
+"""The harness is driven by data: every entry of BENCHMARK.json, and of the
+language-model fixture entered beside them (``conftest.HELD``), resolves to
+files by name and brings its CPU rehearsal; names keep to the contract's
+characters, and ``run.py`` prints the contract's line at a tiny size — and
+nothing without a chip."""
 
 import importlib
 import json
@@ -11,9 +13,8 @@ import sys
 
 import pytest
 
-from conftest import ROOT, load
+from conftest import BENCH, HELD, ROOT, load, rehearsal_path, resolve
 
-BENCH = load("BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -24,40 +25,56 @@ def cells_of(metric):
     return metric.get("workloads", CELLS)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_resolves_to_its_files_by_name(cell):
-    from benchmark import run
+@pytest.mark.parametrize("cell", [w["name"] for w in HELD["workloads"]])
+def test_cell_resolves_to_its_files_by_name(cell, tree):
+    from benchmark import configuration
     from benchmark.traffic import generate
-    bench, entry, config, traffic = run.resolve(cell)
+    bench, entry, config, traffic = resolve(tree, cell)
     assert entry["chips"] in (1, 4) and len(entry["why"]) <= 200
     assert config["name"] == entry["config"]
     assert traffic["name"] == entry["traffic"]
     assert traffic["data"]["kind"] in generate.KINDS
     assert os.path.exists(os.path.join(
-        ROOT, "benchmark", "runners", config["runner"] + ".py"))
+        tree, "benchmark", "runners", config["runner"] + ".py"))
     ref = importlib.import_module(f"benchmark.configs.{config['reference']}")
     assert callable(ref.init_params) and callable(ref.loss_fn)
     from benchmark import flops
     assert flops.resolve(config["flops"])(
-        dict(config["sizes"]), 8, 16) > 0
+        dict(configuration.sizes(config)), 8, 16) > 0
     # every number the comparison prints has an entry, held or null
     assert set(traffic["limits"]) == {
         "loss1_gap", "loss2_gap", "loss3_gap", "grad_norm_gap",
         "stats_gap", "change_norm_gap", "change_worst_gap"}
 
 
-@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
-def test_configuration_is_used_and_its_file_is_under_paths(config):
-    entry = {c["name"]: c for c in BENCH["configs"]}[config]
-    assert any(w["config"] == config for w in BENCH["workloads"])
-    assert any(entry["file"].startswith(p + "/") for p in BENCH["paths"])
-    assert load(entry["file"])["source"] == entry["source"]
+CONFIGS = [c["name"] for c in HELD["configs"]]
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_configuration_is_used_and_its_file_is_under_paths(config, tree):
+    entry = {c["name"]: c for c in HELD["configs"]}[config]
+    assert any(w["config"] == config for w in HELD["workloads"])
+    assert any(entry["file"].startswith(p + "/") for p in HELD["paths"])
+    assert load(tree, entry["file"])["source"] == entry["source"]
     # the contract of a cut configuration (benchmark/configuration.py): what
     # ``reduced`` names is a size of the file, which then states the
     # published values, the deployment and what it assumed, cuts no width
     # and keeps the guide's floors; an uncut one lists nothing
     from benchmark import configuration
-    assert configuration.cut_faults(entry, load(entry["file"])) == []
+    assert configuration.cut_faults(entry, load(tree, entry["file"])) == []
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_configuration_brings_its_rehearsal_file(config):
+    """The one test that fails for a configuration without one; every
+    other test of it is skipped, and none builds it at its own sizes."""
+    path = rehearsal_path(config)
+    assert os.path.exists(path), (
+        f"configuration {config!r} brings no CPU rehearsal: add "
+        f"{os.path.relpath(path, ROOT)} "
+        f"(ARGV, SIZES, SHRINK, DATA, CONTROL and, where the program's "
+        f"argv cannot say the sizes, program(monkeypatch); "
+        f"benchmark/README.md, 'Add a cell, a configuration, a metric')")
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in METRICS])
@@ -69,8 +86,9 @@ def test_names_and_units_use_only_the_allowed_characters(metric):
                            "host_clock")
 
 
-@pytest.mark.parametrize("name", CELLS + [c["name"] for c in BENCH["configs"]]
-                         + [w["traffic"] for w in BENCH["workloads"]])
+@pytest.mark.parametrize("name", [w["name"] for w in HELD["workloads"]]
+                         + CONFIGS
+                         + [w["traffic"] for w in HELD["workloads"]])
 def test_cell_configuration_and_traffic_names_are_names(name):
     assert NAME.match(name)
 

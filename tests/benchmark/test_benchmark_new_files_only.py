@@ -14,49 +14,38 @@ import shutil
 import subprocess
 import sys
 
-from conftest import BENCH, ROOT
+from conftest import (FIXTURE, FIXTURE_CELL as CELL, ROOT, copy_harness,
+                      enter_fixture)
 
-FIXTURE = os.path.join(ROOT, "tests", "benchmark", "fixtures", "lm_toy")
-CONFIG, TRAFFIC = "encoder_lm_toy", "lm_toy_tokens"
-CELL = "encoder_lm_toy.bs8_seq16"
-SCOPE_METRICS = {"forward_device_ms", "backward_device_ms",
-                 "optimizer_device_ms", "augment_device_ms"}
+FIVE = {"BENCHMARK.json", "run_without_a_chip.py",
+        "benchmark/configs/encoder_lm_toy.json",
+        "benchmark/configs/encoder_lm_toy_reference.py",
+        "benchmark/traffic/lm_toy_tokens.json"}
+
+
+def files(root):
+    return {os.path.join(d, f) for d, _, fs in os.walk(root) for f in fs}
 
 
 def copied_tree(tmp_path):
-    """The harness as committed plus the new files; returns the copy's
-    root and the names of the files that were added."""
-    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__", "*.gz"))
-    before = {os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
-              for f in fs}
-    bench = tmp_path / "benchmark"
-    shutil.copy(os.path.join(FIXTURE, CONFIG + ".json"), bench / "configs")
-    shutil.copy(os.path.join(FIXTURE, CONFIG + "_reference.py"),
-                bench / "configs")
-    shutil.copy(os.path.join(FIXTURE, TRAFFIC + ".json"), bench / "traffic")
+    """The harness as committed plus the new files (``conftest.py``'s
+    ``enter_fixture``, which every parametrised test holds the fixture
+    through); returns the files of the copy before and after them."""
+    copy_harness(tmp_path)
+    before = files(tmp_path)
+    enter_fixture(tmp_path)
     shutil.copy(os.path.join(FIXTURE, "run_without_a_chip.py"), tmp_path)
-    config = json.load(open(os.path.join(FIXTURE, CONFIG + ".json")))
-    entries = dict(
-        BENCH,
-        configs=BENCH["configs"] + [{
-            "name": CONFIG, "source": config["source"],
-            "file": f"benchmark/configs/{CONFIG}.json",
-            "reduced": ["num_hidden_layers", "vocab_size"],
-            "why": "rehearsal: a cut language model with AdamW"}],
-        workloads=BENCH["workloads"] + [{
-            "name": CELL, "config": CONFIG, "traffic": TRAFFIC, "chips": 1,
-            "why": "rehearsal: 8 packed rows of 16 ids, causal, AdamW"}])
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(entries))
-    after = {os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
-             for f in fs}
-    return before, after
+    return before, files(tmp_path)
 
 
 def test_a_cut_language_model_with_adamw_is_new_files_and_entries_only(
         tmp_path):
     before, after = copied_tree(tmp_path)
-    assert len(after - before) == 5          # four files and BENCHMARK.json
+    # four files and BENCHMARK.json (fewer only in a tree whose benchmark/
+    # holds the fixture's files already: they are then copied with it)
+    assert {os.path.relpath(p, tmp_path) for p in after - before} == {
+        f for f in FIVE if f == "BENCHMARK.json"
+        or not os.path.exists(os.path.join(ROOT, f))}
     for path in before:                      # no file that was there changed
         with open(path, "rb") as a, open(
                 os.path.join(ROOT, os.path.relpath(path, tmp_path)),

@@ -1,9 +1,10 @@
 """The hand-assembled runner cannot drift from ``cli.run_training``
 unnoticed: at a tiny size on the CPU the train program the runner's Trainer
 lowers is the program ``cli.main`` lowers for the same argv — equal
-observatory fingerprint of ``train:host:k1`` — for every configuration of
-``BENCHMARK.json``, with the same data-set size on both sides (the schedule
-bakes ``steps_per_epoch`` into the program)."""
+observatory fingerprint of ``train:host:k1`` — for every held configuration
+(``BENCHMARK.json``'s and the language-model fixture's), each cut by its
+rehearsal file, over the same data on both sides (the schedule bakes
+``steps_per_epoch`` into the program, the vocabulary sets the tables)."""
 
 import importlib
 import json
@@ -11,9 +12,9 @@ import os
 
 import pytest
 
-from conftest import BENCH, TINY_ARGV, TINY_SIZES, load, tiny_resnet
+from conftest import HELD, tiny_cell
 
-ROWS, BATCH = 4096, 16    # the program's --dataset synthetic has 4096
+SEED = 11
 
 
 def fingerprint_from_manifest(telemetry_dir):
@@ -24,28 +25,21 @@ def fingerprint_from_manifest(telemetry_dir):
     return entry["variants"][0]["fingerprint"]
 
 
-CASES = {w["config"]: w["traffic"] for w in BENCH["workloads"]}
+CASES = {w["config"]: w["name"] for w in HELD["workloads"]}
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_runner_lowers_the_program_cli_main_lowers(name, monkeypatch,
+def test_runner_lowers_the_program_cli_main_lowers(name, tree, monkeypatch,
                                                    tmp_path):
     from faster_distributed_training_tpu import cli
     from benchmark.runners import train
+    from benchmark.traffic.generate import generate
 
-    config = load("benchmark", "configs", name + ".json")
-    traffic = load("benchmark", "traffic", CASES[name] + ".json")
-    tiny_resnet(monkeypatch)
-    config["argv"] = config["argv"] + TINY_ARGV
-    config["sizes"].update(TINY_SIZES)
-    traffic["argv"] = ["--bs", str(BATCH), "--mesh", "dp=1"]
-    # the same split size on both sides (--subset_stride would change the
-    # ResNet's schedule, so the benchmark's side grows to the program's)
-    traffic["data"].update(rows=ROWS)
+    _, _, config, traffic = tiny_cell(tree, monkeypatch, CASES[name])
 
     reference = importlib.import_module(
         f"benchmark.configs.{config['reference']}")
-    session = train.Session(config, traffic, seed=11,
+    session = train.Session(config, traffic, seed=SEED,
                             out_dir=str(tmp_path / "bench"),
                             reference=reference, log=lambda *_: None)
     try:
@@ -56,13 +50,18 @@ def test_runner_lowers_the_program_cli_main_lowers(name, monkeypatch,
     finally:
         session.close()
 
-    # the same argv through cli.main, on the program's own synthetic split
+    # the same argv through cli.main, over the rows the session was given
     i = argv.index("--telemetry_dir")
     argv[i + 1] = str(tmp_path / "cli_telemetry")
     i = argv.index("--checkpoint_dir")
     argv[i + 1] = str(tmp_path / "cli_ckpt")
     entry = importlib.import_module(config["entry"])
-    argv += ["--dataset", "synthetic"]
+
+    def the_sessions_rows(cfg, train):
+        return (generate(traffic["data"], SEED) if train else
+                generate(dict(traffic["data"], rows=cfg.batch_size),
+                         SEED + 1))
+    monkeypatch.setattr(cli, "load_dataset", the_sessions_rows)
     # one step is enough: stop the program's epoch loop after the first
     real = cli.make_loaders
 

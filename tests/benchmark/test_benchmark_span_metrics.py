@@ -8,7 +8,7 @@ import json
 
 import pytest
 
-from conftest import load, tiny_cell, tiny_resnet
+from conftest import load, tiny_cell
 
 BENCH = load("BENCHMARK.json")
 NEW = {"h2d_ms": "data", "host_sync_ms": "loop", "step_fenced_ms": "loop"}
@@ -91,7 +91,8 @@ def test_entry_is_appended_and_keeps_to_the_contract(metric):
     assert NEW[metric] in {e["layer"] for e in BENCH["per_layer"][:7]}
 
 
-def test_result_line_of_a_tiny_traced_run_carries_them(monkeypatch, capsys):
+def test_result_line_of_a_tiny_traced_run_carries_them(tree, monkeypatch,
+                                                       capsys):
     """``run.main --trace 1`` at the tiny size on the CPU (the reduction of
     the device trace stubbed: a CPU trace has no device plane): the readers
     find the fields in the records of the program as it is (the tiny cell
@@ -99,13 +100,9 @@ def test_result_line_of_a_tiny_traced_run_carries_them(monkeypatch, capsys):
     step is about as long as the host's share of it or longer."""
     from benchmark import run, trace_reduce
 
-    def resolve(workload, **kw):
-        bench, cell, config, traffic = tiny_cell()
-        traffic["trace_seconds"] = 0.5
-        return bench, cell, config, traffic
-
-    tiny_resnet(monkeypatch)
-    monkeypatch.setattr(run, "resolve", resolve)
+    resolved = tiny_cell(tree, monkeypatch)
+    resolved[3]["trace_seconds"] = 0.5
+    monkeypatch.setattr(run, "resolve", lambda workload, **kw: resolved)
     monkeypatch.setattr(run, "check_devices", lambda chips: {
         "platform": "cpu", "kind": "cpu", "count": 1})
     monkeypatch.setattr(trace_reduce, "reduce_dir", lambda *a, **kw: {
