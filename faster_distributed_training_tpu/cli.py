@@ -21,7 +21,8 @@ import numpy as np
 
 from faster_distributed_training_tpu.config import (TrainConfig,
                                                     build_parser,
-                                                    config_from_args)
+                                                    config_from_args,
+                                                    is_token_model)
 
 
 def _configured_platform() -> str:
@@ -170,7 +171,17 @@ def load_dataset(cfg: TrainConfig, train: bool):
                                     seed=0 if train else 1,
                                     max_len=cfg.seq_len)
     elif cfg.dataset == "synthetic":
-        if cfg.model == "transformer":
+        if cfg.model == "decoder":
+            # packed rows drawn from the vocabulary the model's file holds
+            from faster_distributed_training_tpu.data.synthetic import (
+                synthetic_packed_lm)
+            from faster_distributed_training_tpu.models.decoder import (
+                load_sizes)
+            vocab = load_sizes(cfg.decoder_config).vocab_size
+            return synthetic_packed_lm(n=512 if train else 128,
+                                       seed=0 if train else 1, vocab=vocab,
+                                       seq_len=cfg.seq_len)
+        if is_token_model(cfg):
             return synthetic_agnews(n=4096 if train else 1024,
                                     seed=0 if train else 1,
                                     max_len=cfg.seq_len)
@@ -526,6 +537,8 @@ def build_model(cfg: TrainConfig, vocab_size: Optional[int] = None,
                                       and getattr(cfg, "tie_lm_head",
                                                   True)),
                          causal=causal)
+    if cfg.model == "decoder":
+        return _build_decoder(cfg, vocab_size, mesh, dtype)
     if (getattr(cfg, "quant", "none") or "none") != "none":
         import warnings
         warnings.warn(
@@ -534,6 +547,32 @@ def build_model(cfg: TrainConfig, vocab_size: Optional[int] = None,
             f"full-precision", stacklevel=2)
     return get_model(cfg.model, cfg.num_classes, dtype=dtype,
                      remat=cfg.remat, conv_remat=not tricks_off)
+
+
+def _build_decoder(cfg: TrainConfig, vocab_size: Optional[int], mesh,
+                   dtype):
+    """``--model decoder``: every size from the one file ``--decoder_config``
+    names (models/decoder.py).  Attention is the causal-band kernel at
+    every length (ops/flash_attention.banded_attention; off-TPU its XLA
+    blockwise twin), so none of the encoder's routing applies.  One chip
+    or a data axis of one: the kernels are not wrapped for a larger mesh
+    yet (ROADMAP R3)."""
+    from faster_distributed_training_tpu.models import get_model
+    from faster_distributed_training_tpu.models.decoder import load_sizes
+    if not cfg.decoder_config:
+        raise ValueError("--model decoder needs --decoder_config <file> "
+                         "(the model's sizes; models/decoder.py)")
+    if mesh is not None and mesh.size > 1:
+        raise ValueError(f"--model decoder runs on one device; mesh "
+                         f"{dict(mesh.shape)} (its kernels are not "
+                         f"wrapped for a mesh yet)")
+    sizes = load_sizes(cfg.decoder_config)
+    if vocab_size is not None and vocab_size > sizes.vocab_size:
+        raise ValueError(f"the data's vocabulary ({vocab_size}) is larger "
+                         f"than {cfg.decoder_config}'s vocab_size "
+                         f"{sizes.vocab_size}")
+    return get_model("decoder", 0, sizes=sizes, dtype=dtype,
+                     remat=cfg.remat)
 
 
 def make_loaders(cfg: TrainConfig, train_ds, eval_ds, dp: int = 1
@@ -690,7 +729,7 @@ def run_training(cfg: TrainConfig,
         initialize_distributed()
 
     mesh = make_mesh(cfg.mesh_axes, cfg.mesh_shape)
-    is_text = cfg.model == "transformer"
+    is_text = is_token_model(cfg)
 
     if cfg.data_path == "stream":
         if cfg.dataset != "stream":
@@ -912,7 +951,7 @@ def run_training(cfg: TrainConfig,
     from faster_distributed_training_tpu.utils.profiling import (
         StepWindowProfiler, parse_profile_steps)
 
-    ckpt_name = "transformer" if is_text else "resnet"
+    ckpt_name = (cfg.model if is_text else "resnet")
     telemetry = build_telemetry(cfg, log=log)
     prev_span_recorder = None
     prev_observatory = None

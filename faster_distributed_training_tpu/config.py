@@ -15,12 +15,26 @@ import dataclasses
 from typing import Optional, Tuple
 
 
+# the models whose batches are rows of token ids (tokens/token_types/mask)
+TOKEN_MODELS = ("transformer", "decoder")
+
+
+def is_token_model(cfg) -> bool:
+    return cfg.model in TOKEN_MODELS
+
+
 @dataclasses.dataclass
 class TrainConfig:
     """Everything a training run needs, in one picklable record."""
 
     # -- workload ---------------------------------------------------------
-    model: str = "resnet50"           # resnet18/34/50/101/152 | transformer
+    model: str = "resnet50"           # resnet18/34/50/101/152 |
+                                      # transformer | decoder
+    decoder_config: str = ""          # --model decoder: the JSON file that
+                                      # holds the model's sizes under its
+                                      # published config's key names
+                                      # (models/decoder.py); no flag
+                                      # repeats a width
     dataset: str = "cifar10"          # cifar10 | agnews | synthetic |
                                       # stream (a sharded on-disk dataset
                                       # under --stream_dir, data/stream/)
@@ -589,6 +603,11 @@ def build_parser(prog: str = "fdt",
     p.add_argument("--weight_decay", default=d.weight_decay, type=float)
     p.add_argument("--gamma", default=d.gamma, type=float, help="LR decay factor")
     p.add_argument("--model", default=None, type=str)
+    p.add_argument("--decoder_config", default=d.decoder_config, type=str,
+                   help="--model decoder: the JSON file with the model's "
+                        "sizes under its published config's key names "
+                        "(hidden_size, layer_types, num_experts, ...; "
+                        "models/decoder.py) — the one place they are said")
     p.add_argument("--optimizer", default=d.optimizer, type=str,
                    help="override: sgd|madgrad|mirror_madgrad|ngd|adamw")
     p.add_argument("--schedule", default=d.schedule,
@@ -1018,6 +1037,7 @@ def config_from_args(args: argparse.Namespace, defaults: Optional[TrainConfig] =
         quant_grad=args.quant_grad,
         tie_lm_head=not args.untie_lm_head,
         lm_causal=args.lm_causal,
+        decoder_config=args.decoder_config,
         pp_microbatches=args.pp_microbatches,
         pp_schedule=args.pp_schedule,
         pp_residency=not args.no_pp_residency,

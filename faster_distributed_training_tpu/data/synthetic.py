@@ -89,3 +89,41 @@ def synthetic_agnews(n: int = 512, seed: int = 0, vocab: int = 30522,
                     "label": self._labels[np.asarray(indices)]}
 
     return _Synthetic()
+
+
+def synthetic_packed_lm(n: int = 512, seed: int = 0, vocab: int = 30522,
+                        seq_len: int = 128):
+    """Packed rows for next-token training behind the same interface:
+    ``n`` rows of exactly ``seq_len`` ids below ``vocab``, no padding (every
+    mask is ones).  Each id follows its predecessor by a fixed map three
+    times in four, so the loss has something to learn."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, vocab, size=(n, seq_len))
+    follow = rng.random((n, seq_len)) < 0.75
+    for t in range(1, seq_len):
+        ids[:, t] = np.where(follow[:, t], (ids[:, t - 1] * 3 + 1) % vocab,
+                             ids[:, t])
+    ids = ids.astype(np.int32)
+
+    class _Packed:
+        def __len__(self):
+            return n
+
+        def num_classes(self):
+            return 1
+
+        def vocab_size(self):
+            return vocab
+
+        def encode_batch(self, indices: Sequence[int], max_len: int = 512
+                         ) -> Dict[str, np.ndarray]:
+            if max_len != seq_len:
+                raise ValueError(f"packed rows are {seq_len} ids long, "
+                                 f"asked for {max_len}")
+            tokens = ids[np.asarray(indices, np.int64)]
+            return {"tokens": tokens,
+                    "token_types": np.zeros_like(tokens),
+                    "mask": np.ones_like(tokens),
+                    "label": np.zeros(len(tokens), np.int32)}
+
+    return _Packed()

@@ -22,5 +22,9 @@ def get_model(name: str, num_classes: int, **kw):
         return _RESNETS[name](num_classes=num_classes, **kw)
     if name == "transformer":
         return Transformer(n_class=num_classes, **kw)
+    if name == "decoder":
+        # imported here: no other model's program sees the decoder's code
+        from faster_distributed_training_tpu.models.decoder import Decoder
+        return Decoder(**kw)
     raise ValueError(f"unknown model {name!r}; "
-                     f"have {sorted(_RESNETS) + ['transformer']}")
+                     f"have {sorted(_RESNETS) + ['transformer', 'decoder']}")

@@ -132,14 +132,16 @@ def bh_index(B: int, H: int) -> jax.Array:
             + jnp.arange(H, dtype=jnp.int32)[None, :])[:, :, None, None]
 
 
-@partial(jax.jit, static_argnames=("block_k", "causal", "dropout_rate"))
+@partial(jax.jit, static_argnames=("block_k", "causal", "dropout_rate",
+                                   "window"))
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         mask: Optional[jax.Array] = None,
                         block_k: int = 128,
                         causal: bool = False,
                         dropout_rate: float = 0.0,
                         dropout_seed: Optional[jax.Array] = None,
-                        dropout_bh: Optional[jax.Array] = None
+                        dropout_bh: Optional[jax.Array] = None,
+                        window: Optional[int] = None
                         ) -> jax.Array:
     """Streaming attention over key blocks via lax.scan.
 
@@ -153,7 +155,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     block's key positions) — never an [Lq, Lk] tensor, so long-context
     callers (ops/ulysses_attention.py) stay O(L·block_k) in memory.
     Assumes query position i attends key positions <= i with q/k indexed
-    from the same origin (Lq == Lk self-attention).
+    from the same origin (Lq == Lk self-attention).  ``window`` (with
+    causal) narrows that to the band i - j < window, built the same way.
     """
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
@@ -192,7 +195,10 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         k_blk, v_blk, bias_blk, blk_idx = blk
         k_pos = blk_idx * block_k + jnp.arange(block_k, dtype=jnp.int32)
         if causal:
-            cb = jnp.where(k_pos[None, :] <= q_pos[:, None], 0.0, NEG_INF)
+            keep_band = k_pos[None, :] <= q_pos[:, None]
+            if window is not None:
+                keep_band &= q_pos[:, None] - k_pos[None, :] < window
+            cb = jnp.where(keep_band, 0.0, NEG_INF)
             bias_blk = bias_blk + cb[None, None]       # [B,1,Lq,block_k]
         keep = None
         if dropout_rate > 0.0:

@@ -76,6 +76,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from faster_distributed_training_tpu.ops import pallas_target
 from faster_distributed_training_tpu.ops.attention import (
@@ -269,15 +270,74 @@ def _lanes_to(x128, d: int):
     return jnp.tile(x128, (1, d // _KB_LANES))
 
 
-def _kb_pad(q, k, v, key_bias, bq, bk):
+class _Band:
+    """The tiles of a causal band (key j <= query i, and with ``window``
+    also i - j < window) on a (bq, bk) tiling of an [Lq, Lk] score
+    matrix with q and k indexed from the same origin.  ``k_lo/k_hi`` are
+    the first and last key tile a query tile needs, ``q_lo/q_hi`` the
+    first and last query tile a key tile feeds; they take a traced grid
+    index inside an index map or a kernel (``xp=jnp``) and numpy ranges
+    for the static widths ``nkb``/``nqb`` of the two grids.  A grid
+    visits ``lo + step`` and skips the steps past ``hi``; its index maps
+    clamp those to ``hi``, so a skipped step asks for the block the step
+    before it held and nothing is fetched."""
+
+    def __init__(self, bq, bk, nq, nk, window):
+        self.bq, self.bk, self.nq, self.nk = bq, bk, nq, nk
+        self.window = window
+        i, j = np.arange(nq), np.arange(nk)
+        self.nkb = int(np.max(self.k_hi(i, np) - self.k_lo(i, np))) + 1
+        self.nqb = int(np.max(self.q_hi(j, np) - self.q_lo(j, np))) + 1
+
+    def k_lo(self, i, xp=jnp):
+        if self.window is None:
+            return i * 0
+        return xp.maximum(i * self.bq - (self.window - 1), 0) // self.bk
+
+    def k_hi(self, i, xp=jnp):
+        return xp.minimum(((i + 1) * self.bq - 1) // self.bk, self.nk - 1)
+
+    def q_lo(self, j, xp=jnp):
+        return xp.minimum((j * self.bk) // self.bq, self.nq - 1)
+
+    def q_hi(self, j, xp=jnp):
+        if self.window is None:
+            return j * 0 + (self.nq - 1)
+        return xp.minimum(((j + 1) * self.bk + self.window - 2) // self.bq,
+                          self.nq - 1)
+
+    def keep(self, i, j):
+        """[bq, bk] bool: the pairs of tile (i, j) inside the band."""
+        rows = i * self.bq + jax.lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 0)
+        cols = j * self.bk + jax.lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 1)
+        keep = cols <= rows
+        if self.window is not None:
+            keep &= rows - cols < self.window
+        return keep
+
+
+def _kb_pad(q, k, v, key_bias, bq, bk, band: bool = False):
     """Pad q to bq multiples and k/v/bias to bk multiples (bias pads with
-    NEG_INF so padded keys carry ~zero probability)."""
+    NEG_INF so padded keys carry ~zero probability).  Under a causal
+    ``band`` there is no bias: a padded key lies past every real query
+    and the band masks it; one shared zeros block stands in for the
+    operand."""
     N, Lq, D = q.shape
     Lk = k.shape[1]
     nq, nk = -(-Lq // bq), -(-Lk // bk)
     pad_q, pad_k = nq * bq - Lq, nk * bk - Lk
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0)))
+    if band:
+        if key_bias is not None:
+            raise ValueError("the causal band takes no key-padding mask "
+                             "(packed rows carry none)")
+        if pad_k:
+            k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0)))
+        return q, k, v, jnp.zeros((1, 1, bk), jnp.float32), nq, nk
     if key_bias is None:
         key_bias = jnp.zeros((N, Lk), jnp.float32)
     key_bias = key_bias.astype(jnp.float32)
@@ -292,8 +352,16 @@ def _kb_pad(q, k, v, key_bias, bq, bk):
 def _flash_fwd_kblocked(q: jax.Array, k: jax.Array, v: jax.Array,
                         key_bias, dropout_rate: float = 0.0,
                         seed3=None, n_heads: int = 1,
-                        h_glob: Optional[int] = None):
-    """q/k/v [N, L, D] (N = B·H).  Returns (out [N, Lq, D],
+                        h_glob: Optional[int] = None,
+                        causal: bool = False,
+                        window: Optional[int] = None):
+    """q [N, L, D] (N = B·H), k/v [N // group, L, D]: each key-value head
+    serves ``group`` consecutive query heads through the index map, so
+    k/v are never repeated in HBM.  ``causal`` (static) keeps key j <=
+    query i, ``window`` (static, with causal) also i - j < window: the
+    k grid covers only the band's tiles (``_Band``), a step past the
+    band's end is skipped, and the pairs of a visited tile outside the
+    band are masked in the kernel.  Returns (out [N, Lq, D],
     lse [N, Lq] fp32).  Grid (N, q-block, k-block), k innermost;
     running (m, l, acc) in VMEM scratch; out and lse written on the
     last k step.  l accumulates PRE-dropout probability mass (softmax-
@@ -306,9 +374,13 @@ def _flash_fwd_kblocked(q: jax.Array, k: jax.Array, v: jax.Array,
     from faster_distributed_training_tpu.ops.attention import dropout_keep
 
     N, Lq, D = q.shape
+    group = N // k.shape[0]
     scale = 1.0 / math.sqrt(D)
     bq, bk = _kb_blocks(Lq, k.shape[1])
-    q, k, v, bias, nq, nk = _kb_pad(q, k, v, key_bias, bq, bk)
+    q, k, v, bias, nq, nk = _kb_pad(q, k, v, key_bias, bq, bk, band=causal)
+    band = _Band(bq, bk, nq, nk, window) if causal else None
+    _check_band(band, window, group, dropout_rate)
+    nkv = band.nkb if band else nk          # k steps a query tile takes
     seed = (seed3 if seed3 is not None
             else _pack_seed(None)).reshape(1, 3).astype(jnp.uint32)
     hg = h_glob if h_glob is not None else n_heads
@@ -316,48 +388,70 @@ def _flash_fwd_kblocked(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def kernel(q_ref, k_ref, v_ref, b_ref, s_ref, o_ref, lse_ref,
                m_scr, l_scr, acc_scr):
-        i, j = pl.program_id(1), pl.program_id(2)
+        i, jj = pl.program_id(1), pl.program_id(2)
+        j = band.k_lo(i) + jj if band else jj
 
-        @pl.when(j == 0)
+        @pl.when(jj == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [bq, bk]
-        s = s + b_ref[0]
-        m_prev, l_prev = m_scr[...], l_scr[...]             # [bq, 128]
-        m_curr = jnp.max(s, axis=-1, keepdims=True)         # [bq, 1]
-        m_next = jnp.maximum(m_prev, m_curr)                # [bq, 128]
-        p = jnp.exp(s - jnp.tile(m_next, (1, kreps)))
-        alpha = jnp.exp(m_prev - m_next)
-        l_next = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_rate > 0.0:
-            bh = _bh_from(s_ref, pl.program_id(0), n_heads, hg)
-            qrow = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kcol = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            p = p * dropout_keep(s_ref[0, 0], bh, qrow, kcol, dropout_rate)
-        acc_scr[...] = (acc_scr[...] * _lanes_to(alpha, D)
-                        + jnp.dot(p.astype(v_ref.dtype), v_ref[0],
-                                  preferred_element_type=jnp.float32))
-        m_scr[...], l_scr[...] = m_next, l_next
+        def tile():
+            s = jax.lax.dot_general(
+                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # [bq, bk]
+            if band:
+                s = jnp.where(band.keep(i, j), s, NEG_INF)
+            else:
+                s = s + b_ref[0]
+            m_prev, l_prev = m_scr[...], l_scr[...]             # [bq, 128]
+            m_curr = jnp.max(s, axis=-1, keepdims=True)         # [bq, 1]
+            m_next = jnp.maximum(m_prev, m_curr)                # [bq, 128]
+            p = jnp.exp(s - jnp.tile(m_next, (1, kreps)))
+            alpha = jnp.exp(m_prev - m_next)
+            l_next = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if dropout_rate > 0.0:
+                bh = _bh_from(s_ref, pl.program_id(0), n_heads, hg)
+                qrow = i * bq + jax.lax.broadcasted_iota(jnp.int32,
+                                                         (bq, bk), 0)
+                kcol = j * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                         (bq, bk), 1)
+                p = p * dropout_keep(s_ref[0, 0], bh, qrow, kcol,
+                                     dropout_rate)
+            acc_scr[...] = (acc_scr[...] * _lanes_to(alpha, D)
+                            + jnp.dot(p.astype(v_ref.dtype), v_ref[0],
+                                      preferred_element_type=jnp.float32))
+            m_scr[...], l_scr[...] = m_next, l_next
 
-        @pl.when(j == nk - 1)
+        if band:
+            pl.when(j <= band.k_hi(i))(tile)
+        else:
+            tile()
+
+        @pl.when(jj == nkv - 1)
         def _fin():
             l = jnp.maximum(l_scr[...], 1e-30)
             o_ref[0] = (acc_scr[...] / _lanes_to(l, D)).astype(o_ref.dtype)
             lse_ref[0] = m_scr[...] + jnp.log(l)
 
+    if band:
+        def kv_map(n, i, jj):
+            return (n // group,
+                    jnp.minimum(band.k_lo(i) + jj, band.k_hi(i)), 0)
+        bias_map = lambda n, i, jj: (0, 0, 0)               # noqa: E731
+    else:
+        kv_map = lambda n, i, j: (n, j, 0)                  # noqa: E731
+        bias_map = lambda n, i, j: (n, 0, j)                # noqa: E731
+
     out, lse = pl.pallas_call(
         kernel,
-        grid=(N, nq, nk),
+        grid=(N, nq, nkv),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda n, i, j: (n, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda n, i, j: (n, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda n, i, j: (n, j, 0)),
-            pl.BlockSpec((1, 1, bk), lambda n, i, j: (n, 0, j)),
+            pl.BlockSpec((1, bk, D), kv_map),
+            pl.BlockSpec((1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bk), bias_map),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
@@ -374,32 +468,54 @@ def _flash_fwd_kblocked(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=pallas_target.interpret(),
-        name="fdt_flash_fwd_kblocked",
+        name="fdt_flash_fwd_banded" if band else "fdt_flash_fwd_kblocked",
     )(q, k, v, bias, seed)
     return out[:, :Lq], lse[:, :Lq, 0]
 
 
+def _check_band(band, window, group: int, dropout_rate: float) -> None:
+    """What the K-blocked kernels serve: a window only inside a causal
+    band, and grouped key-value heads and the band without attention
+    dropout (its hash streams are numbered by query head)."""
+    if band is None and (window is not None or group != 1):
+        raise ValueError("a window or grouped key-value heads need "
+                         "causal=True (the banded kernels)")
+    if band is not None and dropout_rate > 0.0:
+        raise ValueError("the banded kernels take no attention dropout")
+
+
 def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
-                        out, lse, h_glob: Optional[int] = None):
+                        out, lse, h_glob: Optional[int] = None,
+                        causal: bool = False,
+                        window: Optional[int] = None):
     """FA-2-style backward: two k-blocked kernels (dq over the q-grid,
     dk/dv over the k-grid), both O(tile) VMEM — no Lk cap.  Uses the
     forward-saved lse, so probabilities come back exactly normalized
     (p/l = exp(s - lse)) with no in-kernel row sweep; delta = Σ dO·out
-    is precomputed in XLA.  q..v [B, H, L, D]; returns run(g)."""
+    is precomputed in XLA.  q [B, H, L, D], k/v [B, H // group, L, D];
+    under ``causal`` (and ``window``) both grids cover only the band's
+    tiles, as the forward's does, and dk/dv of a key-value head sum over
+    the ``group`` query heads it serves inside the dk/dv kernel's inner
+    grid axis.  Returns run(g)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from faster_distributed_training_tpu.ops.attention import dropout_keep
 
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
-    N = B * H
+    Hkv, Lk = k.shape[1], k.shape[2]
+    group = H // Hkv
+    N, Nkv = B * H, B * Hkv
     scale = 1.0 / math.sqrt(D)
-    n3 = lambda x: x.reshape(N, x.shape[2], x.shape[3])  # noqa: E731
+    n3 = lambda x: x.reshape(-1, x.shape[2], x.shape[3])  # noqa: E731
     qn, kn, vn, on = n3(q), n3(k), n3(v), n3(out)
     kb = jnp.repeat(key_bias, H, axis=0) if key_bias is not None else None
     bq, bk = _kb_blocks(Lq, Lk)
-    qp, kp, vp, bias, nq, nk = _kb_pad(qn, kn, vn, kb, bq, bk)
+    qp, kp, vp, bias, nq, nk = _kb_pad(qn, kn, vn, kb, bq, bk, band=causal)
+    band = _Band(bq, bk, nq, nk, window) if causal else None
+    _check_band(band, window, group, dropout_rate)
+    nkv = band.nkb if band else nk      # k steps of a query tile (dq)
+    nqv = band.nqb if band else nq      # q steps of a key tile (dk/dv)
     Lqp = nq * bq
     seed = (seed3 if seed3 is not None
             else _pack_seed(None)).reshape(1, 3).astype(jnp.uint32)
@@ -415,74 +531,114 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
     lse128 = jnp.broadcast_to(pad_q_rows(lse)[..., None],
                               (N, Lqp, _KB_LANES))
 
-    def common_block(q_blk, k_blk, b_blk, lse_blk):
+    def common_block(q_blk, k_blk, b_blk, lse_blk, i, j):
         s = jax.lax.dot_general(
             q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale + b_blk
+            preferred_element_type=jnp.float32) * scale
+        if band:
+            s = jnp.where(band.keep(i, j), s, NEG_INF)
+        else:
+            s = s + b_blk
         return jnp.exp(s - jnp.tile(lse_blk, (1, kreps)))  # p / l
 
     def dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
                   s_ref, dq_ref, dq_scr):
-        i, j = pl.program_id(1), pl.program_id(2)
+        i, jj = pl.program_id(1), pl.program_id(2)
+        j = band.k_lo(i) + jj if band else jj
 
-        @pl.when(j == 0)
+        @pl.when(jj == 0)
         def _init():
             dq_scr[...] = jnp.zeros_like(dq_scr)
 
-        p = common_block(q_ref[0], k_ref[0], b_ref[0], lse_ref[0])
-        do = do_ref[0].astype(jnp.float32)
-        dpterm = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bk]
-        if dropout_rate > 0.0:
-            bh = _bh_from(s_ref, pl.program_id(0), H, hg)
-            qrow = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kcol = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            dpterm = dpterm * dropout_keep(s_ref[0, 0], bh, qrow, kcol,
-                                           dropout_rate)
-        ds = p * (dpterm - jnp.tile(dl_ref[0], (1, kreps))) * scale
-        dq_scr[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[0],
-                               preferred_element_type=jnp.float32)
+        def tile():
+            p = common_block(q_ref[0], k_ref[0], b_ref[0], lse_ref[0], i, j)
+            do = do_ref[0].astype(jnp.float32)
+            dpterm = jax.lax.dot_general(
+                do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [bq, bk]
+            if dropout_rate > 0.0:
+                bh = _bh_from(s_ref, pl.program_id(0), H, hg)
+                qrow = i * bq + jax.lax.broadcasted_iota(jnp.int32,
+                                                         (bq, bk), 0)
+                kcol = j * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                         (bq, bk), 1)
+                dpterm = dpterm * dropout_keep(s_ref[0, 0], bh, qrow, kcol,
+                                               dropout_rate)
+            ds = p * (dpterm - jnp.tile(dl_ref[0], (1, kreps))) * scale
+            dq_scr[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[0],
+                                   preferred_element_type=jnp.float32)
 
-        @pl.when(j == nk - 1)
+        if band:
+            pl.when(j <= band.k_hi(i))(tile)
+        else:
+            tile()
+
+        @pl.when(jj == nkv - 1)
         def _fin():
             dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
     def dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
                    s_ref, dk_ref, dv_ref, dk_scr, dv_scr):
-        j, i = pl.program_id(1), pl.program_id(2)
+        # the inner axis walks the group's query heads, and under each
+        # the query tiles this key tile feeds
+        j, t = pl.program_id(1), pl.program_id(2)
+        i = band.q_lo(j) + t % nqv if band else t
 
-        @pl.when(i == 0)
+        @pl.when(t == 0)
         def _init():
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
-        p = common_block(q_ref[0], k_ref[0], b_ref[0], lse_ref[0])
-        do = do_ref[0].astype(jnp.float32)
-        dpterm = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bk]
-        if dropout_rate > 0.0:
-            bh = _bh_from(s_ref, pl.program_id(0), H, hg)
-            qrow = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kcol = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = dropout_keep(s_ref[0, 0], bh, qrow, kcol, dropout_rate)
-            pt = p * keep
-            dpterm = dpterm * keep
-        else:
-            pt = p
-        dv_scr[...] += jax.lax.dot_general(
-            pt.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bk, D]
-        ds = p * (dpterm - jnp.tile(dl_ref[0], (1, kreps))) * scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bk, D]
+        def tile():
+            p = common_block(q_ref[0], k_ref[0], b_ref[0], lse_ref[0], i, j)
+            do = do_ref[0].astype(jnp.float32)
+            dpterm = jax.lax.dot_general(
+                do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [bq, bk]
+            if dropout_rate > 0.0:
+                bh = _bh_from(s_ref, pl.program_id(0), H, hg)
+                qrow = i * bq + jax.lax.broadcasted_iota(jnp.int32,
+                                                         (bq, bk), 0)
+                kcol = j * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                         (bq, bk), 1)
+                keep = dropout_keep(s_ref[0, 0], bh, qrow, kcol,
+                                    dropout_rate)
+                pt = p * keep
+                dpterm = dpterm * keep
+            else:
+                pt = p
+            dv_scr[...] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [bk, D]
+            ds = p * (dpterm - jnp.tile(dl_ref[0], (1, kreps))) * scale
+            dk_scr[...] += jax.lax.dot_general(
+                ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [bk, D]
 
-        @pl.when(i == nq - 1)
+        if band:
+            pl.when(i <= band.q_hi(j))(tile)
+        else:
+            tile()
+
+        @pl.when(t == group * nqv - 1)
         def _fin():
             dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    if band:
+        def kv_of_q(n, i, jj):
+            return (n // group,
+                    jnp.minimum(band.k_lo(i) + jj, band.k_hi(i)), 0)
+
+        def q_of_kv(n, j, t):
+            return (n * group + t // nqv,
+                    jnp.minimum(band.q_lo(j) + t % nqv, band.q_hi(j)), 0)
+        bias_of_q = bias_of_kv = lambda n, a, b: (0, 0, 0)   # noqa: E731
+    else:
+        kv_of_q = lambda n, i, j: (n, j, 0)                  # noqa: E731
+        q_of_kv = lambda n, j, i: (n, i, 0)                  # noqa: E731
+        bias_of_q = lambda n, i, j: (n, 0, j)                # noqa: E731
+        bias_of_kv = lambda n, j, i: (n, 0, j)               # noqa: E731
 
     interp = pallas_target.interpret()
 
@@ -494,12 +650,12 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
         delta128 = jnp.broadcast_to(delta[..., None], (N, Lqp, _KB_LANES))
         dq = pl.pallas_call(
             dq_kernel,
-            grid=(N, nq, nk),
+            grid=(N, nq, nkv),
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda n, i, j: (n, i, 0)),
-                pl.BlockSpec((1, bk, D), lambda n, i, j: (n, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda n, i, j: (n, j, 0)),
-                pl.BlockSpec((1, 1, bk), lambda n, i, j: (n, 0, j)),
+                pl.BlockSpec((1, bk, D), kv_of_q),
+                pl.BlockSpec((1, bk, D), kv_of_q),
+                pl.BlockSpec((1, 1, bk), bias_of_q),
                 pl.BlockSpec((1, bq, D), lambda n, i, j: (n, i, 0)),
                 pl.BlockSpec((1, bq, _KB_LANES), lambda n, i, j: (n, i, 0)),
                 pl.BlockSpec((1, bq, _KB_LANES), lambda n, i, j: (n, i, 0)),
@@ -509,19 +665,19 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
             out_shape=jax.ShapeDtypeStruct((N, Lqp, D), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interp,
-            name="fdt_flash_bwd_dq",
+            name="fdt_flash_bwd_dq_banded" if band else "fdt_flash_bwd_dq",
         )(qp, kp, vp, bias, gn, lse128, delta128, seed)
         dk, dv = pl.pallas_call(
             dkv_kernel,
-            grid=(N, nk, nq),
+            grid=(Nkv, nk, group * nqv),
             in_specs=[
-                pl.BlockSpec((1, bq, D), lambda n, j, i: (n, i, 0)),
+                pl.BlockSpec((1, bq, D), q_of_kv),
                 pl.BlockSpec((1, bk, D), lambda n, j, i: (n, j, 0)),
                 pl.BlockSpec((1, bk, D), lambda n, j, i: (n, j, 0)),
-                pl.BlockSpec((1, 1, bk), lambda n, j, i: (n, 0, j)),
-                pl.BlockSpec((1, bq, D), lambda n, j, i: (n, i, 0)),
-                pl.BlockSpec((1, bq, _KB_LANES), lambda n, j, i: (n, i, 0)),
-                pl.BlockSpec((1, bq, _KB_LANES), lambda n, j, i: (n, i, 0)),
+                pl.BlockSpec((1, 1, bk), bias_of_kv),
+                pl.BlockSpec((1, bq, D), q_of_kv),
+                pl.BlockSpec((1, bq, _KB_LANES), q_of_kv),
+                pl.BlockSpec((1, bq, _KB_LANES), q_of_kv),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=[
@@ -529,18 +685,18 @@ def _flash_bwd_kblocked(q, k, v, key_bias, seed3, dropout_rate,
                 pl.BlockSpec((1, bk, D), lambda n, j, i: (n, j, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((N, nk * bk, D), jnp.float32),
-                jax.ShapeDtypeStruct((N, nk * bk, D), jnp.float32),
+                jax.ShapeDtypeStruct((Nkv, nk * bk, D), jnp.float32),
+                jax.ShapeDtypeStruct((Nkv, nk * bk, D), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)],
             interpret=interp,
-            name="fdt_flash_bwd_dkv",
+            name="fdt_flash_bwd_dkv_banded" if band else "fdt_flash_bwd_dkv",
         )(qp, kp, vp, bias, gn, lse128, delta128, seed)
-        shape4 = lambda x, L: x[:, :L].reshape(B, H, L, D)  # noqa: E731
-        return (shape4(dq, Lq).astype(q.dtype),
-                shape4(dk, Lk).astype(k.dtype),
-                shape4(dv, Lk).astype(v.dtype))
+        shape4 = lambda x, h, L: x[:, :L].reshape(B, h, L, D)  # noqa: E731
+        return (shape4(dq, H, Lq).astype(q.dtype),
+                shape4(dk, Hkv, Lk).astype(k.dtype),
+                shape4(dv, Hkv, Lk).astype(v.dtype))
 
     return run
 
@@ -1101,3 +1257,56 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return _flash_core(q, k, v, key_bias, _pack_seed(dropout_seed, bh0),
                        block_q, float(dropout_rate), save_stats,
                        h_glob if h_glob is not None else int(q.shape[1]))
+
+
+# ---------------------------------------------------------------------------
+# Causal band with grouped key-value heads: the decoder's attention
+# (models/decoder.py).  On a TPU target (and under the interpret seam) the
+# banded K-blocked kernels above at every length; elsewhere the XLA
+# blockwise twin with the same band.
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _banded_core(q, k, v, window):
+    return _banded_fwd(q, k, v, window)[0]
+
+
+def _banded_fwd(q, k, v, window):
+    B, H, Lq, D = q.shape
+    n3 = lambda x: x.reshape(-1, x.shape[2], x.shape[3])  # noqa: E731
+    out, lse = _flash_fwd_kblocked(n3(q), n3(k), n3(v), None, n_heads=H,
+                                   causal=True, window=window)
+    out = out.reshape(B, H, Lq, D)
+    return out, (q, k, v, out, lse)
+
+
+def _banded_bwd(window, res, g):
+    q, k, v, out, lse = res
+    return _flash_bwd_kblocked(q, k, v, None, None, 0.0, out, lse,
+                               causal=True, window=window)(g)
+
+
+_banded_core.defvjp(_banded_fwd, _banded_bwd)
+
+
+def banded_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     window: Optional[int] = None) -> jax.Array:
+    """Causal self-attention of packed rows: query i sees key j <= i and,
+    with ``window``, only i - j < window.  q [B, H, L, D]; k/v
+    [B, H // group, L, D], each key-value head serving ``group``
+    consecutive query heads.  No mask operand and no dropout: the band
+    comes from the positions alone.  A window at least as long as the
+    row is the full causal band."""
+    B, H, L, D = q.shape
+    group = H // k.shape[1]
+    if H != group * k.shape[1] or k.shape[2] != L:
+        raise ValueError(f"banded_attention: q {q.shape} against k "
+                         f"{k.shape}: query heads must be a multiple of "
+                         f"the key-value heads, on rows of one length")
+    if window is not None and window >= L:
+        window = None
+    if _use_pallas() and _kblocked_supported(D):
+        return _banded_core(q, k, v, window)
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    return blockwise_attention(q, k, v, None, causal=True, window=window)

@@ -8,7 +8,8 @@ from typing import Optional, Tuple
 
 import optax
 
-from faster_distributed_training_tpu.config import TrainConfig
+from faster_distributed_training_tpu.config import (TrainConfig,
+                                                    is_token_model)
 from faster_distributed_training_tpu.optim import schedules
 from faster_distributed_training_tpu.optim.madgrad import (madgrad,
                                                            mirror_madgrad)
@@ -23,7 +24,7 @@ def build_optimizer(cfg: TrainConfig, steps_per_epoch: int,
     here it is the actual data-parallel world size."""
     base_lr = cfg.lr * lr_scale
     name = cfg.optimizer or ("ngd" if cfg.use_ngd else
-                             ("mirror_madgrad" if cfg.model == "transformer"
+                             ("mirror_madgrad" if is_token_model(cfg)
                               else "madgrad"))
     sched_name = cfg.schedule or _default_schedule(name, cfg)
 
@@ -68,7 +69,7 @@ def build_optimizer(cfg: TrainConfig, steps_per_epoch: int,
 
 
 def _default_schedule(optimizer: str, cfg: TrainConfig) -> str:
-    if cfg.model == "transformer":
+    if is_token_model(cfg):
         return "onecycle"                       # transformer_test.py:224
     if cfg.subset_stride > 1 and optimizer == "ngd":
         return "step"                           # tuning/resnet50_tuning.py:435

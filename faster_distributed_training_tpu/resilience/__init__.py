@@ -272,7 +272,7 @@ def build_resilience(cfg, log: Callable[[str], None] = print
             # mirror the epoch-checkpoint naming (loop.py ckpt_name) so
             # two workloads sharing a checkpoint_dir never restore each
             # other's step checkpoints
-            prefix=("transformer" if cfg.model == "transformer"
+            prefix=(cfg.model if cfg.model in ("transformer", "decoder")
                     else "resnet"),
             every_steps=cfg.checkpoint_every,
             every_secs=cfg.checkpoint_every_secs,

@@ -163,7 +163,12 @@ TELEMETRY_SCHEMA: Dict[str, Optional[frozenset]] = {
     "step": frozenset({"step", "epoch", "n", "k", "wall_ms",
                        "dispatch_ms", "data_ms", "block_ms", "examples",
                        "ex_s", "compile", "h2d_ms", "sync_ms",
-                       "fence_steps", "fence_ms"}),
+                       "fence_steps", "fence_ms",
+                       # PR 33, append-only: a model's own counters
+                       # (spans.COUNTERS; these two are models/moe.py's),
+                       # on the records that close a fenced window: means
+                       # over the window's steps
+                       "moe_slots", "moe_load_max"}),
     # PR 24, append-only: the epoch's last fenced window, closed by
     # run_epoch's own fence (train/loop._DispatchClock.fence)
     "epoch_fence": frozenset({"step", "epoch", "fence_steps", "fence_ms",
@@ -398,11 +403,14 @@ class TelemetryRecorder:
                     data_ms: float = 0.0, block_ms: float = 0.0,
                     compile_: bool = False, h2d_ms: float = 0.0,
                     sync_ms: float = 0.0,
-                    fence: Optional[Tuple[int, float]] = None) -> None:
+                    fence: Optional[Tuple[int, float]] = None,
+                    counters: Optional[Dict[str, float]] = None) -> None:
         """``fence`` = (train steps, host ms) of the fenced window this
         dispatch closed; such a record is kept whatever the sampling
         cadence (there is one per --log_every window and the fenced
-        step time is folded from them)."""
+        step time is folded from them).  ``counters`` = the model's own
+        counters read at that read-back (means over the steps since the
+        last one), each written as a field of its own name."""
         self._steps_seen += 1
         if (self.step_every > 1 and not compile_ and fence is None
                 and self._steps_seen % self.step_every):
@@ -421,6 +429,8 @@ class TelemetryRecorder:
         if fence is not None:
             rec["fence_steps"] = int(fence[0])
             rec["fence_ms"] = round(fence[1], 3)
+        for name, value in (counters or {}).items():
+            rec[name] = round(float(value), 4)
         self._append(rec)
 
     def next_step_kept(self) -> bool:

@@ -60,7 +60,7 @@ step of the dispatch the phase belongs to, which is the step record's
   ``epoch_fence``  ``run_epoch``'s closing ``block_until_ready``
 
 Device scopes (``jax.named_scope`` in train/steps.py, optim/ngd.py,
-ops/quant.py and ops/conv_bn.py;
+ops/quant.py, ops/conv_bn.py, models/decoder.py and models/moe.py;
 metadata only: they live in the HLO's ``op_name`` debug locations, never
 in ``lowered.as_text()``, so program fingerprints and compile-cache keys
 do not move).  A transform wraps ONE path element (``jvp(fdt/model)``,
@@ -86,6 +86,20 @@ set-up on the chip's host (PERF.md section 6, PR 24):
                        FusedConvBNLayers, none in ResNet-18/34); the
                        scope's presence in a program is the path's
                        counter
+  ``fdt/attention``    inside the decoder's forward and backward
+                       (models/decoder.py): attention proper — scores,
+                       softmax, values — through
+                       ``ops/flash_attention.banded_attention``; not the
+                       projections, norms or the output gate
+  ``fdt/moe_route``    an expert layer's router (models/moe.py): sigmoid
+                       scores over the router's whole width, top-k,
+                       weights
+  ``fdt/moe_dispatch`` the token-slots sorted by held expert and the
+                       tokens' rows gathered into that order
+  ``fdt/moe_experts``  the held experts' grouped SwiGLU products
+                       (ops/grouped_matmul.py)
+  ``fdt/moe_combine``  the slots' results back in token order and each
+                       token's weighted sum
   ``fdt/loss``         the (mixup) criterion
   ``fdt/grad_reduce``  ``reduce_grads`` + ``unscale_and_check``
   ``fdt/optimizer``    ``state.apply_gradients``; inside it the bare
@@ -102,6 +116,12 @@ them in the custom call's name):
   ``fdt_flash_fwd_kblocked``   k-blocked flash forward (beyond the
                        monolithic envelope)
   ``fdt_flash_bwd_dq`` / ``fdt_flash_bwd_dkv``  k-blocked backward pair
+  ``fdt_flash_fwd_banded`` / ``fdt_flash_bwd_dq_banded`` /
+  ``fdt_flash_bwd_dkv_banded``  the same three over a causal band
+                       (optionally a window) with grouped key-value
+                       heads: the decoder's attention.  The grouped
+                       products run megablox's kernels, which carry the
+                       library's names (``gmm``, ``tgmm``)
   ``fdt_flash_bwd_fused``      one-kernel backward from saved statistics
   ``fdt_flash_bwd_recompute``  one-kernel backward that recomputes them
   ``fdt_fused_ffn_fwd`` / ``fdt_fused_ffn_fwd_general``  the encoder's
@@ -119,6 +139,14 @@ import time
 from typing import Iterator, List, Optional
 
 _ACTIVE = None   # the installed TelemetryRecorder (or None)
+
+# the flax collection a model sows its own per-step counters into (device
+# scalars, whatever their names).  train/steps.py makes it mutable, means
+# each name over the layers that wrote it and hands them on as ONE entry,
+# ``metrics["counters"]``; the loop keeps them to the read-back it makes
+# anyway and the recorder writes each as a step-record field of the same
+# name.  Only the model and TELEMETRY_SCHEMA name a counter.
+COUNTERS = "counters"
 
 # the hot loop's phases, labels built once (phase() is called several
 # times per dispatch)

@@ -100,6 +100,11 @@ class _DispatchClock:
         self.t_rec = self.t_disp = self.t_done = self.t_hooks = t0
         trainer._blocked_since_log = 0.0
         trainer._sync_s = 0.0
+        # the model's own counters (spans.COUNTERS), whatever their
+        # names: each dispatch's device scalars, kept until the loop reads
+        # the loss anyway (only where it ever does: no --log_every,
+        # nothing kept)
+        self.counters = [] if trainer.cfg.log_every else None
 
     def start(self, key: Optional[tuple] = None, due: bool = False) -> None:
         """Top of an iteration.  ``key`` (where the loop knows its
@@ -168,11 +173,19 @@ class _DispatchClock:
         tr._blocked_since_log += block_s
         if key not in tr._dispatched:
             self.fence_clean = False
-        fence = None
+        if self.counters is not None and "counters" in metrics:
+            self.counters.append(metrics["counters"])
+        fence = counters = None
         if tr._log_due(n, run):
             self.last = tr._log_dispatch(self.epoch, n, run, metrics,
                                          self.last)
             fence = self._close_window(self.last[0])
+            if self.counters:
+                # the device is drained: this read waits for nothing
+                kept = jax.device_get(self.counters)
+                counters = {k: sum(float(c[k]) for c in kept) / len(kept)
+                            for k in kept[0]}
+                self.counters = []
             t_end = time.monotonic()
         sync_s, tr._sync_s = tr._sync_s, 0.0
         want = self.want
@@ -180,7 +193,7 @@ class _DispatchClock:
             self.epoch, n, run, t_end - self.t_rec if want else 0.0,
             self.t_done - self.t_disp if want else 0.0,
             self.t_disp - self.t_rec if want else 0.0, block_s, key,
-            h2d_s=h2d_s, sync_s=sync_s, fence=fence)
+            h2d_s=h2d_s, sync_s=sync_s, fence=fence, counters=counters)
         return state
 
     def fence(self, metrics) -> None:
@@ -424,7 +437,8 @@ class Trainer:
                          dispatch_s: float, data_s: float, block_s: float,
                          program_key: tuple, h2d_s: float = 0.0,
                          sync_s: float = 0.0,
-                         fence: Optional[tuple] = None) -> None:
+                         fence: Optional[tuple] = None,
+                         counters: Optional[dict] = None) -> None:
         """Per-dispatch telemetry: one small host-side record into the
         recorder's ring buffer (nothing on the device, no sync).  The
         first execution of each compiled program is marked compile=True
@@ -441,7 +455,8 @@ class Trainer:
                         dispatch_s * 1e3, kk * self.cfg.batch_size,
                         data_ms=data_s * 1e3, block_ms=block_s * 1e3,
                         compile_=first, h2d_ms=h2d_s * 1e3,
-                        sync_ms=sync_s * 1e3, fence=fence)
+                        sync_ms=sync_s * 1e3, fence=fence,
+                        counters=counters)
         if first:
             rec.record_span("first_dispatch_compile", dispatch_s * 1e3,
                             step=self.global_step)

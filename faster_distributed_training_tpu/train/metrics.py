@@ -50,7 +50,8 @@ class MetricAccumulator:
 
     def add(self, metrics: Metrics) -> None:
         for k, v in metrics.items():
-            self._sums.setdefault(k, []).append(v)
+            if k != "counters":    # the dispatch clock's (train/loop.py)
+                self._sums.setdefault(k, []).append(v)
 
     def summary(self) -> Dict[str, float]:
         """One device->host sync for the whole epoch."""

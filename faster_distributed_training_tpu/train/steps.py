@@ -22,7 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from faster_distributed_training_tpu.config import TrainConfig
+from faster_distributed_training_tpu.config import (TrainConfig,
+                                                    is_token_model)
+from faster_distributed_training_tpu.telemetry import spans
 from faster_distributed_training_tpu.train import mixup as mx
 from faster_distributed_training_tpu.train.amp import (
     scale_loss, unscale_and_check, update_loss_scale)
@@ -143,10 +145,23 @@ def _offload_transfers(state_shardings):
     return fetch, stash
 
 
+def step_counters(mutated) -> Dict[str, jax.Array]:
+    """What the model sowed into ``spans.COUNTERS`` this step, each name
+    meaned over the layers that wrote it; {} where no layer wrote any."""
+    found: Dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            mutated.get(spans.COUNTERS, {})):
+        # a sown leaf sits in a tuple under its name: .../<name>/0
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        found.setdefault(name, []).append(leaf)
+    return {k: jnp.mean(jnp.stack(v)) for k, v in found.items()}
+
+
 def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
                     ) -> Callable[[TrainState, Any],
                                   Tuple[TrainState, Metrics]]:
-    """Build the jitted train step for cfg.model ('resnet*' or 'transformer').
+    """Build the jitted train step for cfg.model ('resnet*', or a token
+    model: 'transformer', 'decoder').
 
     state_shardings: pass the TrainState-shaped sharding tree when
     cfg.host_offload is on — the step then round-trips the state
@@ -180,12 +195,15 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
     from faster_distributed_training_tpu.resilience.faults import (
         graph_nan_at)
     nan_at = graph_nan_at()
-    is_text = cfg.model == "transformer"
+    is_text = is_token_model(cfg)
     lm = getattr(cfg, "task", "cls") == "lm"
     if lm and not is_text:
-        raise ValueError(f"--task lm needs the transformer (next-token "
+        raise ValueError(f"--task lm needs a token model, the "
+                         f"transformer or the decoder (next-token "
                          f"prediction over token ids); got model="
                          f"{cfg.model!r}")
+    if cfg.model == "decoder" and not lm:
+        raise ValueError("--model decoder is a language model: --task lm")
     mode = resolve_mixup_mode(cfg)
     # non-offload shardings (a tp/2D mesh): pin the UPDATED state to the
     # placement policy — without the constraint XLA's propagation is
@@ -292,7 +310,8 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
                         token_types=batch.get("token_types"),
                         mask=None, train=True,
                         rngs={"dropout": k_drop, "mixup": k_mix},
-                        mutable=["batch_stats"], **pp_kwargs)
+                        mutable=["batch_stats", spans.COUNTERS],
+                        **pp_kwargs)
                 with jax.named_scope("fdt/loss"):
                     loss_total, correct, total = lm_shift_metrics(
                         logits, batch["tokens"], batch.get("mask"))
@@ -305,10 +324,12 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
                     scaled = scaled * jnp.where(state.step == nan_at,
                                                 jnp.nan, 1.0)
                 new_stats = mutated.get("batch_stats", state.batch_stats)
-                return scaled, (loss, loss_total, correct, total, new_stats)
+                counters = step_counters(mutated)
+                return scaled, (loss, loss_total, correct, total, new_stats,
+                                counters)
 
-            grads, (loss, loss_total, correct, total, new_stats) = jax.grad(
-                loss_fn, has_aux=True)(state.params)
+            grads, (loss, loss_total, correct, total, new_stats,
+                    counters) = jax.grad(loss_fn, has_aux=True)(state.params)
             with jax.named_scope("fdt/grad_reduce"):
                 grads = reduce_grads(grads)
                 grads, finite = unscale_and_check(grads, state.loss_scale,
@@ -336,6 +357,8 @@ def make_train_step(cfg: TrainConfig, state_shardings=None, pipeline=None
             metrics = {"loss": loss.astype(jnp.float32),
                        "loss_total": loss_total,
                        "correct": correct, "total": total}
+            if counters:
+                metrics["counters"] = counters
             if fp16:
                 metrics["loss_scale"] = updated.loss_scale.scale
             if sentinel_on:
@@ -463,6 +486,8 @@ def _reduce_scanned_metrics(ms: Metrics) -> Metrics:
         out["bad_steps"] = jnp.sum(ms["bad_steps"])
     if "loss_scale" in ms:
         out["loss_scale"] = jax.tree.map(lambda x: x[-1], ms["loss_scale"])
+    if "counters" in ms:
+        out["counters"] = jax.tree.map(jnp.mean, ms["counters"])
     return out
 
 
@@ -570,7 +595,7 @@ def make_eval_step(cfg: TrainConfig) -> Callable[[TrainState, Any],
     No offload fetch here: under --host_offload the Trainer transfers the
     state to device ONCE per eval epoch (Trainer.evaluate), not per batch —
     the state never changes inside an eval loop."""
-    is_text = cfg.model == "transformer"
+    is_text = is_token_model(cfg)
     lm = getattr(cfg, "task", "cls") == "lm"
 
     def step(state: TrainState, batch: Dict[str, jax.Array]) -> Metrics:

@@ -107,6 +107,46 @@ def test_flash_attention_compiles(topo, one_chip, shape, mode):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("mode", ["fwd", "train"])
+@pytest.mark.parametrize("window", [2048, None])
+def test_banded_attention_compiles(topo, one_chip, window, mode):
+    """The decoder's attention at its cell's shape: one packed row of
+    8,192 ids, 32 query heads on 4 key-value heads of 128, the sliding
+    window and the full causal band; forward, and forward with both
+    backward kernels."""
+    from faster_distributed_training_tpu.ops.flash_attention import (
+        banded_attention)
+    q = _struct((1, 32, 8192, 128), BF16, one_chip)
+    kv = _struct((1, 4, 8192, 128), BF16, one_chip)
+    fn = lambda q, k, v: banded_attention(q, k, v, window)  # noqa: E731
+    if mode == "train":
+        fn = jax.grad(lambda q, k, v: jnp.sum(banded_attention(
+            q, k, v, window).astype(jnp.float32)), argnums=(0, 1, 2))
+    text = _compiled_text(topo, fn, q, kv, kv)
+    names = set(re.findall(r"fdt_flash_[a-z_]+banded", text))
+    assert "fdt_flash_fwd_banded" in names
+    if mode == "train":
+        assert {"fdt_flash_bwd_dq_banded",
+                "fdt_flash_bwd_dkv_banded"} <= names
+
+
+def test_grouped_expert_products_compile(topo, one_chip):
+    """The expert layer's grouped products at the cell's shape: 8,192
+    tokens x 8 slots against 16 held experts of 2048 x 1024, forward and
+    backward (megablox's gmm and its transposed twin; a sum's gradient
+    needs no forward, so two kernels stay)."""
+    from faster_distributed_training_tpu.ops.grouped_matmul import (
+        grouped_matmul)
+    xs = _struct((65536, 2048), BF16, one_chip)
+    w = _struct((16, 2048, 1024), BF16, one_chip)
+    sizes = _struct((16,), jnp.int32, one_chip)
+    fn = jax.grad(lambda x, w, s: jnp.sum(grouped_matmul(
+        x, w, s, "gmm").astype(jnp.float32)), argnums=(0, 1))
+    text = _compiled_text(topo, fn, xs, w, sizes)
+    assert text.count("tpu_custom_call") >= 2
+    assert "jit_gmm" in text and "jit_tgmm" in text
+
+
 def test_mlp_head_compiles(topo, one_chip):
     """fused_mlp_pallas at the classifier-head shape (bs64: pooled
     [64, 512] -> d_hidden 1024 -> 4 classes)."""

@@ -102,7 +102,49 @@ def test_lm_branch_in_the_fused_scan_carries_scope(lm_fused_step, scope):
     assert any(scope in loc for loc in locs), scope
 
 
-@pytest.mark.parametrize("program", ["resnet_ngd_step", "lm_fused_step"])
+@pytest.fixture(scope="module")
+def decoder_step():
+    """The decoder's train step at its rehearsal size (one dense and two
+    expert layers, sliding and full attention)."""
+    import os
+
+    from faster_distributed_training_tpu.cli import build_model
+    from faster_distributed_training_tpu.optim import build_optimizer
+    from faster_distributed_training_tpu.train import create_train_state
+    from faster_distributed_training_tpu.train.steps import make_train_step
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = TrainConfig(
+        model="decoder", task="lm", batch_size=2, seq_len=16,
+        optimizer="adamw", schedule="constant", precision="fp32", epochs=1,
+        decoder_config=os.path.join(root, "tests", "benchmark", "tiny",
+                                    "trinity_mini.json"))
+    tx, _ = build_optimizer(cfg, steps_per_epoch=2)
+    model = build_model(cfg, vocab_size=64)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, tx, jnp.zeros((2, 16), jnp.int32), jax.random.PRNGKey(0),
+        init_kwargs={"train": True}))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 16), jnp.int32)}
+    lowered = jax.jit(make_train_step(cfg)).lower(state, batch)
+    return lowered, _locations(lowered)
+
+
+@pytest.mark.parametrize("scope", [
+    "jvp(fdt/model)", "transpose(jvp(fdt/model))", "fdt/attention",
+    "fdt/moe_route", "fdt/moe_dispatch", "fdt/moe_experts",
+    "fdt/moe_combine", "fdt/optimizer"])
+def test_decoder_step_carries_scope(decoder_step, scope):
+    """The decoder's scopes all lie inside ``fdt/model``, forward and
+    backward (a configuration's ``scopes`` match them first)."""
+    _, locs = decoder_step
+    inside = [loc for loc in locs if scope in loc]
+    assert inside, scope
+    if scope.startswith("fdt/moe") or scope == "fdt/attention":
+        assert all("fdt/model" in loc for loc in inside)
+        assert any("transpose(jvp(fdt/model))" in loc for loc in inside)
+
+
+@pytest.mark.parametrize("program", ["resnet_ngd_step", "lm_fused_step",
+                                     "decoder_step"])
 def test_program_text_carries_no_scope(program, request):
     """Scopes live in debug locations only: the text the observatory
     fingerprints and the compile cache keys on does not move."""
@@ -175,7 +217,9 @@ def test_every_pallas_call_site_has_a_unique_documented_name():
     names = [n for site in sites.values() for n in site]
     assert all(site for site in sites.values()), sites
     assert all(re.fullmatch(r"fdt_[a-z0-9_]+", n) for n in names), names
-    assert len(set(names)) == len(names) == 11      # one site names two
+    # one site names two (with / without the saved statistics), three
+    # name a banded twin (the K-blocked kernels over a causal band)
+    assert len(set(names)) == len(names) == 14
     for n in names:
         assert f"``{n}``" in spans.__doc__, n
 
@@ -273,7 +317,20 @@ def _jaxpr_ffn_general(monkeypatch):
         *_ffn_args()))
 
 
+def _jaxpr_flash_banded(monkeypatch):
+    from faster_distributed_training_tpu.ops.flash_attention import (
+        banded_attention)
+    monkeypatch.setenv("FDT_FORCE_PALLAS_INTERPRET", "1")
+    q = jnp.zeros((1, 4, 128, 64), jnp.float32)
+    kv = jnp.zeros((1, 2, 128, 64), jnp.float32)
+    return str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(banded_attention(q, k, v, 32)),
+        argnums=(0, 1, 2)))(q, kv, kv))
+
+
 @pytest.mark.parametrize("trace,names", [
+    (_jaxpr_flash_banded, ["fdt_flash_fwd_banded", "fdt_flash_bwd_dq_banded",
+                           "fdt_flash_bwd_dkv_banded"]),
     (_jaxpr_flash_fwd_only, ["fdt_flash_fwd"]),
     (_jaxpr_flash_saved_stats, ["fdt_flash_fwd_lse", "fdt_flash_bwd_fused"]),
     (_jaxpr_flash_recompute, ["fdt_flash_fwd", "fdt_flash_bwd_recompute"]),
