@@ -29,6 +29,15 @@ configurations do: ``num_experts`` is what is HELD, ``published.num_experts``
 the router's width; this process holds the FIRST share, experts
 ``[0, num_experts)`` (a run over several shares is ROADMAP R3's remainder).
 ``vocab_size`` is the slice's.
+
+``--remat`` keeps, of each block, its input and a short list of named
+values, and the backward replays the rest: the attention kernel's own
+residuals ``out`` and ``lse`` (``flash_attention.BANDED_RESIDUALS``; 68 MB
+a layer and row of 8,192 tokens at 32 heads of 128 in bfloat16, beside the
+33.6 MB block input), so the replay never runs the forward kernel a second
+time, and the routing decision's integers (``moe.ROUTING_RESIDUALS``).
+Projections, norms, rotary, the router's scores and the experts are
+recomputed.  ``--remat_policy`` is the encoder's and is not read here.
 """
 
 from __future__ import annotations
@@ -41,9 +50,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from faster_distributed_training_tpu.models.moe import ExpertLayer, SwiGLU
+from faster_distributed_training_tpu.models.moe import (
+    ROUTING_RESIDUALS, ExpertLayer, SwiGLU)
 from faster_distributed_training_tpu.ops.flash_attention import (
-    banded_attention)
+    BANDED_RESIDUALS, banded_attention)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -200,6 +210,12 @@ class DecoderBlock(nn.Module):
         return x + norm("post_mlp_norm")(m)
 
 
+# What ``--remat`` keeps of a block: its input and these named values.
+RematBlock = nn.remat(
+    DecoderBlock, policy=jax.checkpoint_policies.save_only_these_names(
+        *BANDED_RESIDUALS, *ROUTING_RESIDUALS))
+
+
 class Decoder(nn.Module):
     """Token ids [B, L] -> logits [B, L, vocab].  Takes the call
     ``train/steps.py`` makes of a token model; ``token_types`` and ``mask``
@@ -219,7 +235,7 @@ class Decoder(nn.Module):
                            nn.initializers.normal(1.0 / s.embed_scale),
                            (s.vocab_size, s.hidden_size), jnp.float32)
         x = (table[tokens] * s.embed_scale).astype(self.dtype)
-        block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
+        block = RematBlock if self.remat else DecoderBlock
         for i, kind in enumerate(s.layer_types):
             x = block(s, kind, i < s.num_dense_layers, self.dtype,
                       name=f"layer_{i}")(x)

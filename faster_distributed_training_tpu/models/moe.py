@@ -29,6 +29,13 @@ The balancing bias that the published routing adds to the scores before
 the top-k (``expert_bias``) is the constant zero here: its source gives a
 coefficient and no update rule, and a bias nothing updates is no state.
 When a rule arrives the bias becomes a ``batch_stats`` leaf (ROADMAP R3).
+
+Inside a block under ``--remat`` (models/decoder.py) the routing
+decision's integers are kept by name (``ROUTING_RESIDUALS``: ``chosen``,
+``order``, ``inv``, ``sizes``; 0.8 MB a layer at 8,192 tokens and 8 experts
+a token), so the backward's replay repeats neither the top-k, nor the
+sort, nor the scatters; the router's scores, the gathers and the grouped
+products are replayed.  Saved or recomputed they are the same integers.
 """
 
 from __future__ import annotations
@@ -39,11 +46,19 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from faster_distributed_training_tpu.ops.grouped_matmul import grouped_matmul
 from faster_distributed_training_tpu.telemetry.spans import COUNTERS
 
 HI = jax.lax.Precision.HIGHEST
+
+# The routing decision's integers by checkpoint_name (the module docstring's
+# last paragraph): ``chosen`` [T, k], ``order`` and ``inv`` [T * k],
+# ``sizes`` [held].  Without a policy that saves them the names are the
+# identity.
+CHOSEN, ORDER, INV, SIZES = ROUTING_RESIDUALS = (
+    "moe_chosen", "moe_order", "moe_inv", "moe_sizes")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -96,10 +111,11 @@ def route(x32, router_kernel, top_k: int, route_scale: float,
     scores = jax.nn.sigmoid(jnp.dot(x32, router_kernel.astype(jnp.float32),
                                     precision=HI))
     _, chosen = jax.lax.top_k(scores, top_k)
+    chosen = checkpoint_name(chosen.astype(jnp.int32), CHOSEN)
     w = jnp.take_along_axis(scores, chosen, axis=1)
     if route_norm:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), w * route_scale
+    return chosen, w * route_scale
 
 
 def routed_experts(x, chosen, weights, gate, up, down, lo: int,
@@ -116,10 +132,13 @@ def routed_experts(x, chosen, weights, gate, up, down, lo: int,
         local = chosen.reshape(-1) - lo
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held)          # absent experts last
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        order = checkpoint_name(
+            jnp.argsort(key, stable=True).astype(jnp.int32), ORDER)
+        inv = checkpoint_name(
+            jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=jnp.int32)), INV)
+        sizes = checkpoint_name(
+            jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held], SIZES)
         xs = _dispatch(x, order, inv, top_k)
     with jax.named_scope("fdt/moe_experts"):
         h = (jax.nn.silu(grouped_matmul(xs, gate, sizes, impl))

@@ -77,6 +77,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from faster_distributed_training_tpu.ops import pallas_target
 from faster_distributed_training_tpu.ops.attention import (
@@ -1266,6 +1267,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # blockwise twin with the same band.
 # ---------------------------------------------------------------------------
 
+# What the forward kernel hands its backward besides q, k, v, by
+# checkpoint_name: out [B, H, L, D] and lse [B * H, L] float32 (the row
+# sums' logs WITHOUT the kernel's 128 lanes).  A remat policy that saves
+# these names (models/decoder.py) never runs the forward kernel in its
+# replay; without a policy the names are the identity.  The blockwise twin
+# goes through autodiff, has no such residuals and names nothing.
+BANDED_OUT, BANDED_LSE = BANDED_RESIDUALS = (
+    "banded_attn_out", "banded_attn_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _banded_core(q, k, v, window):
     return _banded_fwd(q, k, v, window)[0]
@@ -1276,7 +1287,8 @@ def _banded_fwd(q, k, v, window):
     n3 = lambda x: x.reshape(-1, x.shape[2], x.shape[3])  # noqa: E731
     out, lse = _flash_fwd_kblocked(n3(q), n3(k), n3(v), None, n_heads=H,
                                    causal=True, window=window)
-    out = out.reshape(B, H, Lq, D)
+    out = checkpoint_name(out.reshape(B, H, Lq, D), BANDED_OUT)
+    lse = checkpoint_name(lse, BANDED_LSE)
     return out, (q, k, v, out, lse)
 
 

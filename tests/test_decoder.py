@@ -12,6 +12,7 @@ gives (4e-3, one part in 2**8), which ``test_bfloat16_would_fail`` shows
 failing it.
 """
 
+import functools
 import importlib
 import json
 import os
@@ -29,7 +30,7 @@ if ROOT not in sys.path:
 from faster_distributed_training_tpu.models import decoder, moe  # noqa: E402
 from faster_distributed_training_tpu.ops import attention as xla_attention  # noqa: E402
 from faster_distributed_training_tpu.ops.flash_attention import (  # noqa: E402
-    banded_attention)
+    BANDED_LSE, banded_attention)
 from faster_distributed_training_tpu.ops.grouped_matmul import (  # noqa: E402
     grouped_matmul)
 from faster_distributed_training_tpu.train.steps import step_counters  # noqa: E402
@@ -309,6 +310,122 @@ def test_banded_attention_matches_dense_masked_softmax(path, length, window,
             *a, window))), (0, 1, 2))(q, k, v)
         for g, w in zip(grads, wants):
             assert close(g, w, 5 * TOL)
+
+
+# -- what --remat keeps --------------------------------------------------------
+
+def equations(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+def forward_kernels(fn, *args):
+    """How often the banded forward kernel is in ``fn``'s gradient."""
+    jaxpr = jax.make_jaxpr(jax.grad(fn))(*args).jaxpr
+    return sum(eqn.primitive.name == "pallas_call"
+               and eqn.params["name"] == "fdt_flash_fwd_banded"
+               for eqn in equations(jaxpr))
+
+
+def kept(fn, *args):
+    """[(aval, where from)] of what ``fn``'s backward keeps of its forward,
+    arguments and constants left out."""
+    from jax._src.ad_checkpoint import saved_residuals
+    return [(aval, why) for aval, why in saved_residuals(fn, *args)
+            if not why.startswith(("from the argument", "from a constant",
+                                   "from a literal"))]
+
+
+def named(residuals, name):
+    return [aval for aval, why in residuals if f"named '{name}'" in why]
+
+
+REMAT_CASES = {"dense-block": one_layer(SLIDING, True),
+               "expert-block": one_layer(FULL, False), "whole-tiny": TINY}
+
+
+@pytest.mark.parametrize("path", ["blockwise", "kernels"])
+@pytest.mark.parametrize("name", sorted(REMAT_CASES))
+def test_remat_keeps_named_residuals_and_changes_nothing(name, path,
+                                                         monkeypatch):
+    """With ``remat=True`` (a) the backward keeps, a layer, the attention
+    kernel's ``out`` and ``lse`` (on the kernels' path: the blockwise twin
+    has no such residuals and names nothing) and an expert layer's routing
+    integers, and the forward banded kernel is in the gradient once a
+    layer, as without remat; (b) loss and every gradient leaf are
+    ``remat=False``'s."""
+    monkeypatch.setenv("FDT_FORCE_PALLAS_INTERPRET",
+                       "1" if path == "kernels" else "0")
+    sizes_dict = REMAT_CASES[name]
+    sizes = decoder.sizes_from(sizes_dict)
+    layers = len(sizes.layer_types)
+    expert_layers = layers - sizes.num_dense_layers
+    with jax.enable_x64(False):
+        tokens = tokens_for(sizes_dict)
+        params = reference.init_params(dict(sizes_dict, seq_len=16), 11)
+
+        def loss(p, remat):
+            logits = decoder.Decoder(sizes, remat=remat).apply(
+                {"params": p}, tokens)
+            logp = jax.nn.log_softmax(logits[:, :-1], -1)
+            return -jnp.mean(jnp.take_along_axis(
+                logp, tokens[:, 1:, None], axis=-1))
+        plain, rematted = (functools.partial(loss, remat=r)
+                           for r in (False, True))
+        residuals = kept(rematted, params)
+        on_kernels = layers if path == "kernels" else 0
+        B, L = tokens.shape
+        out_shape = (B, sizes.num_attention_heads, L, sizes.head_dim)
+        assert len(named(residuals, BANDED_LSE)) == on_kernels
+        # ``out`` is also the block's primal, so JAX keeps it behind a
+        # full-width reduce_precision and reports that in the name's place
+        assert sum(aval.shape == out_shape and "flash_attention.py" in why
+                   for aval, why in residuals) == on_kernels
+        for routing in moe.ROUTING_RESIDUALS:
+            assert len(named(residuals, routing)) == expert_layers
+        assert forward_kernels(rematted, params) == on_kernels
+        assert forward_kernels(plain, params) == on_kernels
+        want, want_grads = jax.value_and_grad(plain)(params)
+        value, grads = jax.value_and_grad(rematted)(params)
+    assert abs(float(value) - float(want)) <= TOL * abs(float(want))
+    assert_trees_close(grads, want_grads)
+
+
+@pytest.mark.parametrize("name", ["dense-block", "expert-block"])
+def test_remat_keeps_lse_without_the_kernels_lanes(name, monkeypatch):
+    """The bytes ONE rematted block keeps besides its input are the
+    arithmetic of its docstring: ``out`` [B, H, L, D] and ``lse``
+    [B * H, L] float32 (not the kernel's 128-lane [B * H, L, 128]), and on
+    an expert block the routing integers: ``chosen`` [T, k], ``order`` and
+    ``inv`` [T * k], ``sizes`` [held], with the two index arrays that
+    jnp's jitted helpers hand over whatever the policy says
+    (``take_along_axis``'s indices [T, k], ``order // top_k`` [T * k])."""
+    monkeypatch.setenv("FDT_FORCE_PALLAS_INTERPRET", "1")
+    sizes = decoder.sizes_from(REMAT_CASES[name])
+    kind, dense = sizes.layer_types[0], bool(sizes.num_dense_layers)
+    B, L = 2, 16
+    H, D, k = (sizes.num_attention_heads, sizes.head_dim,
+               sizes.num_experts_per_tok)
+    with jax.enable_x64(False):
+        x = jax.random.normal(jax.random.PRNGKey(0),
+                              (B, L, sizes.hidden_size), jnp.float32)
+        block = decoder.RematBlock(sizes, kind, dense)
+        params = block.init(jax.random.PRNGKey(1), x)["params"]
+        residuals = kept(lambda p, u: jnp.sum(block.apply({"params": p}, u)),
+                         params, x)
+    got = sum(aval.size * aval.dtype.itemsize for aval, _ in residuals)
+    want = 4 * B * H * L * D + 4 * B * H * L
+    if not dense:
+        slots = B * L * k
+        want += 4 * (slots + 2 * slots + sizes.held) + 4 * (slots + slots)
+    assert got == want, [(aval.str_short(), why) for aval, why in residuals]
+    assert named(residuals, BANDED_LSE)[0].shape == (B * H, L)
 
 
 def test_blockwise_window_needs_no_kernel():
