@@ -19,14 +19,18 @@ combine (``fc2_latent_proj``); the router and the shared expert read the
 tokens at full width.
 
 Static shapes and no capacity: a token's ``top_k`` experts are distinct,
-so at most ``min(top_k, held)`` of its slots land here, and the buffers
-hold ``T x min(top_k, held)`` rows, enough for a step in which every token
-chose every held expert.  No token is dropped.  Held slots sort first, so
-the rows cut off the end of the expert order are absent slots; the combine
-reads a zero row for them.  What the experts held elsewhere would add is
-left out: on one chip the layer runs without its exchange, and nothing
-stands in for the absent chips (expert parallelism's exchange is ROADMAP
-R3's remainder).
+so at most ``m = min(top_k, held)`` of its slots land here, and the buffers
+hold ``T x m`` rows, enough for a step in which every token chose every
+held expert.  No token is dropped.  Held slots sort first, so the rows cut
+off the end of the expert order are absent slots.  Nothing else works at
+``T x top_k`` rows either: a compact index [T, m], a permutation of the
+``T x m`` rows (each token's landed slots' rows in slot order, then rows
+of absent slots, which the grouped products return as zeros), stands for
+the un-sort, so the combine, its transpose and the dispatch's transpose
+each gather ``T x m`` rows, every row once.  What the experts held
+elsewhere would add is left out: on one chip the layer runs without its
+exchange, and nothing stands in for the absent chips (expert parallelism's
+exchange is ROADMAP R3's remainder).
 
 Device scopes (telemetry/spans.py): ``fdt/moe_route``, ``fdt/moe_dispatch``,
 ``fdt/moe_experts``, ``fdt/moe_combine``, and ``fdt/moe_latent`` round the
@@ -44,15 +48,15 @@ When a rule arrives the bias becomes a ``batch_stats`` leaf (ROADMAP R3).
 
 Inside a block under ``--remat`` (models/decoder.py) the routing
 decision's integers are kept by name (``ROUTING_RESIDUALS``: ``chosen``,
-``order``, ``inv``, ``sizes``; 0.8 MB a layer at 8,192 tokens and 8 experts
-a token), so the backward's replay repeats neither the top-k, nor the
-sort, nor the scatters; the router's scores, the gathers and the grouped
+the rows' tokens, ``sizes``, the compact index and its inverse: 1.0 MB a
+layer at 8,192 tokens and 8 experts a token, 1.5 MB at 22 a token over 8
+held), so the backward's replay repeats neither the top-k, nor the sort,
+nor the scatters; the router's scores, the gathers and the grouped
 products are replayed.  Saved or recomputed they are the same integers.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -66,60 +70,90 @@ from faster_distributed_training_tpu.telemetry.spans import COUNTERS
 HI = jax.lax.Precision.HIGHEST
 
 # The routing decision's integers by checkpoint_name (the module docstring's
-# last paragraph): ``chosen`` [T, k], ``order`` [T * min(k, held)],
-# ``inv`` [T * k], ``sizes`` [held].  Without a policy that saves them the names are the
+# last paragraph): ``chosen`` [T, k], ``source`` [T * m] (the token of each
+# expert-order row), ``sizes`` [held], the compact index ``compact``
+# [T, m] and its inverse ``compact_inv`` [T * m] (``_compact``), with
+# m = min(k, held).  Without a policy that saves them the names are the
 # identity.
-CHOSEN, ORDER, INV, SIZES = ROUTING_RESIDUALS = (
-    "moe_chosen", "moe_order", "moe_inv", "moe_sizes")
+CHOSEN, SOURCE, SIZES, COMPACT, COMPACT_INV = ROUTING_RESIDUALS = (
+    "moe_chosen", "moe_source", "moe_sizes", "moe_compact",
+    "moe_compact_inv")
 
 
-def _rows(a, idx):
-    """``a[idx]``, with a zero row where ``idx`` is past ``a``'s rows: the
-    slots cut off the end of the expert order (``routed_experts``)."""
-    if a.shape[0] == idx.shape[0]:      # nothing cut: a permutation
-        return a[idx]
-    return a.at[idx].get(mode="fill", fill_value=0)
+def _compact(here, inv, landed, m: int):
+    """(``pick`` [T, m, top_k], entry (t, j, k): slot k is token t's j-th
+    landed one; the compact index ``slots`` [T, m]; its inverse ``rows``
+    [T * m]).  ``slots`` is a permutation of the expert order's ``T * m``
+    rows: entry (t, j) is the row of token t's j-th landed slot and, past
+    its landed ones, one of the rows past ``landed`` (absent slots', which
+    the grouped products leave zero), each once, so that every gather
+    through it reads each row once.  ``here`` [T, top_k] marks the landed
+    slots, ``inv`` [T, top_k] each slot's row in the expert order."""
+    T = here.shape[0]
+    n = T * m
+    rank = jnp.cumsum(here, axis=1, dtype=jnp.int32) - 1
+    count = rank[:, -1:] + 1
+    pick = here[:, None] & (rank[:, None] == jnp.arange(m)[:, None])
+    free = m - count
+    first = landed + jnp.cumsum(free, axis=0) - free - count
+    j = jnp.arange(m, dtype=jnp.int32)
+    slots = jnp.where(j < count, jnp.sum(jnp.where(pick, inv[:, None], 0),
+                                         axis=2), first + j)
+    rows = jnp.zeros((n,), jnp.int32).at[slots.reshape(-1)].set(
+        jnp.arange(n, dtype=jnp.int32))
+    return (pick, checkpoint_name(slots, COMPACT),
+            checkpoint_name(rows, COMPACT_INV))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inv, top_k):
-    """Row ``r`` of the result is token ``order[r] // top_k``: the tokens'
-    rows laid out slot by slot in expert order.  Its transpose is a gather
-    too (by the inverse permutation, then a sum over a token's slots), where
-    autodiff would scatter-add ``T x top_k`` rows."""
-    return x[order // top_k]
+@jax.custom_vjp
+def _dispatch(x, source, slots):
+    """Row ``r`` of the result is token ``source[r]``: the tokens' rows
+    laid out slot by slot in expert order.  Transposed, a token sums the
+    rows of its entries of ``slots`` [T, m] (zero past its landed slots),
+    a gather where autodiff would scatter-add."""
+    return x[source]
 
 
-def _dispatch_fwd(x, order, inv, top_k):
-    return x[order // top_k], (order, inv)
+def _dispatch_fwd(x, source, slots):
+    return x[source], slots
 
 
-def _dispatch_bwd(top_k, res, g):
-    order, inv = res
-    d = _rows(g, inv).reshape(-1, top_k, g.shape[-1])
+def _dispatch_bwd(slots, g):
+    d = g[slots.reshape(-1)].reshape(*slots.shape, g.shape[-1])
     return jnp.sum(d.astype(jnp.float32), axis=1).astype(g.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+def _weighted_sum(y, w):
+    return jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
+
+
 @jax.custom_vjp
-def _unsort(y, order, inv):
-    """Expert order back to token order: ``y[inv]``, zero for a slot cut
-    off the end; transposed, ``g[order]``."""
-    return _rows(y, inv)
+def _combine(ys, w, slots, rows):
+    """Token t's float32 sum of ``w[t, j] * ys[slots[t, j]]`` (``w``
+    [T, m] is zero past its landed slots).  Transposed, the weighted
+    cotangents are formed in token order at [T, m] in ``ys``' dtype and
+    row r takes its entry's (one gather through ``rows``); a weight's
+    cotangent is its row's dot with its token's cotangent: no
+    ``T x top_k`` rows."""
+    return _weighted_sum(ys[slots], w)
 
 
-def _unsort_fwd(y, order, inv):
-    return _rows(y, inv), (order, inv)
+def _combine_fwd(ys, w, slots, rows):
+    y = ys[slots]
+    return _weighted_sum(y, w), (y, w, rows)
 
 
-def _unsort_bwd(res, g):
-    order, inv = res
-    return g[order], None, None
+def _combine_bwd(res, g):
+    y, w, rows = res
+    d = (g[:, None] * w[..., None]).astype(y.dtype).reshape(-1, y.shape[-1])
+    d_w = jnp.sum(y.astype(jnp.float32) * g[:, None], axis=2)
+    return d[rows], d_w, None, None
 
 
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def route(x32, router_kernel, top_k: int, route_scale: float,
@@ -166,31 +200,34 @@ def routed_experts(x, chosen, weights, gate, up, down, lo: int,
     [held, d, f] (no ``gate`` under a gateless ``act``) and ``down``
     [held, f, d].  Also returns the slots a held expert received, [held]
     int32.  The expert-order buffers hold ``T x min(top_k, held)`` rows:
-    a token's experts are distinct, so no more of its slots land here."""
-    T, d = x.shape
+    a token's experts are distinct, so no more of its slots land here, and
+    the combine and both transposes gather as many through the compact
+    index (the module docstring)."""
+    T = x.shape[0]
     top_k = chosen.shape[1]
     held = up.shape[0]
+    m = min(top_k, held)
     with jax.named_scope("fdt/moe_dispatch"):
         local = chosen.reshape(-1) - lo
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held)          # absent experts last
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = checkpoint_name(
-            jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=jnp.int32)), INV)
-        if top_k > held:                  # the rows past these are absent
-            order = order[:T * held]
-        order = checkpoint_name(order, ORDER)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        # the rows past T x m are absent slots
+        source = checkpoint_name(order[:T * m] // top_k, SOURCE)
         sizes = checkpoint_name(
             jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held], SIZES)
-        xs = _dispatch(x, order, inv, top_k)
+        pick, slots, rows = _compact(here.reshape(T, top_k),
+                                     inv.reshape(T, top_k), jnp.sum(sizes), m)
+        xs = _dispatch(x, source, slots)
     with jax.named_scope("fdt/moe_experts"):
         ys = ffn(xs, gate, up, down,
                  lambda a, w: grouped_matmul(a, w, sizes, impl), act)
     with jax.named_scope("fdt/moe_combine"):
-        y = _unsort(ys, order, inv).reshape(T, top_k, d)
-        w = jnp.where(here.reshape(T, top_k), weights, 0.0)
-        out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
+        # token t's j-th landed slot's weight: one slot of top_k matches
+        w = jnp.sum(jnp.where(pick, weights[:, None], 0.0), axis=2)
+        out = _combine(ys, w, slots, rows)
     return out.astype(x.dtype), sizes
 
 
@@ -211,9 +248,6 @@ class MLP(nn.Module):
         down = self.param("down_proj", init, (self.width, d), jnp.float32)
         return ffn(x, gate, up, down,
                    lambda a, w: jnp.dot(a, w.astype(self.dtype)), self.act)
-
-
-SwiGLU = MLP        # the name tests/test_decoder.py gives the silu default
 
 
 class ExpertLayer(nn.Module):

@@ -195,7 +195,7 @@ def test_shares_add_up_to_the_whole_layer(impl):
             out, stats = apply_share(sizes, params, u, lo=2 * i, held=2,
                                      n_shared=0, impl=impl)
         total, slots = total + out, slots + float(stats["moe_slots"])
-    shared = moe.SwiGLU(sizes["moe_intermediate_size"]).apply(
+    shared = moe.MLP(sizes["moe_intermediate_size"]).apply(
         {"params": params["shared"]}, u)
     assert close(total + shared, want)
     assert slots == u.shape[0] * u.shape[1] * sizes["num_experts_per_tok"]
@@ -346,8 +346,13 @@ def named(residuals, name):
     return [aval for aval, why in residuals if f"named '{name}'" in why]
 
 
+# "expert-block-cut": 5 experts a token over the 4 held (the hybrid cell's
+# 22 over 8), so the compact index is narrower than top_k
 REMAT_CASES = {"dense-block": one_layer(SLIDING, True),
-               "expert-block": one_layer(FULL, False), "whole-tiny": TINY}
+               "expert-block": one_layer(FULL, False),
+               "expert-block-cut": one_layer(FULL, False,
+                                             num_experts_per_tok=5),
+               "whole-tiny": TINY}
 
 
 @pytest.mark.parametrize("path", ["blockwise", "kernels"])
@@ -357,9 +362,10 @@ def test_remat_keeps_named_residuals_and_changes_nothing(name, path,
     """With ``remat=True`` (a) the backward keeps, a layer, the attention
     kernel's ``out`` and ``lse`` (on the kernels' path: the blockwise twin
     has no such residuals and names nothing) and an expert layer's routing
-    integers, and the forward banded kernel is in the gradient once a
-    layer, as without remat; (b) loss and every gradient leaf are
-    ``remat=False``'s."""
+    integers, the compact index and its inverse among them whether
+    ``top_k`` is at most ``held`` or more; and the forward banded kernel is
+    in the gradient once a layer, as without remat; (b) loss and every
+    gradient leaf are ``remat=False``'s."""
     monkeypatch.setenv("FDT_FORCE_PALLAS_INTERPRET",
                        "1" if path == "kernels" else "0")
     sizes_dict = REMAT_CASES[name]
@@ -397,15 +403,17 @@ def test_remat_keeps_named_residuals_and_changes_nothing(name, path,
     assert_trees_close(grads, want_grads)
 
 
-@pytest.mark.parametrize("name", ["dense-block", "expert-block"])
+@pytest.mark.parametrize("name",
+                         ["dense-block", "expert-block", "expert-block-cut"])
 def test_remat_keeps_lse_without_the_kernels_lanes(name, monkeypatch):
     """The bytes ONE rematted block keeps besides its input are the
     arithmetic of its docstring: ``out`` [B, H, L, D] and ``lse``
     [B * H, L] float32 (not the kernel's 128-lane [B * H, L, 128]), and on
-    an expert block the routing integers: ``chosen`` [T, k], ``order`` and
-    ``inv`` [T * k], ``sizes`` [held], with the two index arrays that
-    jnp's jitted helpers hand over whatever the policy says
-    (``take_along_axis``'s indices [T, k], ``order // top_k`` [T * k])."""
+    an expert block the routing integers: ``chosen`` [T, k], the rows'
+    tokens, the compact index [T, m] and its inverse [T * m] with
+    m = min(k, held), ``sizes`` [held], with the index array that jnp's
+    jitted ``take_along_axis`` hands over whatever the policy says
+    ([T, k])."""
     monkeypatch.setenv("FDT_FORCE_PALLAS_INTERPRET", "1")
     sizes = decoder.sizes_from(REMAT_CASES[name])
     kind, dense = sizes.layer_types[0], bool(sizes.num_dense_layers)
@@ -422,8 +430,8 @@ def test_remat_keeps_lse_without_the_kernels_lanes(name, monkeypatch):
     got = sum(aval.size * aval.dtype.itemsize for aval, _ in residuals)
     want = 4 * B * H * L * D + 4 * B * H * L
     if not dense:
-        slots = B * L * k
-        want += 4 * (slots + 2 * slots + sizes.held) + 4 * (slots + slots)
+        slots, rows = B * L * k, B * L * min(k, sizes.held)
+        want += 4 * (slots + 2 * rows + sizes.held) + 4 * (slots + rows)
     assert got == want, [(aval.str_short(), why) for aval, why in residuals]
     assert named(residuals, BANDED_LSE)[0].shape == (B * H, L)
 

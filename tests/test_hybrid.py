@@ -18,6 +18,7 @@ what a bfloat16 scan state gives (``test_bfloat16_state_would_fail``:
 import importlib
 import json
 import os
+import re
 import sys
 
 import jax
@@ -341,20 +342,25 @@ def routed_layer(sizes, held, lo, impl=None, top_k=None):
         latent=sizes["moe_latent_size"], impl=impl)
 
 
-@pytest.mark.parametrize("impl", ["ragged", "gmm"])
-@pytest.mark.parametrize("top_k", [3, 5])
-def test_every_token_on_every_held_expert_fills_the_cut_buffers(top_k,
-                                                                impl):
-    """More experts a token than are held (the cell's 22 of 8): the
-    expert-order buffers hold T x held rows, not T x top_k.  A router that
-    sends every token to both held experts fills them to the last row, and
-    the layer's output and every gradient are still the reference's."""
+def rows_at(fn, width, *args):
+    """{rows} of every two-dimensional value ``width`` wide in ``fn``'s
+    lowered program."""
+    text = jax.jit(fn).lower(*args).as_text()
+    return {int(r) for r in re.findall(rf"tensor<(\d+)x{width}x", text)}
+
+
+def cut_layer(top_k, impl, fill):
+    """Two of 8 latent experts held, ``top_k`` a token: (loss of the
+    layer's output and its counters, the reference's loss, weights,
+    tokens).  ``fill``: a router that sends every token to both held
+    experts; else the seeded one, under which some slots land."""
     sizes, params, u = whole_layer("E")
     sizes = dict(sizes, num_experts_per_tok=top_k, n_routed_experts=2,
                  published={"n_routed_experts": 8})
-    u = jnp.abs(u)                       # so that u . (ones) > 0
-    params = dict(params, router=params["router"].at[:, :2].set(1.0),
-                  experts_up_proj=params["experts_up_proj"][:2],
+    if fill:
+        u = jnp.abs(u)                   # so that u . (ones) > 0
+        params = dict(params, router=params["router"].at[:, :2].set(1.0))
+    params = dict(params, experts_up_proj=params["experts_up_proj"][:2],
                   experts_down_proj=params["experts_down_proj"][:2])
     layer = routed_layer(sizes, held=2, lo=0, impl=impl, top_k=top_k)
 
@@ -365,6 +371,21 @@ def test_every_token_on_every_held_expert_fills_the_cut_buffers(top_k,
     def want(p, x):
         return jnp.sum(jnp.sin(jnp.stack([
             reference.expert_layer(p, row, sizes, mm) for row in x])))
+    return sizes, loss, want, params, u
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+@pytest.mark.parametrize("top_k", [3, 5])
+def test_every_token_on_every_held_expert_fills_the_cut_buffers(top_k,
+                                                                impl):
+    """More experts a token than are held (the cell's 22 of 8): the
+    expert-order buffers hold T x held rows, not T x top_k, and nothing of
+    the gradient works at T x top_k rows of the latent width (the combine,
+    its transpose and the dispatch's go through the compact index).  A
+    router that sends every token to both held experts fills them to the
+    last row, and the layer's output and every gradient are still the
+    reference's."""
+    sizes, loss, want, params, u = cut_layer(top_k, impl, fill=True)
     with jax.enable_x64(False):       # megablox's interpreter is 32-bit
         (value, counted), grads = jax.value_and_grad(
             loss, (0, 1), has_aux=True)(params, u)
@@ -372,12 +393,64 @@ def test_every_token_on_every_held_expert_fills_the_cut_buffers(top_k,
         rows = {v.aval.shape[0]          # of the grouped products' results
                 for e in jax.make_jaxpr(loss)(params, u).jaxpr.eqns
                 for v in e.outvars if v.aval.shape[1:] == (f,)}
+        latent = rows_at(jax.value_and_grad(loss, (0, 1), has_aux=True),
+                         sizes["moe_latent_size"], params, u)
     wanted, want_grads = jax.value_and_grad(want, (0, 1))(params, u)
     tokens = u.shape[0] * u.shape[1]
     assert rows == {tokens * 2}          # held rows a token, not top_k
+    assert tokens * 2 in latent and tokens * top_k not in latent
     assert float(sum(jax.tree.leaves(counted["moe_slots"]))) == tokens * 2
     assert abs(float(value) - float(wanted)) <= TOL * abs(float(wanted))
     assert_trees_close(grads, want_grads)
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+@pytest.mark.parametrize("top_k", [3, 5])
+def test_some_slots_landing_on_a_cut_layer_match_the_reference(top_k, impl):
+    """The seeded router: tokens land none, one or both of their slots on
+    the two held experts, so a token's entries of the compact index past
+    its landed slots name absent slots' rows; output and every gradient
+    are the reference's."""
+    sizes, loss, want, params, u = cut_layer(top_k, impl, fill=False)
+    with jax.enable_x64(False):
+        (value, counted), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(params, u)
+        chosen, _ = moe.route(u.reshape(-1, u.shape[-1]), params["router"],
+                              top_k, 1.0)
+    wanted, want_grads = jax.value_and_grad(want, (0, 1))(params, u)
+    landed = jnp.sum(chosen < 2, axis=1)
+    assert {0, 1, 2} <= set(np.asarray(landed).tolist())
+    assert float(sum(jax.tree.leaves(counted["moe_slots"]))) \
+        == float(jnp.sum(landed))
+    assert abs(float(value) - float(wanted)) <= TOL * abs(float(wanted))
+    assert_trees_close(grads, want_grads)
+
+
+@pytest.mark.parametrize("top_k,held", [(3, 2), (5, 2), (2, 4)])
+def test_compact_index_is_a_permutation_of_the_rows(top_k, held):
+    """Every gather through the compact index reads each expert-order row
+    once: ``slots`` is a permutation of the T x m rows and ``rows`` its
+    inverse; a token's entries are its landed slots' rows in slot order,
+    then rows past the landed ones (absent slots')."""
+    T, m = 16, min(top_k, held)
+    with jax.enable_x64(False):
+        chosen = jnp.argsort(jax.random.uniform(
+            jax.random.PRNGKey(top_k), (T, 8)), axis=1)[:, :top_k]
+        here = chosen < held
+        order = jnp.argsort(jnp.where(here, chosen, held).reshape(-1),
+                            stable=True)
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * top_k))
+        landed = int(jnp.sum(here))
+        _, slots, rows = moe._compact(here, inv.reshape(T, top_k),
+                                      jnp.int32(landed), m)
+    slots, rows, order = (np.asarray(a) for a in (slots, rows, order))
+    assert sorted(slots.reshape(-1)) == list(range(T * m))
+    assert (rows[slots.reshape(-1)] == np.arange(T * m)).all()
+    for t in range(T):
+        mine = [t * top_k + k for k in range(top_k) if here[t, k]]
+        assert list(order[slots[t, :len(mine)]]) == mine
+        assert (slots[t, len(mine):] >= landed).all()
+    assert 0 < landed < T * m
 
 
 def test_flops_and_bytes_of_the_cell():
