@@ -96,7 +96,18 @@ def setup_platform(cfg: TrainConfig) -> str:
     attached; `auto` keeps whatever the environment provides and says
     which that was.  On cpu, a mesh larger than the physical device
     count gets virtual devices (the multi-chip simulation used by
-    tests, SURVEY.md §4)."""
+    tests, SURVEY.md §4).
+
+    Opens the run's set-up stretch (telemetry/spans.py): set-up spans
+    closed before ``build_telemetry`` are held for its recorder."""
+    from faster_distributed_training_tpu.telemetry import spans
+
+    spans.open_setup()
+    with spans.span("setup_platform"):
+        return _select_platform(cfg)
+
+
+def _select_platform(cfg: TrainConfig) -> str:
     import jax
 
     if cfg.device != "auto":
@@ -336,6 +347,14 @@ def build_model(cfg: TrainConfig, vocab_size: Optional[int] = None,
     QuantDense quantize at the scales the restored amax history implies
     and never roll it, so serving is state-free and two identical
     requests return bitwise-identical logits (serve/engine.py)."""
+    from faster_distributed_training_tpu.telemetry import spans
+
+    with spans.span("setup_model"):
+        return _build_model(cfg, vocab_size, mesh, serving)
+
+
+def _build_model(cfg: TrainConfig, vocab_size: Optional[int], mesh,
+                 serving: bool):
     import jax.numpy as jnp
 
     from faster_distributed_training_tpu.models import get_model
@@ -583,6 +602,14 @@ def make_loaders(cfg: TrainConfig, train_ds, eval_ds, dp: int = 1
     process_count samples and make_array_from_process_local_data
     assembles the global array (DistributedSampler semantics,
     resnet50_test.py:331)."""
+    from faster_distributed_training_tpu.telemetry import spans
+
+    with spans.span("setup_data"):
+        return _make_loaders(cfg, train_ds, eval_ds, dp)
+
+
+def _make_loaders(cfg: TrainConfig, train_ds, eval_ds, dp: int
+                  ) -> Tuple[Callable, Callable, int]:
     import jax
 
     from faster_distributed_training_tpu.data import (BatchLoader,
@@ -717,6 +744,7 @@ def run_training(cfg: TrainConfig,
         initialize_distributed, make_mesh)
     from faster_distributed_training_tpu.parallel.placement import (
         dp_size, make_put_batch, shard_train_state, train_state_shardings)
+    from faster_distributed_training_tpu.telemetry import spans
     from faster_distributed_training_tpu.train import (Trainer,
                                                        create_train_state,
                                                        init_attn_lambda,
@@ -801,42 +829,43 @@ def run_training(cfg: TrainConfig,
     tx, _ = build_optimizer(cfg, steps_per_epoch,
                             lr_scale=float(dp_size(mesh)))
 
-    rng = jax.random.PRNGKey(cfg.seed)
-    if is_text:
-        sample = jnp.zeros((cfg.batch_size, cfg.seq_len), jnp.int32)
-        extra = None
-    else:
-        sample = jnp.zeros((cfg.batch_size, 32, 32, 3), jnp.float32)
-        # learnable-lambda modes own a trainable leaf beside the model:
-        # meta = per-sample scalar, attn = per-pixel NHWC map
-        # (resnet50_test.py:388-401, 404-424)
-        mode = resolve_mixup_mode(cfg)
-        if mode == "meta":
-            extra = {"mixup_lambda": init_meta_lambda(rng, cfg.batch_size)}
-        elif mode == "attn":
-            extra = {"mixup_lambda": init_attn_lambda(rng, cfg.batch_size,
-                                                      32, 32, 3)}
-        else:
+    with spans.span("setup_state"):     # the state's creation, placement
+        rng = jax.random.PRNGKey(cfg.seed)
+        if is_text:
+            sample = jnp.zeros((cfg.batch_size, cfg.seq_len), jnp.int32)
             extra = None
-    state = create_train_state(model, tx, sample, rng,
-                               init_kwargs={"train": True},
-                               extra_params=extra)
-    # the explicit sharding tree is needed beyond --host_offload on any
-    # mesh with a model axis: the train step pins its OUTPUT state to it
-    # (steps.make_train_step) so XLA's partitioner can neither drift the
-    # updated tp-sharded params toward replication nor scatter
-    # replicated params onto the sp axis between donated steps
-    # (measured: an sp mesh without the pin re-sharded pos_embedding
-    # over sp after step 1 and the donated recall mismatched)
-    from faster_distributed_training_tpu.parallel.mesh import (pp_size,
-                                                               sp_size,
-                                                               tp_size)
-    shardings = (train_state_shardings(state, mesh, cfg,
-                                       pipeline=pipeline)
-                 if cfg.host_offload or cfg.offload_opt_state
-                 or cfg.overlap_grad_reduce or tp_size(mesh) > 1
-                 or sp_size(mesh) > 1 or pp_size(mesh) > 1 else None)
-    state = shard_train_state(state, mesh, cfg, shardings=shardings)
+        else:
+            sample = jnp.zeros((cfg.batch_size, 32, 32, 3), jnp.float32)
+            # learnable-lambda modes own a trainable leaf beside the model:
+            # meta = per-sample scalar, attn = per-pixel NHWC map
+            # (resnet50_test.py:388-401, 404-424)
+            mode = resolve_mixup_mode(cfg)
+            if mode == "meta":
+                extra = {"mixup_lambda": init_meta_lambda(rng, cfg.batch_size)}
+            elif mode == "attn":
+                extra = {"mixup_lambda": init_attn_lambda(rng, cfg.batch_size,
+                                                          32, 32, 3)}
+            else:
+                extra = None
+        state = create_train_state(model, tx, sample, rng,
+                                   init_kwargs={"train": True},
+                                   extra_params=extra)
+        # the explicit sharding tree is needed beyond --host_offload on any
+        # mesh with a model axis: the train step pins its OUTPUT state to it
+        # (steps.make_train_step) so XLA's partitioner can neither drift the
+        # updated tp-sharded params toward replication nor scatter
+        # replicated params onto the sp axis between donated steps
+        # (measured: an sp mesh without the pin re-sharded pos_embedding
+        # over sp after step 1 and the donated recall mismatched)
+        from faster_distributed_training_tpu.parallel.mesh import (pp_size,
+                                                                   sp_size,
+                                                                   tp_size)
+        shardings = (train_state_shardings(state, mesh, cfg,
+                                           pipeline=pipeline)
+                     if cfg.host_offload or cfg.offload_opt_state
+                     or cfg.overlap_grad_reduce or tp_size(mesh) > 1
+                     or sp_size(mesh) > 1 or pp_size(mesh) > 1 else None)
+        state = shard_train_state(state, mesh, cfg, shardings=shardings)
 
     # TRAIN augmentation lives inside the train step now (steps.py):
     # uint8 batches are crop/flip/normalized on device with the key
@@ -946,7 +975,7 @@ def run_training(cfg: TrainConfig,
     # epoch straggler fold.  build_telemetry returns None under
     # --no_telemetry and the hot loop gets zero new work.
     from faster_distributed_training_tpu.telemetry import (
-        build_telemetry, flight, programs, resolve_telemetry_dir, spans,
+        build_telemetry, flight, programs, resolve_telemetry_dir,
         write_manifest)
     from faster_distributed_training_tpu.utils.profiling import (
         StepWindowProfiler, parse_profile_steps)
@@ -1293,6 +1322,7 @@ def run_serving(cfg: TrainConfig, requests=None,
                 mesh=mesh if sharded else None, log=log)
             prev_obs = programs.set_observatory(obs)
         log(f"[serve] telemetry recording to {tdir}")
+    spans.hand_over(recorder)     # ends the set-up stretch (None: dropped)
     try:
         model, sstate, meta = load_serving_state(
             cfg, mesh=mesh if sharded else None, log=log)
@@ -1443,6 +1473,7 @@ def run_decode_serving(cfg: TrainConfig, prompts=None,
                 mesh=mesh if sharded else None, log=log)
             prev_obs = programs.set_observatory(obs)
         log(f"[decode] telemetry recording to {tdir}")
+    spans.hand_over(recorder)     # ends the set-up stretch (None: dropped)
     try:
         model, sstate, meta = load_serving_state(
             cfg, mesh=mesh if sharded else None, log=log)

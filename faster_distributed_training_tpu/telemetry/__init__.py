@@ -15,7 +15,10 @@ itself:
     under the same name; instrumented seams: H2D upload / epoch
     re-shard (data/device_resident.py), checkpoint snapshot/commit
     (resilience/manager.py), restore/rendezvous
-    (resilience/{manager,coordinator}.py), eval, first-dispatch compile;
+    (resilience/{manager,coordinator}.py), eval, each program's first
+    dispatch with its lowering / compile / observation inside it, and
+    set-up (platform, data, model, state) — spans that close before the
+    recorder exists are held for it;
   * ``aggregate`` — process 0 folds the per-host JSONL into run-level
     p50/p95/p99 step times at epoch end (marker-file transport, the r10
     idiom) and flags stragglers in a ``[telemetry]`` log line;
@@ -145,12 +148,18 @@ def build_telemetry(cfg, log: Callable[[str], None] = print
                     ) -> Optional[RunTelemetry]:
     """RunTelemetry for a TrainConfig, or None when disabled
     (``--no_telemetry`` — the kill switch emergency rollbacks rely
-    on)."""
+    on).  Ends the set-up stretch ``cli.setup_platform`` opened: the
+    spans it held go to the new recorder first, in order."""
     if not getattr(cfg, "telemetry", True):
+        spans.hand_over(None)
         return None
     recorder = TelemetryRecorder(
         resolve_telemetry_dir(cfg),
         step_every=int(getattr(cfg, "telemetry_every", 1) or 1), log=log)
+    dropped = spans.hand_over(recorder)
+    if dropped:
+        log(f"[telemetry] {dropped} set-up span(s) beyond the first "
+            f"{spans.HELD_MAX} were dropped")
     return RunTelemetry(
         recorder,
         straggler_ratio=float(getattr(cfg, "straggler_ratio", 2.0) or 2.0),

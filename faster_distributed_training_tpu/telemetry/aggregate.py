@@ -194,18 +194,48 @@ def _host_stats(fold: dict) -> dict:
 
 
 def span_breakdown(records: List[dict]) -> Dict[str, dict]:
-    """{span name: {count, total_ms, mean_ms}} over one host's stream."""
-    out: Dict[str, dict] = {}
-    for r in records:
-        if r.get("kind") != "span":
+    """{span name: {count, total_ms, mean_ms[, children]}} over a stream.
+
+    Top-level spans are the keys; a span recorded with a ``parent`` sits
+    in ``children`` of the parent span that held its start (the first
+    later record of that name whose interval holds ``t0_s``: a child
+    closes before its parent), keyed the same way at any depth.  So the
+    top level's totals add up without counting nested time twice.  A
+    span whose parent is not in the stream (it never closed) stays at
+    the top."""
+    recs = [r for r in records if r.get("kind") == "span"]
+    paths: List[tuple] = [()] * len(recs)
+    for i in range(len(recs) - 1, -1, -1):
+        r = recs[i]
+        paths[i] = (r.get("name", "?"),)
+        parent, t0 = r.get("parent"), r.get("t0_s")
+        if parent is None or t0 is None:
             continue
-        s = out.setdefault(r.get("name", "?"),
-                           {"count": 0, "total_ms": 0.0})
+        for j in range(i + 1, len(recs)):
+            q = recs[j]
+            if (q.get("name") == parent and q.get("t0_s") is not None
+                    and q["t0_s"] - 1e-5 <= t0
+                    <= q["t0_s"] + float(q.get("dur_ms", 0.0)) / 1e3
+                    + 1e-5):
+                paths[i] = paths[j] + paths[i]
+                break
+    out: Dict[str, dict] = {}
+    for r, path in zip(recs, paths):
+        level = out
+        for name in path[:-1]:     # ancestors close after: placeholders
+            level = level.setdefault(
+                name, {"count": 0, "total_ms": 0.0}).setdefault(
+                    "children", {})
+        s = level.setdefault(path[-1], {"count": 0, "total_ms": 0.0})
         s["count"] += 1
         s["total_ms"] += float(r.get("dur_ms", 0.0))
-    for s in out.values():
-        s["total_ms"] = round(s["total_ms"], 3)
-        s["mean_ms"] = round(s["total_ms"] / s["count"], 3)
+
+    def finish(level: Dict[str, dict]) -> None:
+        for s in level.values():
+            s["total_ms"] = round(s["total_ms"], 3)
+            s["mean_ms"] = round(s["total_ms"] / s["count"], 3)
+            finish(s.get("children", {}))
+    finish(out)
     return out
 
 

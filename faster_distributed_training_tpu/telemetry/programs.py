@@ -77,6 +77,8 @@ import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from faster_distributed_training_tpu.telemetry import spans
+
 ENV_KILL = "FDT_PROGRAM_OBS"
 ENV_FINGERPRINT = "FDT_HLO_FINGERPRINT"
 
@@ -238,7 +240,8 @@ class ProgramObservatory:
         #    programs (cache_source="deserialized") instead of
         #    recompiling; any cache failure degrades to plain compile;
         #  * goodput (resilience/goodput.py) receives every observed
-        #    program-acquisition cost (lower + compile OR deserialize)
+        #    program-acquisition cost (lower + compile OR deserialize +
+        #    the observatory's own work)
         #    so restart MTTR can split into _compile_s vs _restore_s
         #    components — the compile-dominated half was invisible
         #    before.
@@ -247,79 +250,98 @@ class ProgramObservatory:
 
     # -- the compile path --------------------------------------------------
 
-    def wrap(self, name: str, jitted, sig_argnums: Tuple[int, ...] = ()
-             ) -> "ObservedJit":
-        return ObservedJit(name, jitted, self, sig_argnums=sig_argnums)
+    def wrap(self, name: str, jitted, sig_argnums: Tuple[int, ...] = (),
+             step: Optional[Callable[[], int]] = None) -> "ObservedJit":
+        return ObservedJit(name, jitted, self, sig_argnums=sig_argnums,
+                           step=step)
 
     def observe_compile(self, name: str, jitted, args,
                         sig: Optional[tuple] = None):
         """Explicit lower+compile of ``jitted`` for ``args`` under
         observation; returns the AOT compiled callable, or None when the
         AOT path is unavailable (caller falls back to plain jit dispatch
-        — observability must never kill training)."""
+        — observability must never kill training).
+
+        Each part of the acquisition is a span (telemetry/spans.py):
+        ``program_lower`` (``lower_ms``), ``program_compile`` (the
+        executable cache's lookup, then XLA's compile or the persistent
+        cache's load; ``compile_ms`` is the part that served the
+        program) and ``program_observe``, the observatory's own work,
+        once before the compile and once after it."""
         try:
-            t0 = time.monotonic()
-            lowered = jitted.lower(*args)
-            lower_ms = (time.monotonic() - t0) * 1e3
-            lowered_text = self._lowered_text(lowered)
-            fingerprint = (hashlib.sha256(lowered_text.encode())
-                           .hexdigest()[:16] if lowered_text else "")
-            # r17 executable cache: lookup-before-compile.  A hit
-            # deserializes the stored executable (compile_ms below IS
-            # the deserialize time — the restart-MTTR number the A/B
-            # reads); any load failure returned None and the plain
-            # compile below serves the program.  Fingerprint "" (the
-            # FDT_HLO_FINGERPRINT=0 escape) has no key and skips the
-            # tier entirely.
-            ec = self.executable_cache
-            exec_key = (ec.key_for(name, fingerprint)
-                        if ec is not None and fingerprint else None)
-            compiled = None
-            if exec_key is not None:
+            t_start = time.monotonic()
+            with spans.span("program_lower"):
                 t0 = time.monotonic()
-                compiled = ec.load(exec_key, lowered)
-            if compiled is not None:
-                compile_ms = (time.monotonic() - t0) * 1e3
-                cache, method = "bypassed", "executable_cache"
-                source = "deserialized"
-            else:
+                lowered = jitted.lower(*args)
+                lower_ms = (time.monotonic() - t0) * 1e3
+            with spans.span("program_observe"):
+                lowered_text = self._lowered_text(lowered)
+                fingerprint = (hashlib.sha256(lowered_text.encode())
+                               .hexdigest()[:16] if lowered_text else "")
+                # Fingerprint "" (the FDT_HLO_FINGERPRINT=0 escape) has
+                # no key and skips the executable tier entirely
+                ec = self.executable_cache
+                exec_key = (ec.key_for(name, fingerprint)
+                            if ec is not None and fingerprint else None)
                 before = self._cache_listing()
                 hits0 = _persistent_cache_hits()
-                t0 = time.monotonic()
-                compiled = lowered.compile()
-                compile_ms = (time.monotonic() - t0) * 1e3
-                if _persistent_cache_hits() > hits0:
-                    # jax itself said so: the only verdict that tells a
-                    # FAST hit from a compile too quick to be stored
-                    cache, method = "hit", "jax_event"
-                else:
-                    cache, method = self._cache_verdict(before, compile_ms)
-                # "persistent_dir": XLA's own persistent cache served
-                # the compile (the executable tier's designed fallback)
-                source = "persistent_dir" if cache == "hit" else "compiled"
+            with spans.span("program_compile"):
+                # r17 executable cache: lookup-before-compile.  A hit
+                # deserializes the stored executable (compile_ms IS the
+                # deserialize time — the restart-MTTR number the A/B
+                # reads); a load failure returns None and the plain
+                # compile serves the program
+                compiled = None
                 if exec_key is not None:
-                    if cache in ("miss", "off", "below_threshold"):
-                        ec.store(exec_key, compiled)  # best-effort, counted
+                    t0 = time.monotonic()
+                    compiled = ec.load(exec_key, lowered)
+                deserialized = compiled is not None
+                if not deserialized:
+                    t0 = time.monotonic()
+                    compiled = lowered.compile()
+                compile_ms = (time.monotonic() - t0) * 1e3
+            with spans.span("program_observe"):
+                if deserialized:
+                    cache, method = "bypassed", "executable_cache"
+                    source = "deserialized"
+                else:
+                    if _persistent_cache_hits() > hits0:
+                        # jax itself said so: the only verdict that tells
+                        # a FAST hit from a compile too quick to be stored
+                        cache, method = "hit", "jax_event"
                     else:
-                        # served (or unverifiable, remote-dir "unknown"):
-                        # a persistent-cache-served executable does NOT
-                        # serialize round-trippably on XLA:CPU (missing
-                        # function symbols at deserialize) — only fresh
-                        # compiles are stored; the persistent dir keeps
-                        # serving this program at restart regardless
-                        ec.note_skipped_served()
-            mem = memory_analysis_dict(compiled)
-            ops = hlo_op_counts(lowered_text, compiled)
+                        cache, method = self._cache_verdict(before,
+                                                            compile_ms)
+                    # "persistent_dir": XLA's own persistent cache served
+                    # the compile (the executable tier's designed fallback)
+                    source = ("persistent_dir" if cache == "hit"
+                              else "compiled")
+                    if exec_key is not None:
+                        if cache in ("miss", "off", "below_threshold"):
+                            ec.store(exec_key, compiled)  # best-effort
+                        else:
+                            # served (or unverifiable, remote-dir
+                            # "unknown"): a persistent-cache-served
+                            # executable does NOT serialize round-trippably
+                            # on XLA:CPU (missing function symbols at
+                            # deserialize) — only fresh compiles are
+                            # stored; the persistent dir keeps serving
+                            # this program at restart regardless
+                            ec.note_skipped_served()
+                mem = memory_analysis_dict(compiled)
+                ops = hlo_op_counts(lowered_text, compiled)
+            acquire_s = time.monotonic() - t_start
         except Exception as e:
             self._log(f"[programs] could not observe-compile {name!r} "
                       f"({e!r}); plain jit dispatch serves it (no program "
                       f"record)")
             return None
         if self.goodput is not None:
-            # program-acquisition cost (trace + compile-or-deserialize):
-            # the MTTR compile component a restarted process pays
+            # program-acquisition cost (trace + compile-or-deserialize +
+            # the observatory's own work): the MTTR compile component a
+            # restarted process pays
             try:
-                self.goodput.add_compile((lower_ms + compile_ms) / 1e3)
+                self.goodput.add_compile(acquire_s)
             except Exception:
                 pass  # accounting must never kill the compile path
         self._record(name, sig, lower_ms, compile_ms, fingerprint, cache,
@@ -490,14 +512,21 @@ class ObservedJit:
     the duplicate lowering surfaces as a ``retrace`` event — the
     detector and the dispatcher are the same mechanism.  Any observe
     failure degrades permanently to plain jit dispatch for this
-    program."""
+    program.
+
+    Each observed acquisition and the first execution that follows it
+    are one ``first_dispatch_compile`` span, the parent of the
+    observatory's ``program_*`` spans; ``step`` (optional) gives the
+    span's step at that moment.  The fast path reads no clock."""
 
     def __init__(self, name: str, jitted, observatory: ProgramObservatory,
-                 sig_argnums: Tuple[int, ...] = ()):
+                 sig_argnums: Tuple[int, ...] = (),
+                 step: Optional[Callable[[], int]] = None):
         self.name = name
         self._jit = jitted
         self._obs = observatory
         self._sig_argnums = tuple(sig_argnums)
+        self._step = step
         self._by_sig: Dict[tuple, Any] = {}
         self._single = None        # the fast path while one variant exists
         self._fallback = False
@@ -523,13 +552,16 @@ class ObservedJit:
                 # a non-signature arg's avals moved (the state): the
                 # re-observe below records the duplicate as a retrace
                 fn = None
-        fn = self._obs.observe_compile(self.name, self._jit, args, sig=sig)
-        if fn is None:
-            self._fallback = True
-            return self._jit(*args)
-        self._by_sig[sig] = fn
-        self._single = fn if len(self._by_sig) == 1 else None
-        return fn(*args)
+        step = self._step() if self._step is not None else None
+        with spans.span("first_dispatch_compile", step=step):
+            fn = self._obs.observe_compile(self.name, self._jit, args,
+                                           sig=sig)
+            if fn is None:
+                self._fallback = True
+                return self._jit(*args)
+            self._by_sig[sig] = fn
+            self._single = fn if len(self._by_sig) == 1 else None
+            return fn(*args)
 
 
 def wrap_jit(name: str, jitted, sig_argnums: Tuple[int, ...] = ()):

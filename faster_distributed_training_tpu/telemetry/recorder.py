@@ -55,7 +55,10 @@ by ``"kind"``:
                  run_epoch's closing fence closed the epoch's last
                  fenced window (same meaning as the step record's
                  fence fields; sync_ms = the wait of the fence itself)
-  ``span``       {name, dur_ms, step?}           (telemetry/spans.py)
+  ``span``       {name, dur_ms, step?, t0_s?, parent?}
+                 (telemetry/spans.py) t0_s = time.monotonic() at the
+                 span's entry; parent = the innermost span open on the
+                 same thread when it began (absent at top level)
   ``epoch``      {epoch, steps, trained_steps, loss?, accuracy?,
                   wall_s, ex_s, peak_mem_bytes?, eval_loss?,
                   eval_accuracy?}
@@ -173,7 +176,9 @@ TELEMETRY_SCHEMA: Dict[str, Optional[frozenset]] = {
     # run_epoch's own fence (train/loop._DispatchClock.fence)
     "epoch_fence": frozenset({"step", "epoch", "fence_steps", "fence_ms",
                               "sync_ms"}),
-    "span": frozenset({"name", "dur_ms", "step"}),
+    # t0_s / parent (append-only): the span's start on the
+    # monotonic clock and the span it nests in (telemetry/spans.py)
+    "span": frozenset({"name", "dur_ms", "step", "t0_s", "parent"}),
     # perplexity/eval_perplexity (r18 LM workload, append-only): only
     # emitted on --task lm runs (exp of the token-weighted epoch loss)
     "epoch": frozenset({"epoch", "steps", "trained_steps", "loss",
@@ -445,11 +450,17 @@ class TelemetryRecorder:
                 or (self._steps_seen + 1) % self.step_every == 0)
 
     def record_span(self, name: str, dur_ms: float,
-                    step: Optional[int] = None) -> None:
+                    step: Optional[int] = None,
+                    t0_s: Optional[float] = None,
+                    parent: Optional[str] = None) -> None:
         rec = {"kind": "span", "name": str(name),
                "dur_ms": round(dur_ms, 3)}
         if step is not None:
             rec["step"] = int(step)
+        if t0_s is not None:
+            rec["t0_s"] = round(t0_s, 6)
+        if parent is not None:
+            rec["parent"] = str(parent)
         self._append(rec)
 
     def record_event(self, kind: str, **fields) -> None:

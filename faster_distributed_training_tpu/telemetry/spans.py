@@ -2,13 +2,16 @@
 
 Two entry points write it:
 
-``with spans.span("restore"):`` — for BOUNDARY events (checkpoint,
-restore, eval, upload).  It does two things at once:
+``with spans.span("restore"):`` — for BOUNDARY events (set-up,
+checkpoint, restore, eval, upload).  It does two things at once:
 
   * records the HOST wall time of the block into the active
     :class:`~faster_distributed_training_tpu.telemetry.recorder.
     TelemetryRecorder` (a ``{"kind": "span", ...}`` JSONL event), so
-    ordinary runs get a span breakdown without any profiler attached;
+    ordinary runs get a span breakdown without any profiler attached.
+    The record carries ``t0_s``, ``time.monotonic()`` at entry (the
+    clock the benchmark's ``setup_s`` is taken on), and ``parent``, the
+    innermost span still open on the same thread (absent at top level);
   * wraps the block in ``jax.profiler.TraceAnnotation`` under the same
     ``fdt/<name>`` label, so when a trace IS being captured (``--profile``
     or the windowed ``--profile_steps A:B``) the identical names appear
@@ -22,6 +25,14 @@ level check when no profiler session is open).  The dispatching thread's
 iteration is TILED by sibling phases, none nested in another ``fdt/*``
 span, so a reduction that gives a device-idle gap to the host span
 covering most of it names exactly one phase.
+
+Set-up runs before any recorder exists: :func:`open_setup`
+(``cli.setup_platform`` calls it) starts a SET-UP STRETCH, in which a span
+that closes while no recorder is installed is HELD (at most
+:data:`HELD_MAX`; any beyond are counted and dropped) until
+:func:`hand_over` gives the held spans, in order, to the recorder
+``build_telemetry`` made and ends the stretch.  Outside a stretch nothing
+is held.
 
 The recorder is installed process-globally (:func:`set_recorder`) rather
 than threaded through every constructor: the instrumented seams live in
@@ -43,7 +54,27 @@ are never renamed; README "Observability" documents them):
   ``restore``               checkpoint restore walk (manager)
   ``rendezvous``            pod restore-agreement barrier (coordinator)
   ``eval``                  the per-epoch eval pass
-  ``first_dispatch_compile`` first execution of a train program (compile)
+  ``first_dispatch_compile`` first call of an observed program for an
+                            input signature (``ObservedJit``): its
+                            acquisition and first execution; ``step`` =
+                            the dispatch's first train step where the
+                            caller gives one
+  ``program_lower``         inside it: ``jitted.lower`` (Python trace +
+                            lowering)
+  ``program_compile``       inside it: XLA's compile, the persistent
+                            cache's load or the executable cache's
+                            deserialize
+  ``program_observe``       inside it (twice, before and after
+                            ``program_compile``): the observatory's own
+                            work — module text and fingerprint, cache
+                            listings, memory analysis, compiled text for
+                            the op counts, the executable cache's store
+  ``setup_platform``        ``cli.setup_platform`` (opens the set-up
+                            stretch)
+  ``setup_data``            ``cli.make_loaders``
+  ``setup_model``           ``cli.build_model``
+  ``setup_state``           ``cli.run_training``'s state creation and
+                            placement
 
 Hot-loop phases (:data:`PHASES`; trace only; ``step`` = the first train
 step of the dispatch the phase belongs to, which is the step record's
@@ -167,6 +198,15 @@ PHASES = ("data_wait", "h2d", "dispatch", "hooks", "readback",
           "epoch_fence")
 _PHASE_LABEL = {name: f"fdt/{name}" for name in PHASES}
 
+# the set-up stretch (module docstring): None when no stretch is open,
+# else the spans held for the recorder build_telemetry makes
+HELD_MAX = 64
+_held: Optional[list] = None
+_held_dropped = 0
+
+# the names of the spans open on this thread, innermost last (parent)
+_STACK = threading.local()
+
 # spans currently OPEN, any thread ({token: {name, t0, step, thread}}):
 # the crash flight recorder (telemetry/flight.py) reads this so a host
 # that dies inside restore/ckpt_commit/rendezvous names the phase it
@@ -204,14 +244,42 @@ def get_recorder():
     return _ACTIVE
 
 
+def open_setup() -> None:
+    """Start a set-up stretch (clearing anything an earlier one held)."""
+    global _held, _held_dropped
+    with _OPEN_LOCK:
+        _held, _held_dropped = [], 0
+
+
+def hand_over(recorder) -> int:
+    """End the set-up stretch: record the held spans, in order, into
+    ``recorder`` (dropped when it is None, a telemetry-off run) and
+    return how many spans the stretch dropped beyond :data:`HELD_MAX`."""
+    global _held, _held_dropped
+    with _OPEN_LOCK:
+        held, dropped = _held or [], _held_dropped
+        _held, _held_dropped = None, 0
+    if recorder is not None:
+        for h in held:
+            recorder.record_span(**h)
+    return dropped
+
+
 @contextlib.contextmanager
 def span(name: str, step: Optional[int] = None) -> Iterator[None]:
-    """Record `name`'s host wall time to the active recorder AND label
-    the same region ``fdt/<name>`` in any in-flight profiler trace.
-    Exception-safe: a span that raises still records its duration (a
-    failed restore's cost is exactly the kind of time MTTR wants)."""
+    """Record `name`'s host wall time, start and parent to the active
+    recorder (or hold it in a set-up stretch) AND label the same region
+    ``fdt/<name>`` in any in-flight profiler trace.  Exception-safe: a
+    span that raises still records its duration (a failed restore's cost
+    is exactly the kind of time MTTR wants)."""
+    global _held_dropped
     import jax
 
+    stack = getattr(_STACK, "names", None)
+    if stack is None:
+        stack = _STACK.names = []
+    parent = stack[-1] if stack else None
+    stack.append(name)
     t0 = time.monotonic()
     token = object()
     with _OPEN_LOCK:
@@ -221,11 +289,20 @@ def span(name: str, step: Optional[int] = None) -> Iterator[None]:
         with jax.profiler.TraceAnnotation(f"fdt/{name}"):
             yield
     finally:
+        dur_ms = (time.monotonic() - t0) * 1e3
+        stack.pop()
+        rec = _ACTIVE
         with _OPEN_LOCK:
             _OPEN.pop(token, None)
-        rec = _ACTIVE
+            if rec is None and _held is not None:
+                if len(_held) < HELD_MAX:
+                    _held.append({"name": name, "dur_ms": dur_ms,
+                                  "step": step, "t0_s": t0,
+                                  "parent": parent})
+                else:
+                    _held_dropped += 1
         if rec is not None:
-            rec.record_span(name, (time.monotonic() - t0) * 1e3, step=step)
+            rec.record_span(name, dur_ms, step=step, t0_s=t0, parent=parent)
 
 
 def phase(name: str, step: Optional[int] = None):

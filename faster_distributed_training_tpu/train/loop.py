@@ -295,7 +295,7 @@ class Trainer:
             "train:host:k1",
             jax.jit(make_train_step(cfg, state_shardings,
                                     pipeline=pipeline), **donate),
-            sig_argnums=(1,))
+            sig_argnums=(1,), step=self._next_step)
         self._fused_cache: Dict[tuple, Callable] = {}
         # sig_argnums=(1,): eval batches legally vary (text bucket
         # widths) — each width is a counted VARIANT of the one "eval"
@@ -333,19 +333,24 @@ class Trainer:
         self._sync_s = 0.0
         # programs that have already executed once: the FIRST dispatch of
         # each (path, kk) program carries its compile and is recorded as
-        # compile=True + a first_dispatch_compile span, so step-time
-        # percentiles stay clean of compilation
+        # compile=True, so step-time percentiles stay clean of compilation
         self._dispatched: set = set()
         # batches run by the most recent run_epoch call (epoch telemetry)
         self._last_epoch_steps = 0
 
-    def _observe(self, name: str, jitted, sig_argnums=()) -> Callable:
+    def _observe(self, name: str, jitted, sig_argnums=(),
+                 step: Optional[Callable[[], int]] = None) -> Callable:
         """Route a jitted program through the compile observatory when
-        one is active (telemetry/programs.py); identity otherwise."""
+        one is active (telemetry/programs.py); identity otherwise.
+        ``step`` gives its first_dispatch_compile span's step."""
         if self._observatory is None:
             return jitted
         return self._observatory.wrap(name, jitted,
-                                      sig_argnums=sig_argnums)
+                                      sig_argnums=sig_argnums, step=step)
+
+    def _next_step(self) -> int:
+        """The first train step of the dispatch about to run."""
+        return self.global_step + 1
 
     def _fused_step(self, kk: int, resident=None) -> Callable:
         """Jitted K-step fused dispatch, cached per (path, kk) — an
@@ -369,7 +374,7 @@ class Trainer:
             # would surface as a dtype-leak retrace, the r8 bug class)
             fn = self._observe(f"train:{key[0]}:k{kk}", fn,
                                sig_argnums=(1,) if resident is None
-                               else (1, 2, 3))
+                               else (1, 2, 3), step=self._next_step)
             self._fused_cache[key] = fn
         return fn
 
@@ -442,8 +447,9 @@ class Trainer:
         """Per-dispatch telemetry: one small host-side record into the
         recorder's ring buffer (nothing on the device, no sync).  The
         first execution of each compiled program is marked compile=True
-        (and mirrored as a first_dispatch_compile span) so aggregation
-        can exclude compilation from step-time percentiles."""
+        so aggregation can exclude compilation from step-time percentiles
+        (the observatory times the compile itself: its
+        first_dispatch_compile span, telemetry/programs.py)."""
         first = program_key not in self._dispatched
         if first:
             self._dispatched.add(program_key)
@@ -457,9 +463,6 @@ class Trainer:
                         compile_=first, h2d_ms=h2d_s * 1e3,
                         sync_ms=sync_s * 1e3, fence=fence,
                         counters=counters)
-        if first:
-            rec.record_span("first_dispatch_compile", dispatch_s * 1e3,
-                            step=self.global_step)
 
     def _keep_dispatch_times(self, program_key: tuple) -> bool:
         """Whether this dispatch's telemetry-ONLY clock reads (data

@@ -11,9 +11,10 @@ Reads the run manifest + every ``host_<pi>.jsonl`` the run emitted
     of the pod median host-p95);
   * the throughput curve (per-epoch examples/s + loss from the epoch
     events);
-  * the span breakdown (count/total/mean per span name: checkpoint
-    snapshot/commit, restore, rendezvous, eval, H2D upload, epoch
-    re-shard, first-dispatch compile);
+  * the span breakdown (count/total/mean per span name: set-up,
+    checkpoint snapshot/commit, restore, rendezvous, eval, H2D upload,
+    epoch re-shard, first-dispatch compile), each nested span under its
+    parent and only the top level in the total;
   * the final goodput/MTTR snapshot riding the same stream;
   * (r15) the compile observatory: per-program compile ms, persistent-
     cache verdict, HLO fingerprint and memory_analysis bytes, plus any
@@ -183,12 +184,20 @@ def render(report: dict) -> str:
             lines.append(" ".join(bits))
     sp = report.get("spans")
     if sp:
-        lines.append("span breakdown (all hosts):")
-        for name, st in sorted(sp.items(),
-                               key=lambda kv: -kv[1]["total_ms"]):
-            lines.append(f"  {name:<24} x{st['count']:<4} "
-                         f"total={st['total_ms']:>10.1f}ms "
-                         f"mean={st['mean_ms']:>8.1f}ms")
+        lines.append("span breakdown (all hosts; nested spans under their "
+                     "parent):")
+
+        def span_rows(level: dict, depth: int) -> None:
+            for name, st in sorted(level.items(),
+                                   key=lambda kv: -kv[1]["total_ms"]):
+                label = "  " * depth + name
+                lines.append(f"  {label:<24} x{st['count']:<4} "
+                             f"total={st['total_ms']:>10.1f}ms "
+                             f"mean={st['mean_ms']:>8.1f}ms")
+                span_rows(st.get("children", {}), depth + 1)
+        span_rows(sp, 0)
+        lines.append(f"  {'top-level total':<24}       total="
+                     f"{sum(st['total_ms'] for st in sp.values()):>10.1f}ms")
     progs = report.get("programs")
     if progs:
         lines.append("compiled programs (host 0; compile ms / source / "

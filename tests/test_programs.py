@@ -151,6 +151,58 @@ class TestObservedJit:
         assert len(ev) == 1 and ev[0]["name"] == "prog"
         assert ev[0]["lowerings"] == 1 and "compile_ms" in ev[0]
 
+    def test_acquisition_spans_nest_under_first_dispatch(self, tmp_path):
+        """A signature's first call is one first_dispatch_compile span
+        with the caller's step, holding program_lower, program_compile
+        and program_observe (before and after the compile), in that
+        order; the children's sum is within the parent, and lower_ms /
+        compile_ms are the durations of their spans.  Later calls write
+        no span."""
+        rec = TelemetryRecorder(str(tmp_path), process_index=0,
+                                process_count=1, log=lambda *_: None)
+        prev = spans.set_recorder(rec)
+        try:
+            obs = ProgramObservatory(recorder=rec, log=lambda *_: None)
+            wrapped = obs.wrap("prog", jax.jit(lambda a: a * 3),
+                               step=lambda: 41)
+            for _ in range(3):
+                wrapped(jnp.ones(4, jnp.float32))
+        finally:
+            spans.set_recorder(prev)
+        rec.close()
+        recs = _read_jsonl(os.path.join(str(tmp_path), "host_00000.jsonl"))
+        got = [r for r in recs if r["kind"] == "span"]
+        assert [r["name"] for r in got] == [
+            "program_lower", "program_observe", "program_compile",
+            "program_observe", "first_dispatch_compile"]
+        parent, kids = got[-1], got[:-1]
+        assert parent["step"] == 41 and "parent" not in parent
+        assert all(r["parent"] == "first_dispatch_compile" for r in kids)
+        starts = [r["t0_s"] for r in kids]
+        assert starts == sorted(starts) and starts[0] >= parent["t0_s"]
+        assert sum(r["dur_ms"] for r in kids) <= parent["dur_ms"] + 1e-3
+        prog = [r for r in recs if r["kind"] == "program"][0]
+        assert prog["lower_ms"] <= kids[0]["dur_ms"] + 0.01
+        assert prog["compile_ms"] <= kids[2]["dur_ms"] + 0.01
+
+    def test_goodput_counts_the_whole_acquisition(self, monkeypatch):
+        """goodput's compile seconds are the whole acquisition: the
+        lowering, the compile and the observatory's own work."""
+        import time as time_mod
+        billed = []
+        obs = ProgramObservatory(log=lambda *_: None)
+        obs.goodput = types.SimpleNamespace(add_compile=billed.append)
+        slow = ProgramObservatory.__dict__["_lowered_text"]
+
+        def slow_text(self, lowered):
+            time_mod.sleep(0.2)
+            return slow(self, lowered)
+        monkeypatch.setattr(ProgramObservatory, "_lowered_text", slow_text)
+        obs.wrap("prog", jax.jit(lambda a: a - 1))(jnp.ones(2))
+        v = obs.programs["prog"][0]
+        assert len(billed) == 1
+        assert billed[0] >= (v["lower_ms"] + v["compile_ms"]) / 1e3 + 0.2
+
     def test_kill_switch_removes_observatory(self, tmp_path, monkeypatch):
         from faster_distributed_training_tpu.telemetry import (
             build_telemetry)

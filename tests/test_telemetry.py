@@ -117,6 +117,125 @@ class TestSpans:
         with spans.span("eval"):
             pass  # no recorder installed: must not raise or record
 
+    def test_spans_carry_start_and_parent_across_two_levels(self,
+                                                             tmp_path):
+        """t0_s is the monotonic clock at entry; parent is the innermost
+        span open on the SAME thread (a span on another thread while
+        one is open here is top-level there)."""
+        import threading
+        rec = TelemetryRecorder(str(tmp_path), process_index=0,
+                                process_count=1, log=lambda *_: None)
+        prev = spans.set_recorder(rec)
+        try:
+            before = time.monotonic()
+            with spans.span("outer"):
+                with spans.span("middle", step=3):
+                    with spans.span("inner"):
+                        with spans.span("innermost"):
+                            pass
+                        t = threading.Thread(target=self._one_span)
+                        t.start()
+                        t.join()
+            with spans.span("after"):
+                pass
+        finally:
+            spans.set_recorder(prev)
+        rec.close()
+        got = {r["name"]: r for r in _read_jsonl(rec.path)
+               if r["kind"] == "span"}
+        assert got["inner"]["parent"] == "middle"
+        assert got["innermost"]["parent"] == "inner"
+        assert got["middle"]["parent"] == "outer"
+        assert got["middle"]["step"] == 3
+        assert "parent" not in got["outer"]
+        assert "parent" not in got["after"]
+        assert "parent" not in got["thread_top"]
+        assert (before <= got["outer"]["t0_s"] <= got["middle"]["t0_s"]
+                <= got["inner"]["t0_s"] <= got["thread_top"]["t0_s"])
+        for child, parent in (("inner", "middle"), ("middle", "outer")):
+            c, p = got[child], got[parent]
+            assert (c["t0_s"] + c["dur_ms"] / 1e3
+                    <= p["t0_s"] + p["dur_ms"] / 1e3 + 1e-5)
+        assert got["after"]["t0_s"] >= (got["outer"]["t0_s"]
+                                        + got["outer"]["dur_ms"] / 1e3
+                                        - 1e-5)
+
+    @staticmethod
+    def _one_span():
+        with spans.span("thread_top"):
+            pass
+
+
+class TestSetUpStretch:
+    """Spans that close before a recorder exists (cli.setup_platform
+    opens the stretch) are held for the one build_telemetry makes."""
+
+    def test_held_spans_reach_the_built_recorder_in_order(self, tmp_path):
+        assert spans.get_recorder() is None
+        spans.open_setup()
+        with spans.span("setup_platform"):
+            pass
+        with spans.span("setup_model"):
+            with spans.span("inner"):
+                pass
+        tel = build_telemetry(TrainConfig(checkpoint_dir=str(tmp_path)),
+                              log=lambda *_: None)
+        # the stretch ended with the hand-over: nothing more is held
+        with spans.span("after_the_stretch"):
+            pass
+        assert spans.hand_over(None) == 0
+        tel.close()
+        got = [r for r in _read_jsonl(tel.recorder.path)
+               if r["kind"] == "span"]
+        assert [r["name"] for r in got] == ["setup_platform", "inner",
+                                            "setup_model"]
+        assert got[1]["parent"] == "setup_model"
+        assert got[0]["t0_s"] < got[2]["t0_s"] <= got[1]["t0_s"]
+
+    def test_nothing_is_held_outside_a_stretch(self, tmp_path):
+        with spans.span("library_use"):
+            pass
+        rec = TelemetryRecorder(str(tmp_path), process_index=0,
+                                process_count=1, log=lambda *_: None)
+        assert spans.hand_over(rec) == 0
+        rec.close()
+        assert not [r for r in _read_jsonl(rec.path) if r["kind"] == "span"]
+
+    def test_the_held_list_is_bounded(self, tmp_path):
+        spans.open_setup()
+        for i in range(spans.HELD_MAX + 6):
+            with spans.span(f"s{i}"):
+                pass
+        rec = TelemetryRecorder(str(tmp_path), process_index=0,
+                                process_count=1, log=lambda *_: None)
+        assert spans.hand_over(rec) == 6
+        rec.close()
+        got = [r["name"] for r in _read_jsonl(rec.path)
+               if r["kind"] == "span"]
+        assert got == [f"s{i}" for i in range(spans.HELD_MAX)]
+
+    def test_a_later_recorder_sees_none_of_an_earlier_runs_spans(
+            self, tmp_path):
+        """An earlier run that opened a stretch and never built its
+        telemetry; a later one installs its own recorder: only its own
+        spans reach it, and a telemetry-off build ends the stretch."""
+        spans.open_setup()
+        with spans.span("earlier"):
+            pass
+        rec = TelemetryRecorder(str(tmp_path), process_index=0,
+                                process_count=1, log=lambda *_: None)
+        prev = spans.set_recorder(rec)
+        try:
+            with spans.span("mine"):
+                pass
+        finally:
+            spans.set_recorder(prev)
+        rec.close()
+        assert [r["name"] for r in _read_jsonl(rec.path)
+                if r["kind"] == "span"] == ["mine"]
+        assert build_telemetry(TrainConfig(telemetry=False)) is None
+        assert spans.hand_over(None) == 0
+
 
 class TestPercentiles:
     def test_nearest_rank(self):
@@ -357,6 +476,25 @@ class TestEndToEnd:
         # (checkpoint_every=4 fired mid-epoch on the async path)
         assert {"first_dispatch_compile", "eval", "ckpt_snapshot",
                 "ckpt_commit"} <= names, names
+        # set-up, held until the recorder existed: the first spans of the
+        # stream, top-level, in the order they ran, none overlapping
+        setup = [r for r in recs if r["kind"] == "span"][:4]
+        assert [r["name"] for r in setup] == [
+            "setup_platform", "setup_model", "setup_data", "setup_state"]
+        assert all("parent" not in r for r in setup)
+        for a, b in zip(setup, setup[1:]):
+            assert a["t0_s"] + a["dur_ms"] / 1e3 <= b["t0_s"] + 1e-5
+        # the step program's acquisition inside its first dispatch
+        first = [r for r in recs if r["kind"] == "span"
+                 and r["name"] == "first_dispatch_compile"
+                 and r.get("step") == 1]
+        assert len(first) == 1 and "parent" not in first[0]
+        kids = [r for r in recs if r["kind"] == "span"
+                and r.get("parent") == "first_dispatch_compile"
+                and first[0]["t0_s"] <= r["t0_s"]
+                <= first[0]["t0_s"] + first[0]["dur_ms"] / 1e3]
+        assert sorted({r["name"] for r in kids}) == [
+            "program_compile", "program_lower", "program_observe"]
         # goodput rides the same stream (one snapshot per epoch)
         goodputs = [r for r in recs if r["kind"] == "goodput"]
         assert len(goodputs) == 2 and goodputs[-1]["saves"] >= 1
@@ -480,6 +618,44 @@ class TestReportScript:
         bd = span_breakdown(hosts[0] + hosts[1])
         assert bd["eval"]["count"] == 4
         assert bd["ckpt_commit"]["total_ms"] == 360.0
+
+    def test_nested_spans_sit_under_their_parent_and_count_once(self):
+        """A child sits under the parent span that held its start, at any
+        depth; the top level's totals hold no time twice, and the report
+        shows the children indented under their parent."""
+        def sp(name, t0, dur_ms, parent=None):
+            r = {"kind": "span", "name": name, "t0_s": t0, "dur_ms": dur_ms}
+            if parent:
+                r["parent"] = parent
+            return r
+        recs = [sp("setup_platform", 0.0, 500.0),
+                sp("program_lower", 1.0, 300.0, "first_dispatch_compile"),
+                sp("program_compile", 1.3, 600.0, "first_dispatch_compile"),
+                sp("first_dispatch_compile", 1.0, 1000.0),
+                sp("program_lower", 5.0, 30.0, "first_dispatch_compile"),
+                sp("first_dispatch_compile", 5.0, 50.0, "eval"),
+                sp("eval", 4.99, 80.0),
+                {"kind": "span", "name": "ckpt_commit", "dur_ms": 7.0}]
+        bd = span_breakdown(recs)
+        assert set(bd) == {"setup_platform", "first_dispatch_compile",
+                           "eval", "ckpt_commit"}
+        fdc = bd["first_dispatch_compile"]
+        assert fdc["count"] == 1 and fdc["total_ms"] == 1000.0
+        assert fdc["children"]["program_lower"]["total_ms"] == 300.0
+        assert fdc["children"]["program_compile"]["total_ms"] == 600.0
+        inner = bd["eval"]["children"]["first_dispatch_compile"]
+        assert inner["total_ms"] == 50.0
+        assert inner["children"]["program_lower"]["total_ms"] == 30.0
+        assert sum(s["total_ms"] for s in bd.values()) == 1587.0
+        text = self._mod().render({"directory": "/tmp/x", "spans": bd})
+        rows = [ln for ln in text.splitlines() if "total=" in ln]
+        assert [r.split()[0] for r in rows] == [
+            "first_dispatch_compile", "program_compile", "program_lower",
+            "setup_platform", "eval", "first_dispatch_compile",
+            "program_lower", "ckpt_commit", "top-level"]
+        assert rows[1].startswith("    program_compile")
+        assert rows[6].startswith("      program_lower")
+        assert "1587.0ms" in rows[-1]
 
     def test_render_orders_hosts_numerically(self):
         """Host rows sort by host INDEX, not by the stringified key —
