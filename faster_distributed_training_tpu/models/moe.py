@@ -46,13 +46,22 @@ the top-k (``expert_bias``) is the constant zero here: its source gives a
 coefficient and no update rule, and a bias nothing updates is no state.
 When a rule arrives the bias becomes a ``batch_stats`` leaf (ROADMAP R3).
 
+The routing arithmetic is dense compare-and-reduce, not scalar gathers
+and scatters whose indices are data (those run one entry at a time): the
+chosen experts' weights are picked from the scores by a select over the
+router's width (``route``; its transpose is a select too), and each held
+expert's count and each slot's place in the expert order are sums over a
+one-hot of the slots' experts (``_expert_order``).  The sort, the gathers
+of rows and the compact index's inverse stay what they are.
+
 Inside a block under ``--remat`` (models/decoder.py) the routing
 decision's integers are kept by name (``ROUTING_RESIDUALS``: ``chosen``,
 the rows' tokens, ``sizes``, the compact index and its inverse: 1.0 MB a
 layer at 8,192 tokens and 8 experts a token, 1.5 MB at 22 a token over 8
 held), so the backward's replay repeats neither the top-k, nor the sort,
-nor the scatters; the router's scores, the gathers and the grouped
-products are replayed.  Saved or recomputed they are the same integers.
+nor the counts, nor the compact index; it repeats the router's scores and
+the select that picks the chosen weights from them, the gathers of rows
+and the grouped products.  Saved or recomputed they are the same integers.
 """
 
 from __future__ import annotations
@@ -78,6 +87,29 @@ HI = jax.lax.Precision.HIGHEST
 CHOSEN, SOURCE, SIZES, COMPACT, COMPACT_INV = ROUTING_RESIDUALS = (
     "moe_chosen", "moe_source", "moe_sizes", "moe_compact",
     "moe_compact_inv")
+
+
+def _expert_order(chosen, lo: int, held: int):
+    """The token-slots of ``chosen`` [T, top_k] in expert order: (``here``
+    [T * top_k], the slots whose expert is held, ``lo .. lo + held``;
+    ``order``, the slots stably sorted by held expert, absent slots last;
+    ``inv``, each slot's place in ``order``; ``sizes`` [held], the slots
+    each held expert received).  ``sizes`` and ``inv`` are counted from a
+    one-hot of the slots' bins [held + 1, T * top_k]: a bin's count is its
+    row's sum, and a slot's place is its bin's start plus the slots of its
+    bin before it, a running sum down the row, which is the stable sort's
+    inverse; a scatter of ones into ``held + 1`` bins, or of T x top_k
+    places, would run one entry at a time."""
+    local = chosen.reshape(-1) - lo
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)          # absent experts last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    hot = jnp.arange(held + 1, dtype=jnp.int32)[:, None] == key
+    counts = jnp.sum(hot, axis=1, dtype=jnp.int32)
+    start = jnp.cumsum(counts, dtype=jnp.int32) - counts
+    place = start[:, None] + jnp.cumsum(hot, axis=1, dtype=jnp.int32) - 1
+    inv = jnp.sum(jnp.where(hot, place, 0), axis=0)
+    return here, order, inv, counts[:held]
 
 
 def _compact(here, inv, landed, m: int):
@@ -161,12 +193,17 @@ def route(x32, router_kernel, top_k: int, route_scale: float,
     """(chosen experts [T, k] int32, their weights [T, k] float32) from
     float32 tokens [T, d]: sigmoid scores over the router's whole width,
     the ``top_k`` largest (the balancing bias is zero), weights from the
-    scores."""
+    scores.  A chosen expert's score is picked by a select over the
+    router's width and a sum, in which one entry matches: the same float,
+    and its transpose is a select too, where a gather of T x k scalars and
+    its scatter-add would run one entry at a time."""
     scores = jax.nn.sigmoid(jnp.dot(x32, router_kernel.astype(jnp.float32),
                                     precision=HI))
     _, chosen = jax.lax.top_k(scores, top_k)
     chosen = checkpoint_name(chosen.astype(jnp.int32), CHOSEN)
-    w = jnp.take_along_axis(scores, chosen, axis=1)
+    experts = jnp.arange(scores.shape[1], dtype=jnp.int32)
+    w = jnp.sum(jnp.where(chosen[:, :, None] == experts, scores[:, None], 0.0),
+                axis=2)
     if route_norm:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
     return chosen, w * route_scale
@@ -202,22 +239,17 @@ def routed_experts(x, chosen, weights, gate, up, down, lo: int,
     int32.  The expert-order buffers hold ``T x min(top_k, held)`` rows:
     a token's experts are distinct, so no more of its slots land here, and
     the combine and both transposes gather as many through the compact
-    index (the module docstring)."""
+    index (the module docstring).  The counts and the sort's inverse come
+    from ``_expert_order``'s one-hot, not from scatters."""
     T = x.shape[0]
     top_k = chosen.shape[1]
     held = up.shape[0]
     m = min(top_k, held)
     with jax.named_scope("fdt/moe_dispatch"):
-        local = chosen.reshape(-1) - lo
-        here = (local >= 0) & (local < held)
-        key = jnp.where(here, local, held)          # absent experts last
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
+        here, order, inv, sizes = _expert_order(chosen, lo, held)
         # the rows past T x m are absent slots
         source = checkpoint_name(order[:T * m] // top_k, SOURCE)
-        sizes = checkpoint_name(
-            jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held], SIZES)
+        sizes = checkpoint_name(sizes, SIZES)
         pick, slots, rows = _compact(here.reshape(T, top_k),
                                      inv.reshape(T, top_k), jnp.sum(sizes), m)
         xs = _dispatch(x, source, slots)

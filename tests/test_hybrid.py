@@ -453,6 +453,125 @@ def test_compact_index_is_a_permutation_of_the_rows(top_k, held):
     assert 0 < landed < T * m
 
 
+def gathered_route(x32, router_kernel, top_k, route_scale):
+    """``moe.route`` as it was written with a gather of the chosen
+    scores, whose transpose is a scatter-add."""
+    scores = jax.nn.sigmoid(jnp.dot(x32, router_kernel, precision=moe.HI))
+    _, chosen = jax.lax.top_k(scores, top_k)
+    chosen = chosen.astype(jnp.int32)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    return chosen, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) \
+        * route_scale
+
+
+@pytest.mark.parametrize("router_width,top_k", [(512, 22), (128, 8)])
+def test_route_weights_match_the_gathered_scores(router_width, top_k):
+    """The router weights picked by a select over the router's width are
+    the gathered scores': the same experts, the weights and the gradients
+    to the router and the tokens within 1e-6 (only the normalising sum's
+    fusion may differ).  The gradients are probed with a seeded cotangent
+    that differs from slot to slot: one nearly equal over a token's slots
+    makes the normalisation's gradient a difference of near-equal terms,
+    which turns that ulp into 1e-4 on either side alike."""
+    def both(fn, a, k, probe):
+        return fn(a, k, top_k, 2.5), jax.grad(lambda a, k: jnp.sum(
+            probe * fn(a, k, top_k, 2.5)[1]), (0, 1))(a, k)
+
+    @jax.jit
+    def sides(*args):
+        return both(moe.route, *args), both(gathered_route, *args)
+    with jax.enable_x64(False):
+        x = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
+        kernel = jax.random.normal(jax.random.PRNGKey(1), (8, router_width))
+        probe = jax.random.normal(jax.random.PRNGKey(2), (16, top_k))
+        ((chosen, w), got), ((want_chosen, want_w), want) = sides(
+            x, kernel, probe)
+    assert (np.asarray(chosen) == np.asarray(want_chosen)).all()
+    assert close(w, want_w, 1e-6)
+    assert_trees_close(got, want, 1e-6)
+
+
+def scattered_order(chosen, lo, held):
+    """(``inv``, ``sizes``) as ``routed_experts`` wrote them: the stable
+    sort's inverse and the experts' counts by scatters."""
+    local = chosen.reshape(-1) - lo
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    return inv, jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+
+
+@pytest.mark.parametrize("lands,top_k,held", [
+    ("none", 3, 2), ("some", 3, 2), ("all", 2, 4), ("every_held", 5, 2),
+    ("some", 22, 8)])
+def test_sizes_and_inverse_match_the_scatters(lands, top_k, held):
+    """``sizes`` and the sort's inverse counted from a one-hot are the
+    scatters' integers, bit for bit: where no slot lands (the held experts
+    lie past the chosen ones), where some do, where every slot lands
+    (``top_k < held``, each token's experts among the held) and where
+    every token chose every held expert (``top_k >= held``)."""
+    T, width = 16, 24
+    with jax.enable_x64(False):
+        ranked = jnp.argsort(jax.random.uniform(
+            jax.random.PRNGKey(top_k), (T, width)), axis=1).astype(jnp.int32)
+        if lands in ("all", "every_held"):
+            # each token's first min(top_k, held) experts are held ones,
+            # in a shuffled order
+            first = jnp.argsort(ranked[:, :held], axis=1).astype(jnp.int32)
+            rest = jnp.sort(ranked, axis=1)[:, held:]
+            ranked = jnp.concatenate([first, rest], axis=1)
+        chosen = ranked[:, :top_k]
+        lo = width if lands == "none" else 0
+        (here, order, inv, sizes), (want_inv, want_sizes) = jax.jit(
+            lambda c: (moe._expert_order(c, lo, held),
+                       scattered_order(c, lo, held)))(chosen)
+    landed = int(jnp.sum(here))
+    if lands == "some":
+        assert 0 < landed < T * min(top_k, held)
+    else:
+        assert landed == {"none": 0, "all": T * top_k,
+                          "every_held": T * held}[lands]
+    assert (np.asarray(inv) == np.asarray(want_inv)).all()
+    assert (np.asarray(sizes) == np.asarray(want_sizes)).all()
+    assert (np.asarray(order)[np.asarray(inv)] == np.arange(T * top_k)).all()
+
+
+def test_routing_compiles_without_scalar_gathers_or_scatters():
+    """The gradient of a small expert layer under the decoder's
+    ``--remat`` policy, compiled: no gather reads the [T, router_width]
+    scores, and nothing scatters into them, into the ``held + 1`` bins of
+    ``sizes`` or into the T x top_k places of the sort's inverse (the
+    compact index's inverse, T x min(top_k, held), is a scatter still)."""
+    T, d, router_width, held, top_k = 12, 4, 16, 2, 3
+    layer = moe.ExpertLayer(router_width=router_width, held=held, lo=0,
+                            top_k=top_k, width=8, n_shared=0, impl="ragged",
+                            act="relu2")
+    with jax.enable_x64(False):
+        x = jax.ShapeDtypeStruct((1, T, d), jnp.float32)
+        params = jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(1), jnp.zeros(x.shape))["params"])
+        loss = jax.checkpoint(
+            lambda p, a: jnp.sum(layer.apply({"params": p}, a) ** 2),
+            policy=decoder.REMAT_POLICY)
+        text = jax.jit(jax.grad(loss, (0, 1))).lower(
+            params, x).compile().as_text()
+
+    # (result, first operand) shapes of each ``op``; the compiled text
+    # names an operand without its shape, so it is looked up
+    defined = dict(re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]", text))
+
+    def shapes(op):
+        return [(out, defined.get(src)) for out, src in re.findall(
+            rf"= \w+\[([\d,]*)\]\S* {op}\((%[\w.-]+)", text)]
+    scores = f"{T},{router_width}"
+    assert scores not in {src for _, src in shapes("gather")}
+    into = {out for out, _ in shapes("scatter")}
+    assert not into & {scores, f"{T * router_width}", f"{held + 1}",
+                       f"{T * top_k}"}, into
+    assert f"{T * min(top_k, held)}" in into    # the pattern finds scatters
+
+
 def test_flops_and_bytes_of_the_cell():
     """The cell's counts from shapes: the chunked scans' forward products
     x 3 at chunk 128 and their least bytes, and the step's model FLOPs."""
